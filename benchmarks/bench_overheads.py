@@ -12,7 +12,6 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.checkpoint.manager import CheckpointManager
-from repro.compat import shard_map
 from repro.core import (
     BurstPlanner,
     DeadlinePredictor,
@@ -40,7 +39,7 @@ def measured_ppermute_latency_s(payload_bytes: int, iters: int = 50) -> float:
     mesh = stripe_mesh(1)
     n = max(payload_bytes // 4, 1)
 
-    f = jax.jit(shard_map(
+    f = jax.jit(jax.shard_map(
         lambda x: jax.lax.ppermute(x, "stripe", [(0, 0)]),
         mesh=mesh, in_specs=P("stripe"), out_specs=P("stripe"),
     ))

@@ -55,35 +55,23 @@ if "--xla_force_host_platform_device_count" not in _flags:
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
-# Persistent XLA compilation cache: the harness compiles dozens of
-# executables that are byte-identical run to run — warm re-runs (and
-# the CI bench smoke) load them from disk instead of recompiling.
-# The dir follows JAX_COMPILATION_CACHE_DIR when set (ci.sh exports a
-# workspace-local one); hit/miss counts come from the cache's own
+# Persistent XLA compilation cache (repro.launch.compile_cache): the
+# harness compiles dozens of executables that are byte-identical run to
+# run — warm re-runs (and the CI bench smoke) load them from disk
+# instead of recompiling.  Hit/miss counts come from the cache's own
 # on-disk entries: every served entry touches its ``*-atime`` marker,
 # every compile writes a new ``*-cache`` file (jax has no public
 # counter API on this version, so the preamble counts files).
-CACHE_DIR = os.environ.get(
-    "JAX_COMPILATION_CACHE_DIR",
-    str(Path(__file__).resolve().parents[1] / ".jax_cache"),
-)
+from repro.launch.compile_cache import enable_compilation_cache  # noqa: E402
 
 
-def _enable_compilation_cache() -> None:
-    import jax
-
-    jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-
-
-def _cache_counts(since: float) -> tuple[int, int]:
+def _cache_counts(cache_dir: str, since: float) -> tuple[int, int]:
     """(hits, misses) since ``since``: touched atime markers vs new
     cache entries."""
     touched = misses = 0
     try:
-        for f in os.listdir(CACHE_DIR):
-            p = os.path.join(CACHE_DIR, f)
+        for f in os.listdir(cache_dir):
+            p = os.path.join(cache_dir, f)
             if f.endswith("-atime") and os.path.getmtime(p) >= since:
                 touched += 1
             elif f.endswith("-cache") and os.path.getmtime(p) >= since:
@@ -172,13 +160,13 @@ def main(argv: list[str] | None = None) -> None:
         ap.error(f"unknown bench(es): {sorted(unknown)}")
     selected = [(n, m) for n, m in benches if not only or n in only]
 
-    _enable_compilation_cache()
+    cache_dir = enable_compilation_cache()
     import time as _time
     t_start = _time.time()
-    n_existing = sum(1 for f in os.listdir(CACHE_DIR)
-                     if f.endswith("-cache")) if os.path.isdir(CACHE_DIR) \
+    n_existing = sum(1 for f in os.listdir(cache_dir)
+                     if f.endswith("-cache")) if os.path.isdir(cache_dir) \
         else 0
-    print(f"# jax compilation cache: {CACHE_DIR} "
+    print(f"# jax compilation cache: {cache_dir} "
           f"({n_existing} entries on disk)", file=sys.stderr)
 
     print("name,us_per_call,derived")
@@ -196,7 +184,7 @@ def main(argv: list[str] | None = None) -> None:
             errors[name] = repr(e)
             print(f"{name}.FAILED,0,{e!r}", flush=True)
             traceback.print_exc(file=sys.stderr)
-    hits, misses = _cache_counts(t_start)
+    hits, misses = _cache_counts(cache_dir, t_start)
     print(f"# jax compilation cache: {hits} hits, {misses} misses "
           f"this run", file=sys.stderr)
     if args.json:
@@ -204,7 +192,7 @@ def main(argv: list[str] | None = None) -> None:
             "benches": results,
             "failures": failures,
             "errors": errors,
-            "compilation_cache": {"dir": CACHE_DIR, "hits": hits,
+            "compilation_cache": {"dir": cache_dir, "hits": hits,
                                   "misses": misses},
         }
         with open(args.json, "w") as f:

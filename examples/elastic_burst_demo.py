@@ -36,6 +36,7 @@ from repro.core import (  # noqa: E402
     Resources,
 )
 from repro.data.pipeline import SyntheticLMPipeline  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.optim import constant, make_optimizer  # noqa: E402
 from repro.runtime.train_step import (  # noqa: E402
     build_train_step,
@@ -75,11 +76,9 @@ class LMSession:
         self.res = res
         n_pods = len(res.pods)
         if n_pods == 1:
-            mesh = jax.make_mesh((2, 2), ("data", "model"),
-                                 devices=jax.devices()[:4])
+            mesh = make_mesh((2, 2), ("data", "model"))
         else:
-            mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
-                                 devices=jax.devices()[:8])
+            mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         self.rules = make_rules(mesh, "train")
         self.shardings = state_shardings(SCH, self.rules, RUN)
         self.step_fn = jax.jit(build_train_step(CFG, RUN, OPT, self.rules))

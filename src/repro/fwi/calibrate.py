@@ -98,7 +98,6 @@ def measure_seam_latency(
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
     from repro.fwi.domain import halo_exchange_plan, stripe_mesh
     from repro.fwi.solver import ShotState, make_block_runner
 
@@ -110,7 +109,7 @@ def measure_seam_latency(
     perm = [(i, (i + 1) % n_mesh) for i in range(n_mesh)]
     words = max(int(plan["bytes_per_exchange"]) // 4, 1)
 
-    f = jax.jit(shard_map(
+    f = jax.jit(jax.shard_map(
         lambda x: jax.lax.ppermute(x, "stripe", perm),
         mesh=mesh, in_specs=P("stripe"), out_specs=P("stripe"),
     ))

@@ -62,9 +62,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import axis_size, shard_map
 from repro.fwi.solver import FWIConfig, ricker, sponge_taper, velocity_model
-from repro.kernels.stencil.ops import wave_block
+from repro.kernels.stencil.ops import resolves_use_pallas, wave_block
+from repro.launch.mesh import make_mesh
 
 HALO = 2
 
@@ -72,7 +72,7 @@ HALO = 2
 def stripe_mesh(n_devices: int | None = None) -> Mesh:
     devs = jax.devices()
     n = n_devices or len(devs)
-    return jax.make_mesh((n,), ("stripe",), devices=devs[:n])
+    return make_mesh((n,), ("stripe",), devices=devs[:n])
 
 
 def pick_overlap(backend: str | None = None) -> bool:
@@ -130,7 +130,7 @@ def _exchange_halo(edges_r: jnp.ndarray, edges_l: jnp.ndarray,
     from my left/right neighbors, zeroed at the physical domain edge.
     Exactly TWO ppermutes regardless of how many fields are packed in."""
     idx = jax.lax.axis_index(axis_name)
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     from_left = jax.lax.ppermute(
         edges_r, axis_name, [(i, (i + 1) % n) for i in range(n)]
     )
@@ -365,17 +365,17 @@ def _sharded_block_parts(cfg: FWIConfig, mesh: Mesh, k: int,
     # replication-safe by construction (everything is stripe-local)
     sms = {}
     if schedule == "pipeline":
-        sms["prologue"] = shard_map(
+        sms["prologue"] = jax.shard_map(
             local_prologue, mesh=mesh, in_specs=(field, field),
             out_specs=halo_sp, check_vma=False,
         )
-        sms["pipeline"] = shard_map(
+        sms["pipeline"] = jax.shard_map(
             local_pipeline_block, mesh=mesh,
             in_specs=(field, field, parts, parts, P(), halo_sp),
             out_specs=(field, field, field, halo_sp), check_vma=False,
         )
     else:
-        sms["block"] = shard_map(
+        sms["block"] = jax.shard_map(
             local_block, mesh=mesh,
             in_specs=(field, field, parts, parts, P()),
             out_specs=(field, field, field), check_vma=False,
@@ -387,9 +387,10 @@ def _sharded_block_parts(cfg: FWIConfig, mesh: Mesh, k: int,
     return sms, v2e_all, spe_all, place, k
 
 
+@resolves_use_pallas
 @functools.lru_cache(maxsize=32)
 def make_sharded_multistep(cfg: FWIConfig, mesh: Mesh, *, k: int = 1,
-                           use_pallas: bool = False,
+                           use_pallas: bool | None = None,
                            bz: int | None = None,
                            overlap: bool | str | None = None):
     """Temporally-blocked, comm/compute-overlapped sharded propagator.
@@ -426,9 +427,10 @@ def make_sharded_multistep(cfg: FWIConfig, mesh: Mesh, *, k: int = 1,
     return block_step, place
 
 
+@resolves_use_pallas
 @functools.lru_cache(maxsize=32)
 def make_sharded_step(cfg: FWIConfig, mesh: Mesh, *,
-                      use_pallas: bool = False):
+                      use_pallas: bool | None = None):
     """Single-timestep sharded propagator (k=1 temporal block) — the
     seed-compatible interface: step(p, p_prev, t) -> (p, p_prev, trace)
     with trace (S, NX)."""
@@ -444,9 +446,10 @@ def make_sharded_step(cfg: FWIConfig, mesh: Mesh, *,
     return step, place
 
 
+@resolves_use_pallas
 @functools.lru_cache(maxsize=32)
 def make_sharded_scan_runner(cfg: FWIConfig, mesh: Mesh, *, k: int = 4,
-                             use_pallas: bool = False,
+                             use_pallas: bool | None = None,
                              bz: int | None = None,
                              overlap: bool | str | None = None):
     """Scan-fused, overlapped, temporally-blocked runner:

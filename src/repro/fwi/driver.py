@@ -40,7 +40,12 @@ from repro.fwi.domain import (
     stripe_mesh,
 )
 from repro.fwi.solver import FWIConfig, ShotState
-from repro.kernels.stencil.ops import autotune_bz_k, pick_bz_block, pick_k
+from repro.kernels.stencil.ops import (
+    autotune_bz_k,
+    pick_bz_block,
+    pick_k,
+    resolve_use_pallas,
+)
 
 
 @dataclasses.dataclass
@@ -78,7 +83,7 @@ class FWISession(Session):
         n_stripes: int | None = None,
         exchange_interval: int | None = 4,
         scan_block: int = 8,
-        use_pallas: bool = False,
+        use_pallas: bool | None = None,
         autotune: bool = False,
     ):
         self.cfg = cfg
@@ -89,6 +94,7 @@ class FWISession(Session):
         while cfg.nx % n:
             n -= 1
         self.mesh = stripe_mesh(n)
+        use_pallas = resolve_use_pallas(use_pallas)
         bz = None
         if autotune and use_pallas:
             # joint (strip height, block length) tuned at the PER-STRIPE
@@ -308,7 +314,7 @@ def fwi_session_factory(cfg: FWIConfig, time_model: TimeModel,
                         *, seed: int = 0, stripes_for=None,
                         exchange_interval: int | None = 4,
                         scan_block: int = 8,
-                        use_pallas: bool = False,
+                        use_pallas: bool | None = None,
                         autotune: bool = False):
     rng = np.random.default_rng(seed)
 

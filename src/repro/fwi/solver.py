@@ -45,7 +45,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.stencil.ops import pick_k, wave_block, wave_step
+from repro.kernels.stencil.ops import (
+    pick_k,
+    resolves_use_pallas,
+    wave_block,
+    wave_step,
+)
+from repro.launch.mesh import make_mesh
 
 
 @dataclasses.dataclass(frozen=True)
@@ -260,9 +266,11 @@ def _block_scan_body(cfg: FWIConfig, k: int, use_pallas: bool,
     return local_run
 
 
+@resolves_use_pallas
 @functools.lru_cache(maxsize=64)
 def make_block_runner(cfg: FWIConfig, *, k: int | None = None,
-                      use_pallas: bool = False, bz: int | None = None,
+                      use_pallas: bool | None = None,
+                      bz: int | None = None,
                       collect_traces: bool = True,
                       stream: bool | None = None,
                       vmem_budget: int | None = None,
@@ -297,10 +305,11 @@ def make_block_runner(cfg: FWIConfig, *, k: int | None = None,
     return run
 
 
+@resolves_use_pallas
 @functools.lru_cache(maxsize=16)
 def make_shot_parallel_runner(cfg: FWIConfig, n_devices: int, *,
                               k: int | None = None,
-                              use_pallas: bool = False,
+                              use_pallas: bool | None = None,
                               bz: int | None = None,
                               collect_traces: bool = True,
                               stream: bool | None = None,
@@ -335,13 +344,10 @@ def make_shot_parallel_runner(cfg: FWIConfig, n_devices: int, *,
     bitwise; this one trades that for perfect parallel efficiency.)"""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro.compat import shard_map
-
     if k is None:
         k = pick_k(cfg.nz)
     pad = (-cfg.n_shots) % n_devices     # remainder-placement padding
-    mesh = jax.make_mesh((n_devices,), ("shot",),
-                         devices=jax.devices()[:n_devices])
+    mesh = make_mesh((n_devices,), ("shot",))
     pos = cfg.shot_positions()
     if pad:
         pos = np.concatenate([pos, np.repeat(pos[:1], pad, axis=0)])
@@ -362,7 +368,7 @@ def make_shot_parallel_runner(cfg: FWIConfig, n_devices: int, *,
     @functools.partial(jax.jit, static_argnames=("steps",))
     def run(p, p_prev, t0, steps: int):
         p, p_prev = _pad_shots(p), _pad_shots(p_prev)
-        sm = shard_map(
+        sm = jax.shard_map(
             lambda a, b, sz, sx, t: local_run(a, b, sz, sx, t, steps),
             mesh=mesh,
             in_specs=(P("shot"), P("shot"), P("shot"), P("shot"), P()),
@@ -384,7 +390,7 @@ def make_shot_parallel_runner(cfg: FWIConfig, n_devices: int, *,
     return run, place
 
 
-def run_forward(cfg: FWIConfig, *, use_pallas: bool = False,
+def run_forward(cfg: FWIConfig, *, use_pallas: bool | None = None,
                 state: ShotState | None = None,
                 steps: int | None = None, k: int | None = None):
     """Propagate `steps` timesteps (default: to completion) through the

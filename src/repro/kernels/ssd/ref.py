@@ -21,7 +21,7 @@ def ssd_chunk_ref(xdt, b, c, csum):
     diff = csum[..., :, None] - csum[..., None, :]          # (..., Q, Q)
     Q = xdt.shape[-2]
     mask = jnp.tril(jnp.ones((Q, Q), bool))
-    decay = jnp.where(mask, jnp.exp(diff), 0.0)
+    decay = jnp.exp(jnp.where(mask, diff, -jnp.inf))  # no inf·0 in grads
     y = jnp.einsum("...qt,...tp->...qp", (cb * decay).astype(xdt.dtype), xdt)
     to_end = jnp.exp(csum[..., -1:] - csum)                 # (..., Q)
     state = jnp.einsum(

@@ -73,6 +73,28 @@ def default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def resolve_use_pallas(use_pallas: bool | None) -> bool:
+    """The one place ``use_pallas=None`` is decided: the compiled Pallas
+    kernels on TPU, the XLA path everywhere else (where Pallas would only
+    run in interpret mode).  An explicit bool wins."""
+    if use_pallas is None:
+        return jax.default_backend() == "tpu"
+    return bool(use_pallas)
+
+
+def resolves_use_pallas(factory):
+    """Resolve ``use_pallas`` (``resolve_use_pallas``) before the
+    memoized ``factory`` is keyed, so a default and the bool it stands
+    for share one cache entry and one compiled runner."""
+
+    @functools.wraps(factory)
+    def wrapper(*args, use_pallas: bool | None = None, **kwargs):
+        return factory(*args, use_pallas=resolve_use_pallas(use_pallas),
+                       **kwargs)
+
+    return wrapper
+
+
 def _warn_whole_strip(nz: int, cap: int, who: str) -> int:
     warnings.warn(
         f"{who}: nz={nz} has no usable strip divisor <= cap={cap}; "
@@ -210,15 +232,23 @@ def pick_bz_block(nz: int, k: int, cap: int = 128) -> int:
     return _warn_whole_strip(nz, cap, "pick_bz_block") if nz > cap else nz
 
 
+def _lanes(nx: int) -> int:
+    """Row width as VMEM holds it: rows are padded to whole 128-lane
+    tiles, so a 24-column window costs as much as a 128-column one."""
+    return -(-nx // 128) * 128
+
+
 def resident_vmem_bytes(nz: int, nx: int, k: int = 1,
                         bz: int | None = None, s: int = 1) -> int:
     """VMEM footprint of the RESIDENT (whole-array BlockSpec) design:
     ``2·s`` whole (NZ, NX) f32 wavefields plus the TWO shared model
     fields fetched once, the pipeline's double-buffered output strips
-    (per shot) and the trace block.  ``s`` is the shot-batch size — the
-    model-field term is charged ONCE regardless of ``s`` (DESIGN.md §17);
-    ``s=1`` reduces to the classic single-shot accounting."""
+    (per shot) and the trace block, every row lane-padded (``_lanes``).
+    ``s`` is the shot-batch size — the model-field term is charged ONCE
+    regardless of ``s`` (DESIGN.md §17); ``s=1`` reduces to the classic
+    single-shot accounting."""
     bz = min(bz if bz is not None else 128, nz)
+    nx = _lanes(nx)
     return 4 * ((2 * s + 2) * nz * nx + 2 * 2 * s * bz * nx + s * k * nx)
 
 
@@ -227,8 +257,10 @@ def stream_vmem_bytes(nz: int, nx: int, bz: int, k: int, s: int = 1) -> int:
     ``2·s + 2`` (win, NX) haloed f32 windows (``2·s`` shot-tiled
     wavefield windows + ONE shared pair of model-field windows), the
     double-buffered output strips, and the trace block — O(s·bz·NX),
-    independent of NZ.  ``s=1`` reduces to the classic accounting."""
+    independent of NZ, rows lane-padded (``_lanes``).  ``s=1`` reduces
+    to the classic accounting."""
     win = min(bz + 2 * k * HALO, nz)
+    nx = _lanes(nx)
     return 4 * (2 * (2 * s + 2) * win * nx + 2 * 2 * s * bz * nx
                 + s * k * nx)
 
@@ -536,7 +568,7 @@ def wave_block_stream_pallas(
     assert win <= nz, (nz, bz, k)    # no whole-height fallback, ever
     assert stream_vmem_bytes(nz, nx, bz, k) <= budget, (nz, nx, bz, k)
     grid = (nz // bz,)
-    hbm = pl.BlockSpec(memory_space=pltpu.ANY)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     strip = pl.BlockSpec((bz, nx), lambda i: (i, 0))
     srcv = src_vals.reshape(1, k).astype(p.dtype)
     srcp = jnp.stack(
@@ -549,10 +581,12 @@ def wave_block_stream_pallas(
     ]
     kwargs = {}
     if not interpret:
-        # enforce the budget at compile time on real TPUs; interpret
-        # mode has no VMEM, the assert above carries the contract
-        kwargs["compiler_params"] = pltpu.TPUCompilerParams(
-            vmem_limit_bytes=budget
+        # enforce the budget at compile time on real TPUs (twice over:
+        # the trapezoid's temporaries come on top of the buffers the
+        # budget counts); interpret mode has no VMEM, the assert above
+        # carries the contract
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=2 * budget
         )
     return pl.pallas_call(
         functools.partial(
@@ -574,29 +608,87 @@ def wave_block_stream_pallas(
     )(p, p_prev, v2dt2, sponge, srcv, srcp)
 
 
+def _strip_starts(nz: int, bz: int, win: int, k: int) -> list[int]:
+    """First window row of every strip (static): the strip's rows less
+    k·HALO, clamped so the window stays inside the field."""
+    return [min(max(i * bz - k * HALO, 0), nz - win)
+            for i in range(nz // bz)]
+
+
+def _rows_aligned(nz: int, bz: int, win: int, k: int) -> bool:
+    """True when every window starts on an 8-row tile, so Mosaic can
+    prove a dynamic ``pl.ds(start, win)`` row slice aligned."""
+    return all(s % 8 == 0 for s in _strip_starts(nz, bz, win, k))
+
+
+def _check_compiled_geometry(nz: int, nx: int, bz: int, win: int, k: int,
+                             *, stream: bool) -> None:
+    """Refuse, before Mosaic does, a strip geometry the TPU compiler
+    cannot lower: strips must be 8-row tiles (or the whole height), and
+    every window must start on an 8-row tile — which holds when
+    k·HALO and bz are multiples of 8 (k = 4, 8, ...).  The streamed
+    kernel DMAs windows out of HBM, which also needs 8-row windows and
+    128-lane rows."""
+    bad = []
+    if bz % 8 and bz != nz:
+        bad.append(f"bz={bz} is not a multiple of 8")
+    if win != nz and not _rows_aligned(nz, bz, win, k):
+        bad.append(f"window starts are not 8-row aligned (k·HALO="
+                   f"{k * HALO}, bz={bz}, nz={nz})")
+    if stream and win % 8:
+        bad.append(f"window height {win} is not a multiple of 8")
+    if stream and nx % 128:
+        bad.append(f"nx={nx} is not a multiple of 128")
+    if bad:
+        raise ValueError("compiled stencil kernel: " + "; ".join(bad))
+
+
+def _receiver_window_row(rrow: int, nz: int, bz: int, win: int,
+                         k: int) -> tuple[int, int] | None:
+    """(strip that owns the receiver row, that row's index inside the
+    strip's window), or None when the row is outside the field."""
+    if not 0 <= rrow < nz:
+        return None
+    owner = rrow // bz
+    return owner, rrow - _strip_starts(nz, bz, win, k)[owner]
+
+
+def _store_strip(out_ref, window, row0, start, *, nz: int, bz: int,
+                 win: int, k: int):
+    """Store the owned rows of a (S, win, NX) window value — the bz rows
+    from ``row0 - start`` — into ``out_ref``.  That offset is traced but
+    takes only the few static values ``_strip_starts`` allows, so each
+    is one branch with a static slice (the TPU has no dynamic row slice
+    of a value)."""
+    offsets = {i * bz - s for i, s in enumerate(_strip_starts(nz, bz, win, k))}
+    for o in sorted(offsets):
+        @pl.when(row0 - start == o)
+        def _store(o=o):
+            out_ref[...] = window[:, o: o + bz, :]
+
+
 def _trapezoid_k_steps_shots(
-    cur, prevd, vw, sw, srcv_ref, srcp_ref, tr_ref,
-    *, start, row0, win: int, nx: int, bz: int, k: int, rrow: int,
+    cur, prevd, vw, sw, srcv_ref, srcz_ref, srcx_ref, tr_ref,
+    *, start, i, win: int, nx: int, k: int, recv,
 ):
     """k fused leapfrog steps on an (S, win, NX) shot-batched window.
 
-    The shot-batched twin of ``_trapezoid_k_steps``, shared by BOTH
-    batched block kernels (resident and streamed): identical trapezoid
-    math vectorized over the leading shot axis, with the shared model
-    windows ``vw``/``sw`` kept 2-D — (win, NX) — and broadcast across
+    The shared trapezoid body of BOTH batched block kernels (resident
+    and streamed): per inner step, zero-extend in z, 4th-order Laplacian
+    (z-rings from the extension, x-rings via ``_shift_x``), leapfrog +
+    sponge, per-shot source injection, and receiver-row capture into
+    ``tr_ref`` by the program owning the receiver strip.  The model
+    windows ``vw``/``sw`` stay 2-D — (win, NX) — and broadcast across
     shots, so the model fields are read once per strip no matter how
-    many shots ride the batch (DESIGN.md §17).  Source injection and
-    receiver capture are per-shot: ``srcp_ref`` is (S, 2) int32 rows /
-    columns, ``srcv_ref`` is (S, k) amplitudes."""
+    many shots ride the batch (DESIGN.md §17).  ``srcz_ref``/``srcx_ref``
+    are (S, 1, 1) int32 source rows/columns, ``srcv_ref`` is (S, 1, k)
+    amplitudes; ``recv`` is ``_receiver_window_row``'s answer."""
     ns = cur.shape[0]
-    zi = srcp_ref[:, 0]                       # (S,) per-shot source row
-    xi = srcp_ref[:, 1]                       # (S,) per-shot source col
     iz = jax.lax.broadcasted_iota(jnp.int32, (ns, win, nx), 1)
     ix = jax.lax.broadcasted_iota(jnp.int32, (ns, win, nx), 2)
-    zsel = (zi - start)[:, None, None]
-    xsel = xi[:, None, None]
+    # each shot's source point, fixed across the k steps
+    hit = (iz == srcz_ref[...] - start) & (ix == srcx_ref[...])
     zero_h = jnp.zeros((ns, HALO, nx), cur.dtype)
-    own_receiver = (rrow >= row0) & (rrow < row0 + bz)
 
     for j in range(k):
         ext = jnp.concatenate([zero_h, cur, zero_h], axis=1)
@@ -609,15 +701,14 @@ def _trapezoid_k_steps_shots(
         lap += C2 * (_shift_x(cur, 2, nx) + _shift_x(cur, -2, nx))
         pn = (2.0 * cur - prevd + vw * lap) * sw
         # epilogue: per-shot source injection + receiver-row capture
-        pn = pn + jnp.where(
-            (iz == zsel) & (ix == xsel), srcv_ref[:, j][:, None, None], 0.0
-        )
+        pn = pn + jnp.where(hit, srcv_ref[:, :, j: j + 1], 0.0)
 
-        @pl.when(own_receiver)
-        def _capture(pn=pn, j=j):
-            tr_ref[:, j, :] = jax.lax.dynamic_slice_in_dim(
-                pn, rrow - start, 1, axis=1
-            )[:, 0, :]
+        if recv is not None:
+            owner, r = recv
+
+            @pl.when(i == owner)
+            def _capture(pn=pn, j=j, r=r):
+                tr_ref[:, j: j + 1, :] = pn[:, r: r + 1, :]
 
         prevd = cur * sw
         cur = pn
@@ -625,46 +716,54 @@ def _trapezoid_k_steps_shots(
 
 
 def _wave_block_shots_kernel(
-    p_ref, pp_ref, v2dt2_ref, sponge_ref, srcv_ref, srcp_ref,
+    p_ref, pp_ref, v2dt2_ref, sponge_ref, srcv_ref, srcz_ref, srcx_ref,
     p_out_ref, pp_out_ref, tr_ref,
     *, bz: int, win: int, k: int, rrow: int,
 ):
-    """Shot-batched ``_wave_block_kernel``: each program owns an
-    (S, bz, NX) strip and computes the k-step trapezoid on (S, win, NX)
-    windows sliced from the resident wavefields, while the model fields
-    stay 2-D and are sliced ONCE per strip for all shots."""
+    """Shot-batched k-step trapezoid (ghost-zone temporal blocking):
+    each program owns an (S, bz, NX) strip and computes on (S, win, NX)
+    windows, ``win = bz + 2·k·HALO`` clamped to NZ, sliced from the
+    resident wavefields, while the model fields stay 2-D and are sliced
+    ONCE per strip for all shots.  Every inner step zero-extends the
+    window in z: at a physical edge that IS the boundary condition; at
+    an interior window edge the wrong value creeps inward HALO rows per
+    step, so after k steps exactly the owned strip is clean.  Source
+    injection, sponge damping and receiver capture run in the step
+    epilogue, so k launches collapse into one pallas_call
+    (DESIGN.md §13)."""
     i = pl.program_id(0)
     nz = p_ref.shape[1]
     nx = p_ref.shape[2]
     row0 = i * bz
     start = jnp.clip(row0 - k * HALO, 0, nz - win)
-    off = row0 - start          # strip offset inside the window
+    if _rows_aligned(nz, bz, win, k):
+        start = pl.multiple_of(start, 8)
 
     cur = p_ref[:, pl.ds(start, win), :]
     prevd = pp_ref[:, pl.ds(start, win), :]   # already sponge-damped
     vw = v2dt2_ref[pl.ds(start, win), :]      # shared across shots
     sw = sponge_ref[pl.ds(start, win), :]
     cur, prevd = _trapezoid_k_steps_shots(
-        cur, prevd, vw, sw, srcv_ref, srcp_ref, tr_ref,
-        start=start, row0=row0, win=win, nx=nx, bz=bz, k=k, rrow=rrow,
+        cur, prevd, vw, sw, srcv_ref, srcz_ref, srcx_ref, tr_ref,
+        start=start, i=i, win=win, nx=nx, k=k,
+        recv=_receiver_window_row(rrow, nz, bz, win, k),
     )
-
-    p_out_ref[...] = jax.lax.dynamic_slice_in_dim(cur, off, bz, axis=1)
-    pp_out_ref[...] = jax.lax.dynamic_slice_in_dim(prevd, off, bz, axis=1)
+    geometry = dict(nz=nz, bz=bz, win=win, k=k)
+    _store_strip(p_out_ref, cur, row0, start, **geometry)
+    _store_strip(pp_out_ref, prevd, row0, start, **geometry)
 
 
 def _norm_src_shots(src_vals, src_z, src_x, ns: int, dtype):
-    """Normalize batched source args: (k,)-or-(S, k) amplitudes to
-    (S, k), per-shot positions to an (S, 2) int32 block."""
+    """Normalize batched source args for the kernels: (k,)-or-(S, k)
+    amplitudes to an (S, 1, k) block, per-shot positions to (S, 1, 1)
+    int32 blocks (last two dims whole, so each is one VMEM tile)."""
     srcv = jnp.asarray(src_vals, dtype)
     if srcv.ndim == 1:
         srcv = jnp.broadcast_to(srcv, (ns, srcv.shape[0]))
-    srcp = jnp.stack(
-        [jnp.broadcast_to(jnp.asarray(src_z, jnp.int32), (ns,)),
-         jnp.broadcast_to(jnp.asarray(src_x, jnp.int32), (ns,))],
-        axis=1,
-    )
-    return srcv, srcp
+    srcz = jnp.broadcast_to(jnp.asarray(src_z, jnp.int32), (ns,))
+    srcx = jnp.broadcast_to(jnp.asarray(src_x, jnp.int32), (ns,))
+    return (srcv.reshape(ns, 1, -1), srcz.reshape(ns, 1, 1),
+            srcx.reshape(ns, 1, 1))
 
 
 @functools.partial(
@@ -702,11 +801,14 @@ def wave_block_shots_pallas(
     win = min(bz + 2 * k * HALO, nz)
     assert nz % bz == 0, (nz, bz)
     assert bz == nz or bz + 2 * k * HALO <= nz, (nz, bz, k)
+    if not interpret:
+        _check_compiled_geometry(nz, nx, bz, win, k, stream=False)
     grid = (nz // bz,)
     whole3 = pl.BlockSpec((ns, nz, nx), lambda i: (0, 0, 0))  # fetched once
     whole2 = pl.BlockSpec((nz, nx), lambda i: (0, 0))         # model fields
     strip3 = pl.BlockSpec((ns, bz, nx), lambda i: (0, i, 0))
-    srcv, srcp = _norm_src_shots(src_vals, src_z, src_x, ns, p.dtype)
+    src = pl.BlockSpec((ns, 1, 1), lambda i: (0, 0, 0))
+    srcv, srcz, srcx = _norm_src_shots(src_vals, src_z, src_x, ns, p.dtype)
     out_shape = [
         jax.ShapeDtypeStruct((ns, nz, nx), p.dtype),
         jax.ShapeDtypeStruct((ns, nz, nx), p.dtype),
@@ -719,17 +821,16 @@ def wave_block_shots_pallas(
         ),
         grid=grid,
         in_specs=[whole3, whole3, whole2, whole2,
-                  pl.BlockSpec((ns, k), lambda i: (0, 0)),
-                  pl.BlockSpec((ns, 2), lambda i: (0, 0))],
+                  pl.BlockSpec((ns, 1, k), lambda i: (0, 0, 0)), src, src],
         out_specs=[strip3, strip3,
                    pl.BlockSpec((ns, k, nx), lambda i: (0, 0, 0))],
         out_shape=out_shape,
         interpret=interpret,
-    )(p, p_prev, v2dt2, sponge, srcv, srcp)
+    )(p, p_prev, v2dt2, sponge, srcv, srcz, srcx)
 
 
 def _wave_block_shots_stream_kernel(
-    p_hbm, pp_hbm, v_hbm, s_hbm, srcv_ref, srcp_ref,
+    p_hbm, pp_hbm, v_hbm, s_hbm, srcv_ref, srcz_ref, srcx_ref,
     p_out_ref, pp_out_ref, tr_ref, fwin_buf, mwin_buf, fsems, msems,
     *, bz: int, win: int, k: int, rrow: int,
 ):
@@ -741,16 +842,20 @@ def _wave_block_shots_stream_kernel(
     fields get their own (2, 2, win, NX) scratch — one (win, NX) window
     per field per slot, DMA'd ONCE per strip and reused by every shot
     in the batch, which is exactly the traffic the shot batch exists to
-    amortize (DESIGN.md §17).  Prefetch discipline is identical to
-    ``_wave_block_stream_kernel``: strip i starts strip i+1's fetch
-    into the other slot before waiting on its own."""
+    amortize (DESIGN.md §17).  Grid step i starts strip i+1's fetch
+    into the other slot before waiting on its own, so the next window
+    flies over this strip's k-step compute (DESIGN.md §15); the
+    trapezoid math is ``_trapezoid_k_steps_shots``, shared with the
+    resident kernel."""
     i = pl.program_id(0)
     n = pl.num_programs(0)
     nz = p_hbm.shape[1]
     nx = p_hbm.shape[2]
+    aligned = _rows_aligned(nz, bz, win, k)
 
     def win_start(strip):
-        return jnp.clip(strip * bz - k * HALO, 0, nz - win)
+        start = jnp.clip(strip * bz - k * HALO, 0, nz - win)
+        return pl.multiple_of(start, 8) if aligned else start
 
     def dma(slot, strip):
         start = win_start(strip)
@@ -788,15 +893,16 @@ def _wave_block_shots_stream_kernel(
 
     row0 = i * bz
     start = win_start(i)
-    off = row0 - start               # strip offset inside the window
     cur, prevd = _trapezoid_k_steps_shots(
         fwin_buf[slot, 0], fwin_buf[slot, 1],
         mwin_buf[slot, 0], mwin_buf[slot, 1],
-        srcv_ref, srcp_ref, tr_ref,
-        start=start, row0=row0, win=win, nx=nx, bz=bz, k=k, rrow=rrow,
+        srcv_ref, srcz_ref, srcx_ref, tr_ref,
+        start=start, i=i, win=win, nx=nx, k=k,
+        recv=_receiver_window_row(rrow, nz, bz, win, k),
     )
-    p_out_ref[...] = jax.lax.dynamic_slice_in_dim(cur, off, bz, axis=1)
-    pp_out_ref[...] = jax.lax.dynamic_slice_in_dim(prevd, off, bz, axis=1)
+    geometry = dict(nz=nz, bz=bz, win=win, k=k)
+    _store_strip(p_out_ref, cur, row0, start, **geometry)
+    _store_strip(pp_out_ref, prevd, row0, start, **geometry)
 
 
 @functools.partial(
@@ -838,9 +944,10 @@ def wave_block_shots_stream_pallas(
     assert stream_vmem_bytes(nz, nx, bz, k, s=ns) <= budget, \
         (nz, nx, bz, k, ns)
     grid = (nz // bz,)
-    hbm = pl.BlockSpec(memory_space=pltpu.ANY)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     strip3 = pl.BlockSpec((ns, bz, nx), lambda i: (0, i, 0))
-    srcv, srcp = _norm_src_shots(src_vals, src_z, src_x, ns, p.dtype)
+    src = pl.BlockSpec((ns, 1, 1), lambda i: (0, 0, 0))
+    srcv, srcz, srcx = _norm_src_shots(src_vals, src_z, src_x, ns, p.dtype)
     out_shape = [
         jax.ShapeDtypeStruct((ns, nz, nx), p.dtype),
         jax.ShapeDtypeStruct((ns, nz, nx), p.dtype),
@@ -848,8 +955,11 @@ def wave_block_shots_stream_pallas(
     ]
     kwargs = {}
     if not interpret:
-        kwargs["compiler_params"] = pltpu.TPUCompilerParams(
-            vmem_limit_bytes=budget
+        _check_compiled_geometry(nz, nx, bz, win, k, stream=True)
+        # the budget counts the buffers; the k-step trapezoid's
+        # temporaries come on top (DESIGN.md §15)
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=2 * budget
         )
     return pl.pallas_call(
         functools.partial(
@@ -858,8 +968,7 @@ def wave_block_shots_stream_pallas(
         ),
         grid=grid,
         in_specs=[hbm, hbm, hbm, hbm,
-                  pl.BlockSpec((ns, k), lambda i: (0, 0)),
-                  pl.BlockSpec((ns, 2), lambda i: (0, 0))],
+                  pl.BlockSpec((ns, 1, k), lambda i: (0, 0, 0)), src, src],
         out_specs=[strip3, strip3,
                    pl.BlockSpec((ns, k, nx), lambda i: (0, 0, 0))],
         out_shape=out_shape,
@@ -871,7 +980,7 @@ def wave_block_shots_stream_pallas(
         ],
         interpret=interpret,
         **kwargs,
-    )(p, p_prev, v2dt2, sponge, srcv, srcp)
+    )(p, p_prev, v2dt2, sponge, srcv, srcz, srcx)
 
 
 def pick_shot_tile(n_shots: int, nz: int, nx: int, k: int, *,
@@ -887,21 +996,27 @@ def pick_shot_tile(n_shots: int, nz: int, nx: int, k: int, *,
     so no tile is ever ragged by default (explicit ``shot_tile`` args
     may still be unaligned — the dispatch handles the remainder tile).
     Always ≥ 1: a single shot that cannot fit resident is the streamed
-    path's problem (``should_stream``), not the tile picker's."""
+    path's problem (``should_stream``), not the tile picker's.  Tiles
+    whose strip is a whole number of 8-row tiles win over larger ones
+    whose strip is not: the compiled kernel needs such strips
+    (``_check_compiled_geometry``)."""
     budget = vmem_budget if vmem_budget is not None else DEFAULT_VMEM_BUDGET
 
-    def fits(t: int) -> bool:
+    def strip(t: int) -> int | None:
+        """Strip height the tile runs at, None when it does not fit."""
         if stream:
             try:
-                pick_bz_stream(nz, nx, k, vmem_budget=budget, s=t)
-                return True
+                return pick_bz_stream(nz, nx, k, vmem_budget=budget, s=t)
             except ValueError:
-                return False
+                return None
         b = bz if bz is not None else pick_bz_block(nz, k)
-        return resident_vmem_bytes(nz, nx, k, bz=b, s=t) <= budget
+        return b if resident_vmem_bytes(nz, nx, k, bz=b, s=t) <= budget \
+            else None
 
-    ok = [t for t in range(1, n_shots + 1) if n_shots % t == 0 and fits(t)]
-    return max(ok) if ok else 1
+    strips = {t: strip(t) for t in range(1, n_shots + 1) if n_shots % t == 0}
+    ok = [t for t, b in strips.items() if b is not None]
+    aligned = [t for t in ok if strips[t] % 8 == 0 or strips[t] == nz]
+    return max(aligned or ok, default=1)
 
 
 def _tune_backend(backend: str | None) -> str:

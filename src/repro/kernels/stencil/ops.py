@@ -12,7 +12,9 @@ from the backend through the ONE shared helper ``default_interpret``
 (compiled on TPU; interpret mode elsewhere, where the kernel body still
 executes with real Pallas semantics, validating BlockSpec tiling /
 trapezoid logic).  use_pallas=False is the pure-jnp path used on
-CPU/GPU: for ``wave_block`` it is the jitted k-step fused body
+CPU/GPU, and ``use_pallas=None`` picks between the two through
+``resolve_use_pallas`` (the kernels on TPU, XLA elsewhere): for
+``wave_block`` the XLA path is the jitted k-step fused body
 (``wave_block_ref``), BIT-IDENTICAL to k sequential reference steps;
 the Pallas block matches to documented `allclose` tolerance (its z/x
 stencil accumulation order differs).
@@ -31,6 +33,8 @@ from repro.kernels.stencil.kernel import (
     pick_bz_stream,
     pick_k,
     pick_shot_tile,
+    resolve_use_pallas,
+    resolves_use_pallas,
     should_stream,
     wave_block_pallas,
     wave_block_shots_pallas,
@@ -54,7 +58,8 @@ __all__ = [
     "wave_block_shots_ref", "wave_block_shots_strips_ref",
     "autotune_bz", "autotune_bz_k", "default_interpret",
     "pick_bz", "pick_bz_block", "pick_bz_stream", "pick_k",
-    "pick_shot_tile", "should_stream",
+    "pick_shot_tile", "resolve_use_pallas", "resolves_use_pallas",
+    "should_stream",
 ]
 
 
@@ -136,7 +141,7 @@ def _wave_block_shots_tiled(
 
 
 def wave_block(p, p_prev, v2dt2, sponge, src_vals, src_z, src_x, *,
-               receiver_row: int = 0, use_pallas: bool = False,
+               receiver_row: int = 0, use_pallas: bool | None = None,
                bz: int | None = None, interpret: bool | None = None,
                stream: bool | None = None,
                vmem_budget: int | None = None,
@@ -169,6 +174,7 @@ def wave_block(p, p_prev, v2dt2, sponge, src_vals, src_z, src_x, *,
     per-strip working set — so both backends share one capacity story."""
     k = int(src_vals.shape[-1])
     nz, nx = p.shape[-2], p.shape[-1]
+    use_pallas = resolve_use_pallas(use_pallas)
     if stream is None:
         stream = should_stream(nz, nx, k, vmem_budget=vmem_budget)
     if p.ndim == 3:

@@ -8,7 +8,7 @@ before any jax import; everything else sees the real device count.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def _n(shape) -> int:
@@ -18,15 +18,24 @@ def _n(shape) -> int:
     return n
 
 
+def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...],
+              devices=None) -> Mesh:
+    """The one mesh builder: the first ``prod(shape)`` devices (or
+    ``devices``) with every axis Auto.  The installed JAX defaults to
+    Explicit axes, which reject ``with_sharding_constraint``, ambiguous
+    gathers and uneven slices — everything here is written for the
+    compiler-propagated (Auto) sharding."""
+    if devices is None:
+        devices = jax.devices()[: _n(shape)]
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     """Single-pod (16,16) ("data","model") or 2-pod (2,16,16)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, devices=jax.devices()[: _n(shape)])
-
-
-def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> Mesh:
-    return jax.make_mesh(shape, axes, devices=jax.devices()[: _n(shape)])
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model: int | None = None) -> Mesh:
@@ -35,7 +44,7 @@ def make_host_mesh(model: int | None = None) -> Mesh:
     model = model or 1
     if n % model:
         model = 1
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))
 
 
 def chips(mesh: Mesh) -> int:

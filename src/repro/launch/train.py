@@ -45,7 +45,9 @@ def build_session(cfg, run, mesh, steps_total):
     return opt, sch, shardings, step_fn, rules
 
 
-def main(argv=None):
+def main(argv=None) -> dict:
+    """Train; returns ``{"loss": [...], "step_s": [...]}`` for the steps
+    this call ran (the first step's time includes compilation)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -86,7 +88,9 @@ def main(argv=None):
         state = {
             "params": params,
             "opt": jax.jit(opt.init, out_shardings=shardings["opt"])(params),
-            "step": jnp.zeros((), jnp.int32),
+            # placed like the step's output, or step 2 recompiles
+            "step": jax.device_put(jnp.zeros((), jnp.int32),
+                                   shardings["step"]),
         }
 
     monitor = StepTimeMonitor()
@@ -94,12 +98,15 @@ def main(argv=None):
         DeadlinePredictor(args.deadline) if args.deadline else None
     )
     t_start = time.monotonic()
+    losses, step_s = [], []
     for step in range(start_step, args.steps):
         batch = pipeline.batch_at(step)
         t0 = time.monotonic()
         state, metrics = step_fn(state, batch)
         loss = float(metrics["loss"])  # blocks
         dt = time.monotonic() - t0
+        losses.append(loss)
+        step_s.append(dt)
         monitor.observe(dt)
         pipeline.state.step = step + 1
         if mgr and (step + 1) % args.ckpt_every == 0:
@@ -121,6 +128,7 @@ def main(argv=None):
         mgr.save(args.steps, state, extra=pipeline.state.to_extra(),
                  wait=True)
     print(f"[train] done in {time.monotonic() - t_start:.1f}s")
+    return {"loss": losses, "step_s": step_s}
 
 
 if __name__ == "__main__":

@@ -189,7 +189,9 @@ def ssd_chunked(xs, bs, cs, dt, dA, *, chunk: int, n_heads: int):
     diff = csum[:, :, :, None, :] - csum[:, :, None, :, :]      # (B,nc,Q,Q,H)
     diff = jnp.moveaxis(diff, -1, 2)                            # (B,nc,H,Q,Q)
     mask = jnp.tril(jnp.ones((Q, Q), bool))
-    decay = jnp.where(mask, jnp.exp(diff), 0.0)
+    # mask before exp: above the diagonal diff > 0 overflows at long
+    # chunks, and exp's gradient there (inf · 0) would be NaN
+    decay = jnp.exp(jnp.where(mask, diff, -jnp.inf))
     y_intra = jnp.einsum(
         "bchqt,bcthp->bcqhp", (cb * decay).astype(dt_c), xdt
     )

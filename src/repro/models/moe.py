@@ -17,7 +17,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
 from repro.configs.base import ModelConfig
 from repro.sharding.rules import current_rules, normal_param, param, shard
 
@@ -286,7 +285,7 @@ def apply_moe_ep(cfg: ModelConfig, p, x: jax.Array):
         z = jax.lax.pmean(z, batch_axes)
         return y, lb, z
 
-    y, lb, z = shard_map(
+    y, lb, z = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(

@@ -21,7 +21,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size
 
 CBLOCK = 256
 
@@ -56,7 +55,7 @@ def cross_pod_reduce(grads, axis: str = "pod", method: str = "int8"):
         return jax.tree.map(lambda g: jax.lax.psum(g, axis), grads)
 
     def reduce_leaf(g):
-        npods = axis_size(axis)
+        npods = jax.lax.axis_size(axis)
         acc = g.astype(jnp.float32)
         q, scale, n = _q8(g.astype(jnp.float32))
         for hop in range(1, npods):

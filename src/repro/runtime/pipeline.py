@@ -31,13 +31,18 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import configure_partial_auto, mesh_and_manual, shard_map
 from repro.configs.base import BlockDef, ModelConfig, RunConfig
 from repro.models import model as M
 from repro.models.layers import apply_norm, embed_tokens
 from repro.models.transformer import apply_block_full
 from repro.optim import Optimizer
-from repro.sharding.rules import AxisRules, axis_rules, is_spec, shard
+from repro.sharding.rules import (
+    AxisRules,
+    axis_rules,
+    is_spec,
+    mesh_and_manual,
+    shard,
+)
 
 
 def pipeline_compatible(cfg: ModelConfig) -> bool:
@@ -75,10 +80,6 @@ def build_pipeline_train_step(
 ):
     """Returns (step_fn, state_in_specs) — step_fn(state, batch) with the
     state's block params stage-sharded over 'pod'."""
-    # this builder constructs a grad-of-scan inside a partial-auto
-    # region — opt into the partitioner that can compile it on legacy
-    # JAX (no-op on jax.shard_map-native versions)
-    configure_partial_auto()
     assert pipeline_compatible(cfg), cfg.name
     mesh = rules.mesh
     stages = mesh.shape.get("pod", 1)
@@ -102,7 +103,7 @@ def build_pipeline_train_step(
         # manual over pod: params['b0'] holds THIS stage's layer slice;
         # sid arrives as data (a P("pod")-sharded arange) rather than
         # lax.axis_index — partition-id lowering is not portable across
-        # partitioners (see compat.configure_partial_auto)
+        # partitioners
         tokens = batch["tokens"]                   # (B, S) pod-replicated
         mask = batch.get("loss_mask")
         if mask is None:
@@ -177,11 +178,9 @@ def build_pipeline_train_step(
             from repro.sharding.rules import param_pspecs
 
             pspecs = param_pspecs(M.schema(cfg), inner_rules)
-            am, _, constrainable = mesh_and_manual(mesh)
+            am, _ = mesh_and_manual()
 
             def constrain(x, spec):
-                if not constrainable:
-                    return x
                 return jax.lax.with_sharding_constraint(
                     x, NamedSharding(am, spec)
                 )
@@ -223,7 +222,7 @@ def build_pipeline_train_step(
     def step(state, batch):
         batch_specs = jax.tree.map(lambda _: P(), batch)
         sid_in = jnp.arange(stages, dtype=jnp.int32)
-        return shard_map(
+        return jax.shard_map(
             inner,
             mesh=mesh,
             in_specs=(state_specs, batch_specs, P("pod")),

@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import configure_partial_auto, shard_map
 from repro.configs.base import ModelConfig, RunConfig
 from repro.models import model as M
 from repro.optim import Optimizer
@@ -211,9 +210,6 @@ def build_compressed_train_step(
 ):
     """Requires a mesh with a 'pod' axis.  Gradients cross the pod boundary
     as int8; everything else stays automatically sharded (data/model)."""
-    # grad-of-scan inside a partial-auto region: opt into the
-    # partitioner that can compile it on legacy JAX (no-op otherwise)
-    configure_partial_auto()
     mesh = rules.mesh
     assert "pod" in mesh.shape, "compressed step needs a 'pod' mesh axis"
     npods = mesh.shape["pod"]
@@ -251,7 +247,7 @@ def build_compressed_train_step(
         batch_specs = jax.tree.map(
             lambda x: P("pod") if x.ndim else P(), batch
         )
-        return shard_map(
+        return jax.shard_map(
             inner,
             mesh=mesh,
             in_specs=(state_specs, batch_specs),

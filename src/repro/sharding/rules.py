@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import mesh_and_manual
 
 # ---------------------------------------------------------------------------
 # Rule tables
@@ -203,6 +202,18 @@ def current_rules() -> AxisRules | None:
     return getattr(_CTX, "rules", None)
 
 
+def mesh_and_manual():
+    """(abstract mesh, its Manual-typed axis names) of the current trace
+    context — inside a shard_map region the manual axes are the ones a
+    sharding constraint must leave out."""
+    am = jax.sharding.get_abstract_mesh()
+    manual = {
+        name for name, t in zip(am.axis_names, am.axis_types)
+        if t == jax.sharding.AxisType.Manual
+    }
+    return am, manual
+
+
 def shard(x: jax.Array, *logical_axes: str | None) -> jax.Array:
     """Constrain an activation's sharding; no-op outside an axis_rules ctx.
 
@@ -215,9 +226,7 @@ def shard(x: jax.Array, *logical_axes: str | None) -> jax.Array:
     if rules is None:
         return x
     spec = rules.spec(logical_axes, x.shape)
-    am, manual, constrainable = mesh_and_manual(rules.mesh)
-    if not constrainable:
-        return x
+    am, manual = mesh_and_manual()
     if manual:
         parts = []
         for p_ in tuple(spec):

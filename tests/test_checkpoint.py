@@ -89,7 +89,9 @@ def test_restore_latest_and_missing_leaf_error(tmp_path):
 def test_restore_with_shardings_places_on_device(tmp_path):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    mesh = jax.make_mesh((1,), ("data",))
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((1,), ("data",))
     sh = NamedSharding(mesh, P())
     state = {"w": jnp.arange(16.0).reshape(4, 4)}
     m = CheckpointManager(tmp_path, async_save=False)

@@ -29,6 +29,7 @@ from repro.checkpoint.manager import CheckpointManager
 from repro.configs import RunConfig, get_config, smoke_config
 from repro.configs.shapes import ShapeConfig
 from repro.data.pipeline import SyntheticLMPipeline
+from repro.launch.mesh import make_mesh
 from repro.optim import constant, make_optimizer
 from repro.runtime.train_step import (
     batch_shardings, build_train_step, state_schema, state_shardings,
@@ -43,8 +44,7 @@ sch = state_schema(cfg, run, opt)
 pipe = SyntheticLMPipeline(cfg, shape)
 
 def session(mesh_shape, axes):
-    mesh = jax.make_mesh(mesh_shape, axes,
-                         devices=jax.devices()[: int(np.prod(mesh_shape))])
+    mesh = make_mesh(mesh_shape, axes)
     rules = make_rules(mesh, "train")
     sh = state_shardings(sch, rules, run)
     fn = jax.jit(build_train_step(cfg, run, opt, rules))
@@ -106,8 +106,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs import RunConfig, get_config, smoke_config
 from repro.configs.shapes import ShapeConfig
 from repro.data.pipeline import SyntheticLMPipeline
-from repro.compat import configure_partial_auto, shard_map
-configure_partial_auto()
+from repro.launch.mesh import make_mesh
 from repro.optim.compression import cross_pod_reduce
 from repro.runtime.train_step import batch_shardings, compute_grads
 from repro.sharding.rules import axis_rules, init_params, make_rules
@@ -115,7 +114,7 @@ from repro.sharding.rules import axis_rules, init_params, make_rules
 cfg = smoke_config(get_config("yi-6b"))
 run = RunConfig(loss_chunk=32)
 shape = ShapeConfig("t", "train", 32, 8)
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 rules = make_rules(mesh, "train")
 inner_rules = dataclasses.replace(
     rules, rules={**rules.rules, "batch": (("data",),)})
@@ -145,7 +144,7 @@ def make_manual(method):
     def f(p, b):
         pspec = jax.tree.map(lambda _: P(), p)
         bspec = jax.tree.map(lambda x: P("pod") if x.ndim else P(), b)
-        return shard_map(inner, mesh=mesh, in_specs=(pspec, bspec),
+        return jax.shard_map(inner, mesh=mesh, in_specs=(pspec, bspec),
                          out_specs=pspec, axis_names={"pod"},
                          check_vma=False)(p, b)
     return jax.jit(f)
@@ -232,9 +231,8 @@ from repro.configs import RunConfig, get_config, smoke_config
 from repro.configs.base import BlockDef
 from repro.configs.shapes import ShapeConfig
 from repro.data.pipeline import SyntheticLMPipeline
+from repro.launch.mesh import make_mesh
 from repro.optim import constant, make_optimizer
-from repro.compat import configure_partial_auto
-configure_partial_auto()
 from repro.runtime.pipeline import build_pipeline_train_step
 from repro.runtime.train_step import build_train_step, state_schema
 from repro.sharding.rules import init_params, make_rules
@@ -249,7 +247,7 @@ run = RunConfig(loss_chunk=32, pipeline_stages=2, pp_microbatches=4)
 shape = ShapeConfig("t", "train", 32, 8)
 opt = make_optimizer("adamw", constant(1e-3))
 sch = state_schema(cfg, run, opt)
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 rules = make_rules(mesh, "train")
 pipe = SyntheticLMPipeline(cfg, shape)
 

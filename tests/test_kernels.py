@@ -122,6 +122,29 @@ def test_ssd_chunked_equals_naive_recurrence():
     np.testing.assert_allclose(state_fast, h, atol=5e-5)
 
 
+def test_ssd_chunked_grads_finite_at_long_chunks():
+    """At mamba2-370m's chunk (256) with its fastest-decaying head, the
+    cumulative decay spans hundreds of e-folds: exp() above the causal
+    diagonal overflows, and its gradient must not turn inf·0 into NaN."""
+    from repro.models.mamba2 import ssd_chunked
+
+    B_, S, H, P, N, chunk = 1, 256, 2, 8, 8, 256
+    ks = jax.random.split(jax.random.key(1), 4)
+    xs = jax.random.normal(ks[0], (B_, S, H, P), jnp.float32)
+    bs = jax.random.normal(ks[1], (B_, S, 1, N), jnp.float32)
+    cs = jax.random.normal(ks[2], (B_, S, 1, N), jnp.float32)
+    dt = jnp.full((B_, S, H), 0.1, jnp.float32)
+    A = -jnp.array([1.0, 32.0])          # A_log init spans log(1..H)
+
+    def loss(xs, dt):
+        y, st = ssd_chunked(xs, bs, cs, dt, dt * A, chunk=chunk, n_heads=H)
+        return jnp.sum(y) + jnp.sum(st)
+
+    gx, gdt = jax.grad(loss, argnums=(0, 1))(xs, dt)
+    assert np.isfinite(np.asarray(gx)).all()
+    assert np.isfinite(np.asarray(gdt)).all()
+
+
 # ----------------------------------------------------------------- rmsnorm
 
 

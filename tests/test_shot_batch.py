@@ -167,12 +167,13 @@ def test_dispatch_pallas_shot_tile(tile):
 
 def test_vmem_formulas_reduce_at_s1():
     nz, nx, bz, k = 600, 600, 120, 8
+    lanes = 640                  # VMEM pads each 600-float row to 5×128
     # the pre-§17 single-shot accounting, written out long-hand
     assert resident_vmem_bytes(nz, nx, k, bz=bz) == \
-        4 * (4 * nz * nx + 4 * bz * nx + k * nx)
+        4 * (4 * nz * lanes + 4 * bz * lanes + k * lanes)
     win = min(bz + 2 * k * HALO, nz)
     assert stream_vmem_bytes(nz, nx, bz, k) == \
-        4 * (2 * 4 * win * nx + 4 * bz * nx + k * nx)
+        4 * (2 * 4 * win * lanes + 4 * bz * lanes + k * lanes)
 
 
 def test_vmem_monotone_in_s():
