@@ -55,6 +55,7 @@ from repro.core.allocator import (
 from repro.core.deadline import DeadlineEstimate, DeadlinePredictor
 from repro.core.monitor import StepTimeMonitor
 from repro.core.planner import BurstDecision, BurstPlanner
+from repro.core.spans import span
 
 #: pod-name prefixes that mark a pod as elastic (cloud-side, scalable);
 #: everything else is the fixed on-premise allocation.
@@ -338,7 +339,8 @@ class ElasticOrchestrator:
                 cloud_chip_s += (
                     elastic_chips(res) * self.planner.overheads.restart_s
                 )
-                session = session_factory(res, restart, last_ckpt)
+                with span("orch.transition", kind="failure", step=step):
+                    session = session_factory(res, restart, last_ckpt)
                 self.monitor.reset_window()
                 step = restart
                 continue
@@ -375,117 +377,127 @@ class ElasticOrchestrator:
             elif step % self.check_every or step >= steps_total:
                 continue
 
-            est = self.predictor.estimate(
-                self.monitor, step, steps_total, elapsed
-            )
-            eff_chips = sum(p.chips / p.slowdown for p in res.pods)
-            if autoscaler is not None:
-                # policy-driven mode: the interval-evaluated autoscaler
-                # replaces the built-in burst-once decision, and every
-                # resize rides the same ckpt -> remesh -> reshard path
-                forced: ScaleAction | None = None
-                if (
-                    self.degraded_factor is not None
-                    and elastic_chips(res) > 0
-                ):
-                    # degraded-pod detector (DESIGN.md §19): the cluster
-                    # model says what this allocation *should* deliver;
-                    # measuring far above it means a pod is sick —
-                    # retire the elastic pod and re-stripe around it
-                    t_meas = self.monitor.step_time()
-                    t_model = (
-                        self.planner.cluster_model.predict_time(eff_chips)
-                        + self.planner.overheads.seam_s_per_step()
-                    )
-                    if t_model > 0 \
-                            and t_meas > self.degraded_factor * t_model:
-                        forced = ScaleAction(
-                            "retire",
-                            reason=(
-                                f"degraded pod: measured {t_meas:.3f}s "
-                                f"vs modeled {t_model:.3f}s"
-                            ),
+            with span("orch.decide", step=step):
+                est = self.predictor.estimate(
+                    self.monitor, step, steps_total, elapsed
+                )
+                eff_chips = sum(p.chips / p.slowdown for p in res.pods)
+                if autoscaler is not None:
+                    # policy-driven mode: the interval-evaluated autoscaler
+                    # replaces the built-in burst-once decision, and every
+                    # resize rides the same ckpt -> remesh -> reshard path
+                    forced: ScaleAction | None = None
+                    if (
+                        self.degraded_factor is not None
+                        and elastic_chips(res) > 0
+                    ):
+                        # degraded-pod detector (DESIGN.md §19): the cluster
+                        # model says what this allocation *should* deliver;
+                        # measuring far above it means a pod is sick —
+                        # retire the elastic pod and re-stripe around it
+                        t_meas = self.monitor.step_time()
+                        t_model = (
+                            self.planner.cluster_model.predict_time(eff_chips)
+                            + self.planner.overheads.seam_s_per_step()
                         )
-                        events.append(OrchestratorEvent(
-                            step, "degraded",
-                            {"measured_s": t_meas, "modeled_s": t_model},
-                        ))
-                if forced is not None:
-                    action = forced
-                else:
-                    action = autoscaler.decide(ScaleContext(
-                        step=step, steps_total=steps_total,
-                        elapsed_s=elapsed,
-                        est=est, resources=res,
-                        cloud_chips=elastic_chips(res),
-                        planner=self.planner, monitor=self.monitor,
-                        legal=list(self.planner.legal),
-                        provision_failures=provision_failures,
-                        since_failure_s=elapsed - last_failure_elapsed,
-                    ))
-                if (
-                    action.kind == "grow"
-                    and self.cloud_slowdown is not None
-                ):
-                    # the pod's *true* K is the provider's, whatever the
-                    # policy believed when sizing (DESIGN.md §10)
-                    action = dataclasses.replace(
-                        action, slowdown=self.cloud_slowdown
-                    )
-                if action.kind == "grow" and fault_hook is not None:
-                    attempt = 1
-                    while fault_hook("provision", {
-                        "chips": action.chips, "attempt": attempt,
-                        "step": step,
-                    }):
-                        retries += 1
-                        provision_failures += 1
-                        last_failure_elapsed = elapsed
-                        events.append(OrchestratorEvent(
-                            step, "provision_denied",
-                            {"chips": action.chips, "attempt": attempt},
-                        ))
-                        if (retry_policy is None
-                                or attempt > retry_policy.max_retries):
-                            gave_up = True
+                        if t_model > 0 \
+                                and t_meas > self.degraded_factor * t_model:
+                            forced = ScaleAction(
+                                "retire",
+                                reason=(
+                                    f"degraded pod: measured {t_meas:.3f}s "
+                                    f"vs modeled {t_model:.3f}s"
+                                ),
+                            )
                             events.append(OrchestratorEvent(
-                                step, "provision_gave_up",
-                                {"chips": action.chips,
-                                 "attempts": attempt},
+                                step, "degraded",
+                                {"measured_s": t_meas, "modeled_s": t_model},
                             ))
-                            action = HOLD
-                            break
-                        backoff = retry_policy.backoff_s(attempt, rng)
-                        elapsed += backoff
-                        events.append(OrchestratorEvent(
-                            step, "provision_retry",
-                            {"attempt": attempt + 1,
-                             "backoff_s": backoff},
-                        ))
-                        attempt += 1
+                    if forced is not None:
+                        action = forced
                     else:
-                        provision_failures = 0
-                new_res = self.apply_scale(res, action)
+                        action = autoscaler.decide(ScaleContext(
+                            step=step, steps_total=steps_total,
+                            elapsed_s=elapsed,
+                            est=est, resources=res,
+                            cloud_chips=elastic_chips(res),
+                            planner=self.planner, monitor=self.monitor,
+                            legal=list(self.planner.legal),
+                            provision_failures=provision_failures,
+                            since_failure_s=elapsed - last_failure_elapsed,
+                        ))
+                    if (
+                        action.kind == "grow"
+                        and self.cloud_slowdown is not None
+                    ):
+                        # the pod's *true* K is the provider's, whatever the
+                        # policy believed when sizing (DESIGN.md §10)
+                        action = dataclasses.replace(
+                            action, slowdown=self.cloud_slowdown
+                        )
+                    if action.kind == "grow" and fault_hook is not None:
+                        attempt = 1
+                        while fault_hook("provision", {
+                            "chips": action.chips, "attempt": attempt,
+                            "step": step,
+                        }):
+                            retries += 1
+                            provision_failures += 1
+                            last_failure_elapsed = elapsed
+                            events.append(OrchestratorEvent(
+                                step, "provision_denied",
+                                {"chips": action.chips, "attempt": attempt},
+                            ))
+                            if (retry_policy is None
+                                    or attempt > retry_policy.max_retries):
+                                gave_up = True
+                                events.append(OrchestratorEvent(
+                                    step, "provision_gave_up",
+                                    {"chips": action.chips,
+                                     "attempts": attempt},
+                                ))
+                                action = HOLD
+                                break
+                            backoff = retry_policy.backoff_s(attempt, rng)
+                            elapsed += backoff
+                            events.append(OrchestratorEvent(
+                                step, "provision_retry",
+                                {"attempt": attempt + 1,
+                                 "backoff_s": backoff},
+                            ))
+                            attempt += 1
+                        else:
+                            provision_failures = 0
+                    new_res = self.apply_scale(res, action)
+                else:
+                    decision = self.planner.plan(
+                        est, step, steps_total,
+                        observed_step_s=self.monitor.step_time(),
+                        effective_chips=eff_chips,
+                    )
+            if autoscaler is not None:
                 if action.kind != "hold" and new_res.pods != res.pods:
-                    last_ckpt = session.checkpoint(step)
-                    last_ckpt_step = step
-                    ov = self.planner.overheads
-                    overhead = (
-                        ov.total() if action.kind == "grow"
-                        else ov.ckpt_s + ov.restart_s
-                    )
-                    elapsed += overhead
-                    res = new_res
-                    # provisioning is not billed (the provider's clock
-                    # starts at attach, as in the fleet); the ckpt +
-                    # restart legs hold the new allocation
-                    cloud_chip_s += elastic_chips(res) * max(
-                        overhead
-                        - (ov.provision_s if action.kind == "grow"
-                           else 0.0),
-                        0.0,
-                    )
-                    session = session_factory(res, step, last_ckpt)
+                    with span("orch.transition", kind=action.kind,
+                              step=step):
+                        last_ckpt = session.checkpoint(step)
+                        last_ckpt_step = step
+                        ov = self.planner.overheads
+                        overhead = (
+                            ov.total() if action.kind == "grow"
+                            else ov.ckpt_s + ov.restart_s
+                        )
+                        elapsed += overhead
+                        res = new_res
+                        # provisioning is not billed (the provider's clock
+                        # starts at attach, as in the fleet); the ckpt +
+                        # restart legs hold the new allocation
+                        cloud_chip_s += elastic_chips(res) * max(
+                            overhead
+                            - (ov.provision_s if action.kind == "grow"
+                               else 0.0),
+                            0.0,
+                        )
+                        session = session_factory(res, step, last_ckpt)
                     self.monitor.reset_window()
                     events.append(OrchestratorEvent(
                         step, "scale",
@@ -498,27 +510,23 @@ class ElasticOrchestrator:
                         },
                     ))
                 continue
-            decision = self.planner.plan(
-                est, step, steps_total,
-                observed_step_s=self.monitor.step_time(),
-                effective_chips=eff_chips,
-            )
             if decision.burst and bursts_done < self.max_bursts:
                 # Fig.1 steps 2,5: save state, move it to the new nodes
-                last_ckpt = session.checkpoint(step)
-                last_ckpt_step = step
-                overhead = (
-                    overhead_s_fn(decision) if overhead_s_fn
-                    else decision.overhead_s
-                )
-                elapsed += overhead
-                # steps 3,4: expand resources with the γ split
-                res = self.apply_burst(res, decision)
-                cloud_chip_s += elastic_chips(res) * max(
-                    overhead - self.planner.overheads.provision_s, 0.0
-                )
-                # steps 6,7: assimilate state, restart at the stopped step
-                session = session_factory(res, step, last_ckpt)
+                with span("orch.transition", kind="burst", step=step):
+                    last_ckpt = session.checkpoint(step)
+                    last_ckpt_step = step
+                    overhead = (
+                        overhead_s_fn(decision) if overhead_s_fn
+                        else decision.overhead_s
+                    )
+                    elapsed += overhead
+                    # steps 3,4: expand resources with the γ split
+                    res = self.apply_burst(res, decision)
+                    cloud_chip_s += elastic_chips(res) * max(
+                        overhead - self.planner.overheads.provision_s, 0.0
+                    )
+                    # steps 6,7: assimilate state, restart at the stopped step
+                    session = session_factory(res, step, last_ckpt)
                 self.monitor.reset_window()
                 bursts_done += 1
                 events.append(OrchestratorEvent(
@@ -540,7 +548,10 @@ class ElasticOrchestrator:
                 # measured (not nominal) throughput
                 tps = [p.chips / p.slowdown for p in res.pods]
                 res = self.rebalanced(res, tps)
-                session = session_factory(res, step, session.checkpoint(step))
+                with span("orch.transition", kind="rebalance",
+                          step=step):
+                    session = session_factory(
+                        res, step, session.checkpoint(step))
                 events.append(OrchestratorEvent(
                     step, "rebalance", {"shares": list(res.shares)}
                 ))

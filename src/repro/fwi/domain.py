@@ -214,6 +214,7 @@ def _sharded_block_parts(cfg: FWIConfig, mesh: Mesh, k: int,
     src_x = jnp.asarray(pos[:, 1])
     sh = NamedSharding(mesh, P(None, None, "stripe"))
 
+    @jax.named_scope("fwi.exchange")
     def exchange_edges(p_r, p_l, pp_r, pp_l):
         # ONE packed exchange for the whole k-step block; for k > 1 the
         # p_prev edges ride in the same message (leading stacked axis)
@@ -254,6 +255,7 @@ def _sharded_block_parts(cfg: FWIConfig, mesh: Mesh, k: int,
             use_pallas=use_pallas, bz=bz,
         )
 
+    @jax.named_scope("fwi.interior")
     def interior(p, p_prev, v2e, spe, x0, srcv):
         # valid after k steps: columns [pad, nxl-pad) — everything the
         # seams cannot influence within one block
@@ -262,6 +264,7 @@ def _sharded_block_parts(cfg: FWIConfig, mesh: Mesh, k: int,
             0, x0, srcv,
         )
 
+    @jax.named_scope("fwi.boundary")
     def boundary(p, p_prev, lh_p, rh_p, lh_pp, rh_pp, v2e, spe, x0, srcv):
         # two BOUNDARY windows, batched into ONE call:
         # left covers local [-pad, 2·pad) -> valid [0, pad);
@@ -281,6 +284,7 @@ def _sharded_block_parts(cfg: FWIConfig, mesh: Mesh, k: int,
             bp, bpp, bv, bs, wx0s, x0, srcv
         )
 
+    @jax.named_scope("fwi.stitch")
     def stitch(bnd, mid, axis=-1):
         # stitch the disjoint valid regions
         sl = [slice(None)] * (bnd.ndim - 1)
@@ -486,12 +490,7 @@ def make_sharded_scan_runner(cfg: FWIConfig, mesh: Mesh, *, k: int = 4,
             (p, pp, _), traces = jax.lax.scan(
                 body, (p, p_prev, halos), jnp.arange(blocks)
             )
-            # (blocks, S, k, NX) -> (S, blocks·k, NX)
-            traces = jnp.moveaxis(traces, 0, 1)
-            traces = traces.reshape(
-                traces.shape[0], -1, traces.shape[-1]
-            )
-            return p, pp, traces
+            return p, pp, _trace_rows(traces)
     else:
         sm = sms["block"]
 
@@ -505,14 +504,16 @@ def make_sharded_scan_runner(cfg: FWIConfig, mesh: Mesh, *, k: int = 4,
             (p, pp), traces = jax.lax.scan(
                 body, (p, p_prev), jnp.arange(blocks)
             )
-            # (blocks, S, k, NX) -> (S, blocks·k, NX)
-            traces = jnp.moveaxis(traces, 0, 1)
-            traces = traces.reshape(
-                traces.shape[0], -1, traces.shape[-1]
-            )
-            return p, pp, traces
+            return p, pp, _trace_rows(traces)
 
     return run, place, k
+
+
+@jax.named_scope("fwi.traces")
+def _trace_rows(traces):
+    """(blocks, S, k, NX) per-block receiver traces -> (S, blocks·k, NX)."""
+    traces = jnp.moveaxis(traces, 0, 1)
+    return traces.reshape(traces.shape[0], -1, traces.shape[-1])
 
 
 def halo_bytes_per_step(cfg: FWIConfig, n_stripes: int, k: int = 1) -> int:

@@ -22,6 +22,7 @@ already compiled for an equal mesh.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import signal
 import time
 
@@ -34,6 +35,7 @@ from repro.checkpoint.manager import (
     install_preemption_hook,
 )
 from repro.core.orchestrator import Resources, Session, elastic_chips
+from repro.core.spans import span
 from repro.fwi.domain import (
     effective_block,
     make_sharded_scan_runner,
@@ -70,6 +72,10 @@ class TimeModel:
     scaling_alpha: float = 1.0
 
 
+#: one id per FWISession, shared by every span the session records
+_session_ids = itertools.count(1)
+
+
 class FWISession(Session):
     def __init__(
         self,
@@ -93,39 +99,44 @@ class FWISession(Session):
         n = n_stripes or min(len(jax.devices()), max(res.total_chips, 1))
         while cfg.nx % n:
             n -= 1
-        self.mesh = stripe_mesh(n)
-        use_pallas = resolve_use_pallas(use_pallas)
-        bz = None
-        if autotune and use_pallas:
-            # joint (strip height, block length) tuned at the PER-STRIPE
-            # width the engine actually runs (not the global NX);
-            # memoized per (shape, backend) so a RESHARD rebuild does
-            # not re-time.  If the stripe clamp shrinks the tuned k,
-            # re-derive bz for the clamped k instead of keeping the
-            # strip that won jointly with the larger one.
-            bz, exchange_interval = autotune_bz_k(cfg.nz, cfg.nx // n)
-            keff = effective_block(cfg, n, exchange_interval)
-            if keff != exchange_interval:
-                exchange_interval = keff
-                bz = pick_bz_block(cfg.nz, keff)
-        elif exchange_interval is None:
-            exchange_interval = pick_k(cfg.nz)
-        self.runner, place, self.k = make_sharded_scan_runner(
-            cfg, self.mesh, k=exchange_interval, use_pallas=use_pallas,
-            bz=bz,
-        )
+        self.session = next(_session_ids)
+        with span("fwi.remesh", session=self.session, stripes=n):
+            self.mesh = stripe_mesh(n)
+            use_pallas = resolve_use_pallas(use_pallas)
+            bz = None
+            if autotune and use_pallas:
+                # joint (strip height, block length) tuned at the
+                # PER-STRIPE width the engine actually runs (not the
+                # global NX); memoized per (shape, backend) so a RESHARD
+                # rebuild does not re-time.  If the stripe clamp shrinks
+                # the tuned k, re-derive bz for the clamped k instead of
+                # keeping the strip that won jointly with the larger one.
+                bz, exchange_interval = autotune_bz_k(cfg.nz, cfg.nx // n)
+                keff = effective_block(cfg, n, exchange_interval)
+                if keff != exchange_interval:
+                    exchange_interval = keff
+                    bz = pick_bz_block(cfg.nz, keff)
+            elif exchange_interval is None:
+                exchange_interval = pick_k(cfg.nz)
+            self.runner, place, self.k = make_sharded_scan_runner(
+                cfg, self.mesh, k=exchange_interval, use_pallas=use_pallas,
+                bz=bz,
+            )
         # timesteps per measured dispatch (multiple of the exchange
         # interval so every block is fully temporally blocked)
         self.block = max(scan_block // self.k, 1) * self.k
-        if restored is not None:
-            st = ShotState(
-                p=jnp.asarray(restored["p"]),
-                p_prev=jnp.asarray(restored["p_prev"]),
-                t=int(restored["t"]),
-            )
-        else:
-            st = ShotState.init(cfg)
-        self.p, self.p_prev = place((st.p, st.p_prev))
+        with span("fwi.place", session=self.session,
+                  devices=[d.id for d in self.mesh.devices.flat]) as sp:
+            if restored is not None:
+                st = ShotState(
+                    p=jnp.asarray(restored["p"]),
+                    p_prev=jnp.asarray(restored["p_prev"]),
+                    t=int(restored["t"]),
+                )
+            else:
+                st = ShotState.init(cfg)
+            sp.attrs["bytes"] = st.p.nbytes + st.p_prev.nbytes
+            self.p, self.p_prev = place((st.p, st.p_prev))
         self.t = st.t
         # logical steps already covered by the last dispatched block —
         # carried through checkpoints so a mid-block RESHARD resumes the
@@ -157,13 +168,15 @@ class FWISession(Session):
     def _advance_block(self) -> float:
         """Dispatch one scan block; returns amortized wall s/step."""
         blocks = self.block // self.k
-        t0 = time.monotonic()
-        p, pp, _ = self.runner(self.p, self.p_prev, self.t, blocks)
-        jax.block_until_ready(p)
-        dt = time.monotonic() - t0
+        steps = blocks * self.k
+        with span("fwi.dispatch", session=self.session,
+                  steps=steps) as dispatch:
+            p, pp, _ = self.runner(self.p, self.p_prev, self.t, blocks)
+        with span("fwi.wait", session=self.session) as wait:
+            jax.block_until_ready(p)
         self.p, self.p_prev = p, pp
-        self.t += blocks * self.k
-        return dt / (blocks * self.k)
+        self.t += steps
+        return (wait.t1 - dispatch.t0) / steps
 
     def run_step(self, step: int) -> float:
         if self._pending <= 0:
@@ -198,9 +211,15 @@ class FWISession(Session):
         return dt * (1.0 + self.tm.jitter * abs(self.rng.standard_normal()))
 
     def checkpoint(self, step: int):
+        fields = {}
+        with span("fwi.checkpoint", session=self.session,
+                  stripes=self._n_stripes):
+            for name in ("p", "p_prev"):
+                x = getattr(self, name)
+                with span("fwi.fetch", bytes=x.nbytes):
+                    fields[name] = np.asarray(x)
         return {
-            "p": np.asarray(self.p),
-            "p_prev": np.asarray(self.p_prev),
+            **fields,
             "t": self.t,
             "pending": self._pending,
             "amortized_s": self._amortized,

@@ -132,12 +132,14 @@ def _wave_block_shots_tiled(
     outs = []
     for lo in range(0, ns, shot_tile):
         hi = min(lo + shot_tile, ns)
-        sv = sv2[lo:hi] if sv2 is not None else src_vals
-        outs.append(run(p[lo:hi], p_prev[lo:hi], sv,
-                        src_z[lo:hi], src_x[lo:hi]))
-    return tuple(
-        jnp.concatenate([o[i] for o in outs], axis=0) for i in range(3)
-    )
+        with jax.named_scope("stencil.tile_split"):
+            sv = sv2[lo:hi] if sv2 is not None else src_vals
+            tile = (p[lo:hi], p_prev[lo:hi], sv, src_z[lo:hi], src_x[lo:hi])
+        outs.append(run(*tile))
+    with jax.named_scope("stencil.tile_concat"):
+        return tuple(
+            jnp.concatenate([o[i] for o in outs], axis=0) for i in range(3)
+        )
 
 
 def wave_block(p, p_prev, v2dt2, sponge, src_vals, src_z, src_x, *,
