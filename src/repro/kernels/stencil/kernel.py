@@ -256,13 +256,14 @@ def stream_vmem_bytes(nz: int, nx: int, bz: int, k: int, s: int = 1) -> int:
     """VMEM footprint of the STREAMED design: two DMA slots of
     ``2·s + 2`` (win, NX) haloed f32 windows (``2·s`` shot-tiled
     wavefield windows + ONE shared pair of model-field windows), the
-    double-buffered output strips, and the trace block — O(s·bz·NX),
-    independent of NZ, rows lane-padded (``_lanes``).  ``s=1`` reduces
-    to the classic accounting."""
+    double-buffered output strips, and the double-buffered trace block
+    (the shot-batched kernel moves it from one shot tile to the next) —
+    O(s·bz·NX), independent of NZ, rows lane-padded (``_lanes``).
+    ``s`` is the shot tile, not the batch."""
     win = min(bz + 2 * k * HALO, nz)
     nx = _lanes(nx)
     return 4 * (2 * (2 * s + 2) * win * nx + 2 * 2 * s * bz * nx
-                + s * k * nx)
+                + 2 * s * k * nx)
 
 
 def should_stream(nz: int, nx: int, k: int = 1,
@@ -837,31 +838,37 @@ def _wave_block_shots_stream_kernel(
     """Shot-batched STREAMED trapezoid: double-buffered window DMA with
     a shot-tiled wavefield slot and a SINGLE model-field slot.
 
-    The wavefields stay in HBM as (S, NZ, NX); each grid step DMAs an
-    (S, win, NX) window pair into one of two VMEM slots.  The model
+    The wavefields stay in HBM as (S, NZ, NX); the grid is (shot tiles,
+    strips), tile-major, and each grid step DMAs one tile's
+    (tile, win, NX) window pair into one of two VMEM slots.  The model
     fields get their own (2, 2, win, NX) scratch — one (win, NX) window
     per field per slot, DMA'd ONCE per strip and reused by every shot
-    in the batch, which is exactly the traffic the shot batch exists to
-    amortize (DESIGN.md §17).  Grid step i starts strip i+1's fetch
-    into the other slot before waiting on its own, so the next window
-    flies over this strip's k-step compute (DESIGN.md §15); the
-    trapezoid math is ``_trapezoid_k_steps_shots``, shared with the
-    resident kernel."""
-    i = pl.program_id(0)
-    n = pl.num_programs(0)
+    of the tile, which is exactly the traffic the shot batch exists to
+    amortize (DESIGN.md §17).  The walk is one sequence of steps
+    ``i = tile · strips + strip``: step i starts step i+1's fetch into
+    the other slot before waiting on its own, so the next window flies
+    over this strip's k-step compute (DESIGN.md §15), across a tile
+    boundary too.  The trapezoid math is ``_trapezoid_k_steps_shots``,
+    shared with the resident kernel."""
     nz = p_hbm.shape[1]
     nx = p_hbm.shape[2]
+    ts = fwin_buf.shape[2]           # shots per tile
+    nb = nz // bz                    # strips per tile
+    tile, strip = pl.program_id(0), pl.program_id(1)
+    i = tile * nb + strip            # step of the walk
+    n = pl.num_programs(0) * nb
     aligned = _rows_aligned(nz, bz, win, k)
 
-    def win_start(strip):
-        start = jnp.clip(strip * bz - k * HALO, 0, nz - win)
+    def win_start(j):
+        start = jnp.clip(j * bz - k * HALO, 0, nz - win)
         return pl.multiple_of(start, 8) if aligned else start
 
-    def dma(slot, strip):
-        start = win_start(strip)
+    def dma(slot, step):
+        start = win_start(step % nb)
+        shots = pl.ds((step // nb) * ts, ts)
         copies = [
             pltpu.make_async_copy(
-                f.at[:, pl.ds(start, win), :],
+                f.at[shots, pl.ds(start, win), :],
                 fwin_buf.at[slot, fi],
                 fsems.at[slot, fi],
             )
@@ -882,7 +889,7 @@ def _wave_block_shots_stream_kernel(
         for c in dma(0, 0):
             c.start()
 
-    @pl.when(i + 1 < n)              # prefetch next strip's window
+    @pl.when(i + 1 < n)              # prefetch the next step's window
     def _prefetch():
         for c in dma((i + 1) % 2, i + 1):
             c.start()
@@ -891,13 +898,13 @@ def _wave_block_shots_stream_kernel(
     for c in dma(slot, i):           # wait for our window to land
         c.wait()
 
-    row0 = i * bz
-    start = win_start(i)
+    row0 = strip * bz
+    start = win_start(strip)
     cur, prevd = _trapezoid_k_steps_shots(
         fwin_buf[slot, 0], fwin_buf[slot, 1],
         mwin_buf[slot, 0], mwin_buf[slot, 1],
         srcv_ref, srcz_ref, srcx_ref, tr_ref,
-        start=start, i=i, win=win, nx=nx, k=k,
+        start=start, i=strip, win=win, nx=nx, k=k,
         recv=_receiver_window_row(rrow, nz, bz, win, k),
     )
     geometry = dict(nz=nz, bz=bz, win=win, k=k)
@@ -907,7 +914,8 @@ def _wave_block_shots_stream_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("receiver_row", "bz", "interpret", "vmem_budget"),
+    static_argnames=("receiver_row", "bz", "interpret", "vmem_budget",
+                     "shot_tile"),
 )
 def wave_block_shots_stream_pallas(
     p: jax.Array,          # (S, NZ, NX) f32 shot batch
@@ -922,31 +930,40 @@ def wave_block_shots_stream_pallas(
     bz: int | None = None,
     interpret: bool | None = None,
     vmem_budget: int | None = None,
+    shot_tile: int | None = None,
 ):
     """Shot-batched ``wave_block_stream_pallas``: VMEM holds two
-    (S, win, NX) wavefield window slots plus ONE shared (win, NX)
-    model-field slot pair — capacity O(s·bz·NX), independent of NZ.
+    (tile, win, NX) wavefield window slots plus ONE shared (win, NX)
+    model-field slot pair — capacity O(tile·bz·NX), independent of NZ
+    and of S.
 
-    Strip height defaults to ``pick_bz_stream(..., s=S)`` (raises
-    rather than fall back to a whole-height resident strip — same
-    no-fallback contract as the single-shot streamed kernel).  Returns
+    ``shot_tile`` (a divisor of S; ``None`` is the whole batch) is how
+    many shots share a window: the kernel walks the S / tile tiles in
+    its own grid, so the whole batch goes in and comes out whole, with
+    no slice or join of the wavefields around the call.  Strip height
+    defaults to ``pick_bz_stream(..., s=tile)`` (raises rather than
+    fall back to a whole-height resident strip — same no-fallback
+    contract as the single-shot streamed kernel).  Returns
     (p_k, p_prev_damped_k, traces (S, k, NX))."""
     ns, nz, nx = p.shape
     k = int(src_vals.shape[-1])
+    ts = ns if shot_tile is None else int(shot_tile)
     if interpret is None:
         interpret = default_interpret()
     if bz is None:
-        bz = pick_bz_stream(nz, nx, k, vmem_budget=vmem_budget, s=ns)
+        bz = pick_bz_stream(nz, nx, k, vmem_budget=vmem_budget, s=ts)
     budget = vmem_budget if vmem_budget is not None else DEFAULT_VMEM_BUDGET
     win = bz + 2 * k * HALO
+    assert ns % ts == 0, (ns, ts)
     assert nz % bz == 0, (nz, bz)
     assert win <= nz, (nz, bz, k)    # no whole-height fallback, ever
-    assert stream_vmem_bytes(nz, nx, bz, k, s=ns) <= budget, \
-        (nz, nx, bz, k, ns)
-    grid = (nz // bz,)
+    assert stream_vmem_bytes(nz, nx, bz, k, s=ts) <= budget, \
+        (nz, nx, bz, k, ts)
+    # tile-major: a tile's trace block stays put while its strips run
+    grid = (ns // ts, nz // bz)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
-    strip3 = pl.BlockSpec((ns, bz, nx), lambda i: (0, i, 0))
-    src = pl.BlockSpec((ns, 1, 1), lambda i: (0, 0, 0))
+    strip3 = pl.BlockSpec((ts, bz, nx), lambda t, i: (t, i, 0))
+    src = pl.BlockSpec((ts, 1, 1), lambda t, i: (t, 0, 0))
     srcv, srcz, srcx = _norm_src_shots(src_vals, src_z, src_x, ns, p.dtype)
     out_shape = [
         jax.ShapeDtypeStruct((ns, nz, nx), p.dtype),
@@ -956,10 +973,12 @@ def wave_block_shots_stream_pallas(
     kwargs = {}
     if not interpret:
         _check_compiled_geometry(nz, nx, bz, win, k, stream=True)
-        # the budget counts the buffers; the k-step trapezoid's
-        # temporaries come on top (DESIGN.md §15)
+        # both axes sequential: each step's DMA was started by the step
+        # before it.  The budget counts the buffers; the k-step
+        # trapezoid's temporaries come on top (DESIGN.md §15)
         kwargs["compiler_params"] = pltpu.CompilerParams(
-            vmem_limit_bytes=2 * budget
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=2 * budget,
         )
     return pl.pallas_call(
         functools.partial(
@@ -968,12 +987,13 @@ def wave_block_shots_stream_pallas(
         ),
         grid=grid,
         in_specs=[hbm, hbm, hbm, hbm,
-                  pl.BlockSpec((ns, 1, k), lambda i: (0, 0, 0)), src, src],
+                  pl.BlockSpec((ts, 1, k), lambda t, i: (t, 0, 0)),
+                  src, src],
         out_specs=[strip3, strip3,
-                   pl.BlockSpec((ns, k, nx), lambda i: (0, 0, 0))],
+                   pl.BlockSpec((ts, k, nx), lambda t, i: (t, 0, 0))],
         out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((2, 2, ns, win, nx), p.dtype),
+            pltpu.VMEM((2, 2, ts, win, nx), p.dtype),
             pltpu.VMEM((2, 2, win, nx), p.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SemaphoreType.DMA((2, 2)),
@@ -1175,6 +1195,11 @@ def _autotune_shots_cached(
                     continue              # tile blows the resident budget
 
             def run(b, t=t, srcv=srcv):
+                if stream and ns % t == 0:    # tiles walked in the grid
+                    return wave_block_shots_stream_pallas(
+                        p, p, v, s, srcv, sz, sx, bz=b,
+                        vmem_budget=budget, shot_tile=t,
+                    )
                 outs = []
                 for lo in range(0, ns, t):
                     hi = min(lo + t, ns)

@@ -84,11 +84,15 @@ def _wave_block_shots_tiled(
     shot_tile,
 ):
     """Run the shot-batched block kernel over shot tiles of size
-    ``shot_tile`` and concatenate — the 3-D dispatch body of
-    ``wave_block``.  Per-shot results are independent, so tiling the
-    batch is value-preserving (bitwise on the XLA mirror) while keeping
-    each pallas_call's VMEM footprint at the tile size, not the full
-    batch (DESIGN.md §17)."""
+    ``shot_tile`` — the 3-D dispatch body of ``wave_block``.  Per-shot
+    results are independent, so tiling the batch is value-preserving
+    (bitwise on the XLA mirror) while keeping each pallas_call's VMEM
+    footprint at the tile size, not the full batch (DESIGN.md §17).
+
+    The streamed Pallas kernel walks the tiles in its own grid, so a
+    tile that divides the batch is one call on the whole batch.  The
+    resident and XLA paths, and a ragged explicit tile, slice the batch
+    into tiles and concatenate what they return."""
     ns = p.shape[0]
     nz, nx = p.shape[-2], p.shape[-1]
     k = int(src_vals.shape[-1])
@@ -96,6 +100,12 @@ def _wave_block_shots_tiled(
     src_x = jnp.asarray(src_x, jnp.int32).reshape(ns)
     sv2 = src_vals if getattr(src_vals, "ndim", 1) == 2 else None
 
+    if use_pallas and stream and ns % shot_tile == 0:
+        return wave_block_shots_stream_pallas(
+            p, p_prev, v2dt2, sponge, src_vals, src_z, src_x,
+            receiver_row=receiver_row, bz=bz, interpret=interpret,
+            vmem_budget=vmem_budget, shot_tile=shot_tile,
+        )
     if use_pallas:
         if stream:
             def run(pt, ppt, sv, zt, xt):
@@ -159,11 +169,14 @@ def wave_block(p, p_prev, v2dt2, sponge, src_vals, src_z, src_x, *,
     (DESIGN.md §17): the whole batch advances in one kernel per block,
     sharing the model-field reads across shots; ``src_z``/``src_x`` are
     per-shot ``(S,)`` positions and ``src_vals`` may be ``(k,)`` shared
-    or ``(S, k)`` per-shot.  ``shot_tile`` bounds how many shots ride
-    one pallas_call (VMEM scales with the tile, not the batch);
+    or ``(S, k)`` per-shot.  ``shot_tile`` bounds how many shots are
+    computed together (VMEM scales with the tile, not the batch);
     ``None`` auto-picks the largest budget-fitting divisor of S via
     ``pick_shot_tile`` on the Pallas path and the whole batch on the
-    XLA path, and unaligned explicit tiles run a smaller remainder tile.
+    XLA path.  The streamed Pallas kernel walks the tiles in its own
+    grid, on the whole batch; the resident and XLA paths, and ragged
+    explicit tiles (which run a smaller remainder tile), slice the
+    batch into tiles and join the results.
 
     ``stream`` selects the STREAMED tiling for production-scale grids
     (DESIGN.md §15): ``None`` auto-streams when the whole-array

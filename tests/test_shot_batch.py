@@ -118,6 +118,57 @@ def test_shots_stream_bitwise_vs_resident():
         assert np.array_equal(np.asarray(a), np.asarray(b))
 
 
+@pytest.mark.parametrize("S,tile", [(4, 1), (4, 2), (6, 3)])
+@pytest.mark.parametrize("per_shot_src", [False, True])
+def test_shots_stream_walks_tiles_in_grid_bitwise(S, tile, per_shot_src):
+    """The streamed kernel walking S / tile shot tiles in its grid
+    equals the whole batch in one tile, and each tile run alone and
+    concatenated, bitwise: every shot's source sits in its own tile."""
+    p, pp, v, sp, srcv, sz, sx = _case(S, 64, 128, 4,
+                                       per_shot_src=per_shot_src)
+    kw = dict(receiver_row=7, bz=16)
+    tiled = wave_block_shots_stream_pallas(p, pp, v, sp, srcv, sz, sx,
+                                           shot_tile=tile, **kw)
+    whole = wave_block_shots_stream_pallas(p, pp, v, sp, srcv, sz, sx,
+                                           shot_tile=S, **kw)
+    parts = [
+        wave_block_shots_stream_pallas(
+            p[lo:lo + tile], pp[lo:lo + tile], v, sp,
+            srcv[lo:lo + tile] if per_shot_src else srcv,
+            sz[lo:lo + tile], sx[lo:lo + tile], **kw)
+        for lo in range(0, S, tile)
+    ]
+    joined = [np.concatenate([np.asarray(o[i]) for o in parts])
+              for i in range(3)]
+    for a, b, c in zip(tiled, whole, joined):
+        assert a.shape == b.shape == c.shape
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+        assert np.array_equal(np.asarray(a), c)
+
+
+def test_streamed_dispatch_neither_splits_nor_joins_the_batch():
+    """A dividing tile on the streamed Pallas path is one call on the
+    whole batch: no tile-split or tile-concat scope in the lowered
+    program.  A ragged tile still slices and joins, which shows the
+    scopes would be seen."""
+    s, nz, nx, k = 4, 64, 128, 4
+    f = jnp.zeros((s, nz, nx), jnp.float32)
+    m = jnp.ones((nz, nx), jnp.float32)
+    at = jnp.zeros((s,), jnp.int32)
+
+    def lowered(tile):
+        return jax.jit(lambda p, pp: wave_block(
+            p, pp, 0.1 * m, m, jnp.ones((k,)), at + 8, at + 16,
+            use_pallas=True, stream=True, bz=16, shot_tile=tile,
+        )).lower(f, f).as_text(debug_info=True)
+
+    walked, ragged = lowered(2), lowered(3)
+    assert "stencil.tile_split" not in walked
+    assert "stencil.tile_concat" not in walked
+    assert "stencil.tile_split" in ragged
+    assert "stencil.tile_concat" in ragged
+
+
 def test_shots_s1_bitwise_vs_2d_kernel():
     p, pp, v, sp, srcv, sz, sx = _case(1, 64, 128, 4)
     batched = wave_block_shots_pallas(p, pp, v, sp, srcv, sz, sx,
@@ -171,9 +222,11 @@ def test_vmem_formulas_reduce_at_s1():
     # the pre-§17 single-shot accounting, written out long-hand
     assert resident_vmem_bytes(nz, nx, k, bz=bz) == \
         4 * (4 * nz * lanes + 4 * bz * lanes + k * lanes)
+    # streamed: two slots of four windows, double-buffered output
+    # strips and a double-buffered trace block
     win = min(bz + 2 * k * HALO, nz)
     assert stream_vmem_bytes(nz, nx, bz, k) == \
-        4 * (2 * 4 * win * lanes + 4 * bz * lanes + k * lanes)
+        4 * (2 * 4 * win * lanes + 4 * bz * lanes + 2 * k * lanes)
 
 
 def test_vmem_monotone_in_s():
@@ -226,6 +279,15 @@ def test_autotune_shots_returns_triple():
     )
     assert (bz, k) in {(8, 2), (16, 2)}
     assert tile in (1, 2) and 2 % tile == 0
+
+
+def test_autotune_streamed_shots_returns_triple():
+    bz, k, tile = autotune_bz_k(
+        64, 128, bz_candidates=(16,), k_candidates=(4,), repeats=1,
+        backend="interpret", stream=True, n_shots=2,
+    )
+    assert (bz, k) == (16, 4)
+    assert tile in (1, 2)
 
 
 def test_autotune_without_shots_still_pair():

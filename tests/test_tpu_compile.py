@@ -64,15 +64,23 @@ def test_resident_kernel_compiles_for_v5e(one_chip, n_shots, nz, nx):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("n_shots,nz,nx", [
-    (16, 4096, 4096),     # the production grid (DESIGN.md §15)
-    (16, 4096, 1024),     # one of its four stripes
+@pytest.mark.parametrize("n_shots,nz,nx,in_grid", [
+    # the production grid (DESIGN.md §15): one shot tile alone
+    pytest.param(16, 4096, 4096, False, id="16-4096-4096"),
+    # one of its four stripes
+    pytest.param(16, 4096, 1024, False, id="16-4096-1024"),
+    # the production grid's whole batch, its tiles walked in the grid
+    pytest.param(16, 4096, 4096, True, id="16-4096-4096-tiles-in-grid"),
 ])
-def test_streamed_kernel_compiles_for_v5e(one_chip, n_shots, nz, nx):
+def test_streamed_kernel_compiles_for_v5e(one_chip, n_shots, nz, nx,
+                                          in_grid):
     assert should_stream(nz, nx, K)
     tile = pick_shot_tile(n_shots, nz, nx, K, stream=True)
+    batch = n_shots if in_grid else tile
+    assert batch // tile == (4 if in_grid else 1)
     compiled = _compile(
         lambda p, pp, v, s, sv, z, x: wave_block_shots_stream_pallas(
-            p, pp, v, s, sv, z, x, receiver_row=2, interpret=False),
-        tile, nz, nx, one_chip)
+            p, pp, v, s, sv, z, x, receiver_row=2, interpret=False,
+            shot_tile=tile),
+        batch, nz, nx, one_chip)
     assert "tpu_custom_call" in compiled.as_text()
