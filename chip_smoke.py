@@ -164,7 +164,7 @@ def orchestrated(cfg, steps: int, *, stripes: int | None = None,
 
 
 def stripe_devices(session) -> list:
-    return [sh.device for sh in session.p.addressable_shards]
+    return [sh.device for sh in session.carry[0].addressable_shards]
 
 
 def rel_diff(a: np.ndarray, ref: np.ndarray) -> float:
@@ -209,7 +209,7 @@ def fwi_phase(name: str, cfg, *, stream_expected: bool, steps: int) -> None:
     log(name, f"measured_s_per_step={s_per_step!r} "
               f"(first dispatch, compile included: "
               f"{rec.step_times[0]!r} s/step)")
-    text = s.runner.lower(s.p, s.p_prev, s.t, s.block // s.k) \
+    text = s.runner.lower(*s.carry, s.t, s.block // s.k) \
         .compile().as_text()
     has_kernel = "tpu_custom_call" in text
     log(name, f"tpu_custom_call={has_kernel} "
@@ -252,7 +252,7 @@ def four_chip_phase(cfg, steps: int = 100 * SCAN_BLOCK,
     rec, sessions = orchestrated(cfg, steps, stripes=4)
     s = sessions[-1]
     devs = stripe_devices(s)
-    widths = [sh.data.shape[-1] for sh in s.p.addressable_shards]
+    widths = [sh.data.shape[-1] for sh in s.carry[0].addressable_shards]
     steady = rec.step_times[s.block:]
     log(name, f"4 stripes: devices {[str(d) for d in devs]}, "
               f"columns {widths}; compile_s={_compile_s[0] - c0!r} "

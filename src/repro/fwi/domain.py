@@ -52,9 +52,20 @@ Physical domain edges need no special-casing: every window is
 zero-extended in x, which at a physical edge IS the reference's
 zero-halo convention, and at a seam marks the redundant zone that the
 trapezoidal shrink discards.
+
+Ragged grids (a published survey's 13601 × 2801, whose width no stripe
+count divides and whose prime height no strip divides) run PADDED
+(``stripe_geometry``): the runner computes on extra zero rows at the
+bottom and zero columns at the right, where the model fields ``v2dt2``
+and the sponge are 0, so the padded cells stay exactly 0 every step —
+the reference's "zero beyond the edge".  Sources, receivers and the
+sponge stay on the logical grid; ``place`` pads (scope ``fwi.pad``),
+``crop`` cuts the padding off (scope ``fwi.crop``), and an aligned grid
+gets neither op.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -63,10 +74,22 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.fwi.solver import FWIConfig, ricker, sponge_taper, velocity_model
-from repro.kernels.stencil.ops import resolves_use_pallas, wave_block
+from repro.kernels.stencil.kernel import _check_compiled_geometry, _lanes
+from repro.kernels.stencil.ops import (
+    pick_bz_block,
+    pick_bz_stream,
+    pick_shot_tile,
+    resolves_use_pallas,
+    should_stream,
+    wave_block,
+)
 from repro.launch.mesh import make_mesh
 
 HALO = 2
+
+#: padded heights tried above the logical one before giving up; a
+#: k-step trapezoid with k a multiple of 4 finds its height within 8
+ROW_SEARCH = 64
 
 
 def stripe_mesh(n_devices: int | None = None) -> Mesh:
@@ -157,17 +180,129 @@ def _overlapped_field(arr: np.ndarray, n: int, pad: int) -> jnp.ndarray:
 def effective_block(cfg: FWIConfig, n_stripes: int, k: int) -> int:
     """Clamp k so the overlap windows fit inside one stripe: the
     interior/boundary split needs the two 2·k·HALO-column boundary
-    source regions to be disjoint, i.e. 2·k·HALO ≤ NX/stripes."""
-    nxl = cfg.nx // n_stripes
+    source regions to be disjoint, i.e. 2·k·HALO ≤ NX/stripes (the
+    stripe's width before any lane padding, rounded up)."""
+    nxl = -(-cfg.nx // n_stripes)
     return max(1, min(k, nxl // (2 * HALO)))
+
+
+@dataclasses.dataclass(frozen=True)
+class StripeGeometry:
+    """The grid the sharded runner computes on, and how its interior
+    window's kernel is tiled.
+
+    ``rows`` × (``stripes`` · ``lanes``) holds the logical grid in its
+    top-left corner; the rest is zero padding.  ``stream``,
+    ``shot_tile``, ``bz`` and ``win`` are what the interior's
+    ``wave_block`` is called with (``bz``/``win`` None where no strips
+    are cut: the XLA path's unstripped block)."""
+    rows: int
+    lanes: int            # columns per stripe, padding included
+    stripes: int
+    k: int                # effective steps per block
+    stream: bool
+    shot_tile: int
+    bz: int | None
+    win: int | None
+
+    @property
+    def cols(self) -> int:
+        return self.stripes * self.lanes
+
+    def padded(self, cfg: FWIConfig) -> bool:
+        return (self.rows, self.cols) != (cfg.nz, cfg.nx)
+
+
+def stripe_geometry(cfg: FWIConfig, n_stripes: int, k: int,
+                    use_pallas: bool, bz: int | None = None
+                    ) -> StripeGeometry:
+    """The padded grid and the interior kernel's tiling for ``cfg`` on
+    ``n_stripes`` stripes (DESIGN.md §15).
+
+    The width goes up to the least one ``n_stripes`` divides.  Where the
+    interior window streams (``should_stream``), each stripe goes up to
+    whole 128-lane tiles and the height to the least one at or above
+    ``nz`` for which the pickers find a streamable strip that the
+    compiled kernel accepts (``_check_compiled_geometry``): 13601 ×
+    2801 becomes 13696 × 2808 on one stripe, 4 × 3456 × 2808 on four.
+    A resident or XLA interior takes any height.  An aligned grid is
+    not padded at all."""
+    k = effective_block(cfg, n_stripes, k)
+    lanes = -(-cfg.nx // n_stripes)
+    ns = cfg.n_shots
+    if not should_stream(cfg.nz, lanes, k):
+        sbz = (bz if bz is not None else pick_bz_block(cfg.nz, k)) \
+            if use_pallas else None
+        tile = pick_shot_tile(ns, cfg.nz, lanes, k, bz=sbz) \
+            if use_pallas else ns
+        win = None if sbz is None else min(sbz + 2 * k * HALO, cfg.nz)
+        return StripeGeometry(cfg.nz, lanes, n_stripes, k, False, tile,
+                              sbz, win)
+    lanes = _lanes(lanes)
+    refused = ""
+    for rows in range(cfg.nz, cfg.nz + ROW_SEARCH):
+        tile = pick_shot_tile(ns, rows, lanes, k, stream=True) \
+            if use_pallas else ns
+        try:
+            sbz = bz if bz is not None else pick_bz_stream(
+                rows, lanes, k, s=tile if use_pallas else 1)
+            win = sbz + 2 * k * HALO
+            if use_pallas:
+                _check_compiled_geometry(rows, lanes, sbz, win, k,
+                                         stream=True)
+        except ValueError as e:
+            refused = f"{rows}: {e}"
+            continue
+        return StripeGeometry(rows, lanes, n_stripes, k, True, tile, sbz,
+                              win)
+    raise ValueError(
+        f"no padded height in [{cfg.nz}, {cfg.nz + ROW_SEARCH}) streams "
+        f"{lanes} lanes at k={k}; last refusal: {refused}")
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _pad(x, rows: int, cols: int):
+    with jax.named_scope("fwi.pad"):
+        extra = [(0, rows - x.shape[-2]), (0, cols - x.shape[-1])]
+        return jnp.pad(x, [(0, 0)] * (x.ndim - 2) + extra)
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _crop(x, rows: int, cols: int):
+    with jax.named_scope("fwi.crop"):
+        return x[..., :rows, :cols]
+
+
+def _put_padded(f: np.ndarray, grid: tuple[int, int], sharding):
+    """Host array ``f`` (..., nz, nx) as a device array (..., *grid) on
+    ``sharding``, zero past the logical edge, each shard filled on the
+    host and sent to its own device."""
+    shape = f.shape[:-2] + grid
+
+    def shard(index):
+        part = f[index]
+        out = np.zeros(sharding.shard_shape(shape), f.dtype)
+        out[tuple(slice(0, n) for n in part.shape)] = part
+        return out
+
+    return jax.make_array_from_callback(shape, sharding, shard)
+
+
+def crop(cfg: FWIConfig, x):
+    """``x`` (..., rows, cols) cut to the logical (..., nz, nx) grid;
+    ``x`` itself where it has no padding."""
+    if x.shape[-2:] == (cfg.nz, cfg.nx):
+        return x
+    return _crop(x, cfg.nz, cfg.nx)
 
 
 @functools.lru_cache(maxsize=32)
 def _sharded_block_parts(cfg: FWIConfig, mesh: Mesh, k: int,
                          use_pallas: bool, bz: int | None = None,
                          schedule: str = "overlap"):
-    """(sms, v2e_all, spe_all, place, k): the UNJITTED shard_map'd
-    k-step fused block bodies plus their closure fields — callers jit at
+    """(sms, v2e_all, spe_all, place, geom): the UNJITTED shard_map'd
+    k-step fused block bodies plus their closure fields, on the padded
+    grid of ``geom`` (``stripe_geometry``) — callers jit at
     their own boundary (wrapping the body in its own jit inside a
     lax.scan defeats XLA's loop fusion; see solver.py).  ``sms`` is a
     dict: ``{"block"}`` for the "fused"/"overlap" schedules,
@@ -199,15 +334,16 @@ def _sharded_block_parts(cfg: FWIConfig, mesh: Mesh, k: int,
     noise.
     """
     n = mesh.shape["stripe"]
-    assert cfg.nx % n == 0, (cfg.nx, n)
-    nxl = cfg.nx // n
-    k = effective_block(cfg, n, k)
+    geom = stripe_geometry(cfg, n, k, use_pallas, bz)
+    nxl, k = geom.lanes, geom.k
     pad = k * HALO
     v = velocity_model(cfg)
     v2dt2 = (v * cfg.dt / cfg.dx) ** 2
     sponge = sponge_taper(cfg)
-    v2e_all = _overlapped_field(np.asarray(v2dt2), n, pad)
-    spe_all = _overlapped_field(np.asarray(sponge), n, pad)
+    # zero model fields in the padding hold the padded cells at 0
+    edge = ((0, geom.rows - cfg.nz), (0, geom.cols - cfg.nx))
+    v2e_all = _overlapped_field(np.pad(np.asarray(v2dt2), edge), n, pad)
+    spe_all = _overlapped_field(np.pad(np.asarray(sponge), edge), n, pad)
     wavelet = ricker(cfg)
     pos = cfg.shot_positions()
     src_z = jnp.asarray(pos[:, 0])
@@ -234,8 +370,14 @@ def _sharded_block_parts(cfg: FWIConfig, mesh: Mesh, k: int,
             jnp.clip(t0 + jnp.arange(k), 0, cfg.timesteps - 1)
         ] * (cfg.dt ** 2)
 
+    # the interior's kernel runs at the tiling the geometry resolved;
+    # the narrow boundary windows pick their own
+    interior_tiling = {"bz": geom.bz, "stream": geom.stream,
+                       "shot_tile": geom.shot_tile}
+    boundary_tiling = {"bz": bz}
+
     # --- k fused steps on a window via wave_block -------------------
-    def window(px, ppx, vw, sw, wx0, x0, srcv):
+    def window(px, ppx, vw, sw, wx0, x0, srcv, tiling=boundary_tiling):
         # wx0: local column of window column 0 (traced).  Sources
         # inject into EVERY window covering their column, so redundant
         # zones track true neighbor physics; each window's valid region
@@ -252,7 +394,7 @@ def _sharded_block_parts(cfg: FWIConfig, mesh: Mesh, k: int,
         return wave_block(
             px, ppx, vw, sw, sv, src_z, xc,
             receiver_row=cfg.receiver_depth,
-            use_pallas=use_pallas, bz=bz,
+            use_pallas=use_pallas, **tiling,
         )
 
     @jax.named_scope("fwi.interior")
@@ -261,7 +403,7 @@ def _sharded_block_parts(cfg: FWIConfig, mesh: Mesh, k: int,
         # seams cannot influence within one block
         return window(
             p, p_prev, v2e[:, pad: pad + nxl], spe[:, pad: pad + nxl],
-            0, x0, srcv,
+            0, x0, srcv, interior_tiling,
         )
 
     @jax.named_scope("fwi.boundary")
@@ -386,9 +528,22 @@ def _sharded_block_parts(cfg: FWIConfig, mesh: Mesh, k: int,
         )
 
     def place(state_fields):
-        return jax.device_put(state_fields, sh)
+        """Logical (S, nz, nx) fields onto the stripes, padded.  A device
+        array is padded where it lies; a host array (a checkpoint) goes
+        shard by shard, so that no device holds more than its stripe."""
+        if not geom.padded(cfg):
+            return jax.device_put(jax.tree.map(jnp.asarray, state_fields),
+                                  sh)
 
-    return sms, v2e_all, spe_all, place, k
+        def one(f):
+            if isinstance(f, jax.Array):
+                return jax.device_put(_pad(f, geom.rows, geom.cols), sh)
+            return _put_padded(np.asarray(f), (geom.rows, geom.cols), sh)
+
+        return jax.tree.map(one, state_fields)
+
+    place.geometry = geom
+    return sms, v2e_all, spe_all, place, geom
 
 
 @resolves_use_pallas
@@ -402,8 +557,9 @@ def make_sharded_multistep(cfg: FWIConfig, mesh: Mesh, *, k: int = 1,
     Returns (block_step, place): ``block_step(p, p_prev, t0)`` advances
     ALL k timesteps with a single packed halo exchange and returns
     (p, p_prev, traces) with traces (S, k, NX).  Fields are (S, NZ, NX)
-    sharded on x over "stripe".  ``overlap`` takes the legacy bool
-    (True="overlap", False="fused") or a schedule name; ``None``
+    sharded on x over "stripe", on the padded grid ``place`` makes of
+    logical fields (``crop`` takes them back).  ``overlap`` takes the
+    legacy bool (True="overlap", False="fused") or a schedule name; ``None``
     auto-selects per backend (``pick_schedule``).  The cross-block
     "pipeline" schedule needs a scan to carry halos through, so the
     single-block API maps it to its within-block form, "overlap".
@@ -415,14 +571,15 @@ def make_sharded_multistep(cfg: FWIConfig, mesh: Mesh, *, k: int = 1,
     schedule = _as_schedule(overlap)
     if schedule == "pipeline":
         schedule = "overlap"
-    sms, v2e_all, spe_all, place, k = _sharded_block_parts(
+    sms, v2e_all, spe_all, place, geom = _sharded_block_parts(
         cfg, mesh, k, use_pallas, bz, schedule
     )
-    sm = sms["block"]
+    sm, k = sms["block"], geom.k
 
-    jit_block = jax.jit(
-        lambda p, p_prev, t0: sm(p, p_prev, v2e_all, spe_all, t0)
-    )
+    @jax.jit
+    def jit_block(p, p_prev, t0):
+        pn, pd, tr = sm(p, p_prev, v2e_all, spe_all, t0)
+        return pn, pd, _crop_traces(cfg, tr)
 
     def block_step(p, p_prev, t0):
         return jit_block(p, p_prev, t0)
@@ -467,11 +624,14 @@ def make_sharded_scan_runner(cfg: FWIConfig, mesh: Mesh, *, k: int = 4,
     ppermute before its own interior compute and stitch, and the last
     block's exchange is discarded (one wasted epilogue message —
     the price of keeping every other exchange a full block ahead).
-    Returns (p, p_prev, traces (S, blocks·k, NX))."""
+    Returns (p, p_prev, traces (S, blocks·k, NX)): the fields on the
+    padded grid ``place`` makes of logical ones (``stripe_geometry``;
+    ``crop`` takes them back), the traces on the logical one."""
     schedule = _as_schedule(overlap)
-    sms, v2e_all, spe_all, place, k = _sharded_block_parts(
+    sms, v2e_all, spe_all, place, geom = _sharded_block_parts(
         cfg, mesh, k, use_pallas, bz, schedule
     )
+    k = geom.k
 
     if schedule == "pipeline":
         sm_pro, sm_pipe = sms["prologue"], sms["pipeline"]
@@ -490,7 +650,7 @@ def make_sharded_scan_runner(cfg: FWIConfig, mesh: Mesh, *, k: int = 4,
             (p, pp, _), traces = jax.lax.scan(
                 body, (p, p_prev, halos), jnp.arange(blocks)
             )
-            return p, pp, _trace_rows(traces)
+            return p, pp, _crop_traces(cfg, _trace_rows(traces))
     else:
         sm = sms["block"]
 
@@ -504,9 +664,16 @@ def make_sharded_scan_runner(cfg: FWIConfig, mesh: Mesh, *, k: int = 4,
             (p, pp), traces = jax.lax.scan(
                 body, (p, p_prev), jnp.arange(blocks)
             )
-            return p, pp, _trace_rows(traces)
+            return p, pp, _crop_traces(cfg, _trace_rows(traces))
 
     return run, place, k
+
+
+def _crop_traces(cfg: FWIConfig, traces):
+    """Receiver traces (S, T, cols) cut to the logical nx columns."""
+    if traces.shape[-1] == cfg.nx:
+        return traces
+    return _crop(traces, traces.shape[-2], cfg.nx)
 
 
 @jax.named_scope("fwi.traces")
@@ -539,7 +706,7 @@ def halo_exchange_plan(cfg: FWIConfig, n_stripes: int, k: int = 1) -> dict:
     stripe width."""
     k = effective_block(cfg, n_stripes, k)
     pad = k * HALO
-    nxl = cfg.nx // n_stripes
+    nxl = -(-cfg.nx // n_stripes)
     fields = 1 if k == 1 else 2
     per_exchange = 2 * fields * pad * cfg.nz * cfg.n_shots * 4
     interior_cols = nxl                   # overlappable with the seam

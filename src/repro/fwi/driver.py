@@ -9,6 +9,12 @@ monitoring → prediction → burst exactly as for LM training;
 CHECKPOINT/RESHARD are real: fields are pulled to host and re-placed
 under the new stripe mesh.
 
+The session holds its fields on the runner's padded grid
+(``stripe_geometry``: a ragged survey grid gets zero rows and columns
+the kernel can tile); everything that leaves it — ``p`` and ``p_prev``,
+the ``checkpoint()`` dict — is on the logical grid, so a RESHARD onto
+another stripe count pads afresh for its own.
+
 Measurement is AMORTIZED over a scan block: the session dispatches one
 jitted ``make_sharded_scan_runner`` call covering ``scan_block``
 timesteps (temporally blocked at ``exchange_interval`` steps per halo
@@ -27,7 +33,6 @@ import signal
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint.manager import (
@@ -37,6 +42,7 @@ from repro.checkpoint.manager import (
 from repro.core.orchestrator import Resources, Session, elastic_chips
 from repro.core.spans import span
 from repro.fwi.domain import (
+    crop,
     effective_block,
     make_sharded_scan_runner,
     stripe_mesh,
@@ -97,10 +103,8 @@ class FWISession(Session):
         self.tm = time_model
         self.rng = rng
         n = n_stripes or min(len(jax.devices()), max(res.total_chips, 1))
-        while cfg.nx % n:
-            n -= 1
         self.session = next(_session_ids)
-        with span("fwi.remesh", session=self.session, stripes=n):
+        with span("fwi.remesh", session=self.session, stripes=n) as remesh:
             self.mesh = stripe_mesh(n)
             use_pallas = resolve_use_pallas(use_pallas)
             bz = None
@@ -111,7 +115,8 @@ class FWISession(Session):
                 # rebuild does not re-time.  If the stripe clamp shrinks
                 # the tuned k, re-derive bz for the clamped k instead of
                 # keeping the strip that won jointly with the larger one.
-                bz, exchange_interval = autotune_bz_k(cfg.nz, cfg.nx // n)
+                bz, exchange_interval = autotune_bz_k(cfg.nz,
+                                                      -(-cfg.nx // n))
                 keff = effective_block(cfg, n, exchange_interval)
                 if keff != exchange_interval:
                     exchange_interval = keff
@@ -122,22 +127,25 @@ class FWISession(Session):
                 cfg, self.mesh, k=exchange_interval, use_pallas=use_pallas,
                 bz=bz,
             )
+            # the runner's grid and its interior kernel's tiling
+            g = place.geometry
+            remesh.attrs.update(rows=g.rows, lanes=g.lanes, stream=g.stream,
+                                shot_tile=g.shot_tile, bz=g.bz, win=g.win)
         # timesteps per measured dispatch (multiple of the exchange
         # interval so every block is fully temporally blocked)
         self.block = max(scan_block // self.k, 1) * self.k
         with span("fwi.place", session=self.session,
                   devices=[d.id for d in self.mesh.devices.flat]) as sp:
             if restored is not None:
-                st = ShotState(
-                    p=jnp.asarray(restored["p"]),
-                    p_prev=jnp.asarray(restored["p_prev"]),
-                    t=int(restored["t"]),
-                )
+                fields = (restored["p"], restored["p_prev"])
+                self.t = int(restored["t"])
             else:
                 st = ShotState.init(cfg)
-            sp.attrs["bytes"] = st.p.nbytes + st.p_prev.nbytes
-            self.p, self.p_prev = place((st.p, st.p_prev))
-        self.t = st.t
+                fields, self.t = (st.p, st.p_prev), st.t
+            sp.attrs["bytes"] = sum(f.nbytes for f in fields)
+            #: (p, p_prev) on the runner's padded grid
+            self.carry = place(fields)
+            sp.attrs["padded_bytes"] = sum(f.nbytes for f in self.carry)
         # logical steps already covered by the last dispatched block —
         # carried through checkpoints so a mid-block RESHARD resumes the
         # remaining steps instead of re-dispatching (physical timesteps
@@ -165,16 +173,26 @@ class FWISession(Session):
                     and old_eff > 0.0 and self._eff > 0.0):
                 self._amortized *= old_eff / self._eff
 
+    @property
+    def p(self):
+        """The wavefield on the logical grid."""
+        return crop(self.cfg, self.carry[0])
+
+    @property
+    def p_prev(self):
+        """The damped previous wavefield on the logical grid."""
+        return crop(self.cfg, self.carry[1])
+
     def _advance_block(self) -> float:
         """Dispatch one scan block; returns amortized wall s/step."""
         blocks = self.block // self.k
         steps = blocks * self.k
         with span("fwi.dispatch", session=self.session,
                   steps=steps) as dispatch:
-            p, pp, _ = self.runner(self.p, self.p_prev, self.t, blocks)
+            p, pp, _ = self.runner(*self.carry, self.t, blocks)
         with span("fwi.wait", session=self.session) as wait:
             jax.block_until_ready(p)
-        self.p, self.p_prev = p, pp
+        self.carry = (p, pp)
         self.t += steps
         return (wait.t1 - dispatch.t0) / steps
 

@@ -266,9 +266,13 @@ def test_fwi_session_spans_its_mesh_placement_blocks_and_checkpoint():
         "fwi.remesh", "fwi.place", "fwi.dispatch", "fwi.wait",
         "fwi.fetch", "fwi.fetch", "fwi.checkpoint"]
     remesh, place, dispatch, wait, f_p, f_pp, ckpt = got
-    assert remesh.attrs == {"session": s.session, "stripes": 1}
+    # an aligned grid on the XLA path: no padding, one unstripped block
+    assert remesh.attrs == {
+        "session": s.session, "stripes": 1, "rows": 32, "lanes": 64,
+        "stream": False, "shot_tile": 2, "bz": None, "win": None}
     assert place.attrs == {
         "session": s.session, "bytes": s.p.nbytes + s.p_prev.nbytes,
+        "padded_bytes": s.p.nbytes + s.p_prev.nbytes,
         "devices": [d.id for d in s.mesh.devices.flat]}
     assert dispatch.attrs == {"session": s.session, "steps": 8}
     assert dispatch.t1 <= wait.t0
@@ -279,6 +283,32 @@ def test_fwi_session_spans_its_mesh_placement_blocks_and_checkpoint():
         (s.p.nbytes, s.p_prev.nbytes)
     # the amortised step time is the recorded dispatch-and-wait pair's
     assert s._amortized == (wait.t1 - dispatch.t0) / 8
+
+
+def test_fwi_session_spans_its_padded_geometry(monkeypatch):
+    """61 × 203 under a small VMEM budget streams as the 13601 × 2801
+    survey grid does under the real one: shot tile 1, 8-row strips, the
+    height padded to 64 and the width to 256 lanes."""
+    import repro.kernels.stencil.kernel as kernel
+    from repro.fwi.driver import FWISession, TimeModel
+    from repro.fwi.solver import FWIConfig
+
+    monkeypatch.setattr(kernel, "DEFAULT_VMEM_BUDGET", 300_000)
+    cfg = FWIConfig(nz=61, nx=203, timesteps=33, n_shots=2, sponge_width=4)
+    t = time.perf_counter()
+    s = FWISession(
+        cfg, Resources(pods=[PodSpec(chips=1, name="cluster")],
+                       shares=[1.0]),
+        0, None, time_model=TimeModel(jitter=0.0),
+        rng=np.random.default_rng(0), exchange_interval=4, scan_block=8,
+        use_pallas=True)
+    remesh, place = _of(s, t)
+    assert remesh.attrs == {
+        "session": s.session, "stripes": 1, "rows": 64, "lanes": 256,
+        "stream": True, "shot_tile": 1, "bz": 8, "win": 24}
+    assert place.attrs["bytes"] == 2 * 2 * 61 * 203 * 4
+    assert place.attrs["padded_bytes"] == 2 * 2 * 64 * 256 * 4
+    assert s.p.shape == (2, 61, 203)
 
 
 def test_fwi_session_restored_in_a_transition_spans_its_placement():
@@ -350,3 +380,33 @@ def test_shot_tiles_carry_named_scopes_in_the_hlo():
         use_pallas=False, shot_tile=2)).lower(f, f).compile().as_text()
     assert "/stencil.tile_split/" in hlo
     assert "/stencil.tile_concat/" in hlo
+
+
+@pytest.mark.parametrize("nz,nx,padded", [(32, 64, False), (61, 203, True)])
+def test_padding_carries_named_scopes_only_on_a_ragged_grid(
+        monkeypatch, nz, nx, padded):
+    """Placement, a scan and the crop in one program: a ragged grid
+    pads under ``fwi.pad`` and crops under ``fwi.crop``; an aligned one
+    compiles with neither."""
+    import jax
+    import jax.numpy as jnp
+
+    import repro.kernels.stencil.kernel as kernel
+    from repro.fwi.domain import crop, make_sharded_scan_runner, stripe_mesh
+    from repro.fwi.solver import FWIConfig
+
+    # the 61 × 203 interior streams under this budget, so it is padded
+    monkeypatch.setattr(kernel, "DEFAULT_VMEM_BUDGET", 300_000)
+    cfg = FWIConfig(nz=nz, nx=nx, timesteps=34, n_shots=2, sponge_width=4)
+    run, place, k = make_sharded_scan_runner(
+        cfg, stripe_mesh(1), k=4, use_pallas=False, overlap="overlap")
+    assert place.geometry.padded(cfg) == padded
+
+    def program(p, pp):
+        pn, pd, traces = run(*place((p, pp)), 0, 2)
+        return crop(cfg, pn), crop(cfg, pd), traces
+
+    z = jnp.zeros((cfg.n_shots, nz, nx), jnp.float32)
+    hlo = jax.jit(program).lower(z, z).compile().as_text()
+    for scope in ("fwi.pad", "fwi.crop"):
+        assert (f"/{scope}/" in hlo) == padded, scope

@@ -64,20 +64,25 @@ def test_resident_kernel_compiles_for_v5e(one_chip, n_shots, nz, nx):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("n_shots,nz,nx,in_grid", [
+@pytest.mark.parametrize("n_shots,nz,nx,in_grid,tiles", [
     # the production grid (DESIGN.md §15): one shot tile alone
-    pytest.param(16, 4096, 4096, False, id="16-4096-4096"),
+    pytest.param(16, 4096, 4096, False, 1, id="16-4096-4096"),
     # one of its four stripes
-    pytest.param(16, 4096, 1024, False, id="16-4096-1024"),
+    pytest.param(16, 4096, 1024, False, 1, id="16-4096-1024"),
     # the production grid's whole batch, its tiles walked in the grid
-    pytest.param(16, 4096, 4096, True, id="16-4096-4096-tiles-in-grid"),
+    pytest.param(16, 4096, 4096, True, 4, id="16-4096-4096-tiles-in-grid"),
+    # the 13601 x 2801 survey grid as the program pads it
+    # (``stripe_geometry``): one 13,696-lane stripe, shot tiles of 1 ...
+    pytest.param(12, 2808, 13696, True, 12, id="12-2808-13696-tiles-in-grid"),
+    # ... and one of its four 3,456-lane stripes, shot tiles of 6
+    pytest.param(12, 2808, 3456, True, 2, id="12-2808-3456-tiles-in-grid"),
 ])
 def test_streamed_kernel_compiles_for_v5e(one_chip, n_shots, nz, nx,
-                                          in_grid):
+                                          in_grid, tiles):
     assert should_stream(nz, nx, K)
     tile = pick_shot_tile(n_shots, nz, nx, K, stream=True)
     batch = n_shots if in_grid else tile
-    assert batch // tile == (4 if in_grid else 1)
+    assert batch // tile == tiles
     compiled = _compile(
         lambda p, pp, v, s, sv, z, x: wave_block_shots_stream_pallas(
             p, pp, v, s, sv, z, x, receiver_row=2, interpret=False,
