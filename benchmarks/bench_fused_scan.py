@@ -317,7 +317,7 @@ def build_big_engines(cfg: FWIConfig, steps: int, *,
         keffs[name] = keff
 
     meta = {"k": k, "stripes": n, "k_effective": keffs,
-            "schedule_auto": pick_schedule()}
+            "schedule_auto": pick_schedule(n)}
     return engines, meta
 
 

@@ -18,7 +18,16 @@ blocking.  For k > 1 the previous-field edges ride in the SAME message
 (stacked), so ppermute invocations per timestep drop k× (2 per block vs
 2 per step) while amortized bytes stay flat.
 
-Communication HIDING comes in three schedules (DESIGN.md §13, §15):
+One stripe has no seam, so it needs none of this: its schedule,
+``"single"``, advances the whole stripe as one window per block — the
+interior ``wave_block`` alone, output uncropped — with no exchange, no
+boundary windows and no stitch.  The window zero-extends at both of
+its edges every inner step, and on one stripe both are physical edges,
+where zero IS the reference's value: the window's whole output is the
+answer.
+
+Where seams exist, communication HIDING comes in three schedules
+(DESIGN.md §13, §15):
 
 * ``"overlap"`` — within one block: the packed exchange is issued
   FIRST; the INTERIOR of the stripe — every column ≥ k·HALO from a
@@ -41,8 +50,9 @@ Communication HIDING comes in three schedules (DESIGN.md §13, §15):
   split schedules).
 
 The splits only pay where collectives are async, so ``pick_schedule``
-auto-selects per backend (TPU: "pipeline"; synchronous hosts:
-"fused"); ``pick_overlap`` is the legacy boolean view.
+auto-selects from the stripe count and the backend (one stripe:
+"single"; more on TPU: "pipeline"; on synchronous hosts: "fused");
+``pick_overlap`` is the legacy boolean view.
 ``halo_exchange_plan`` exports the seam-traffic AND overlap
 bookkeeping (``overlap_fraction``) that ``OverheadModel
 .with_overlapped_seam``, the ``measure_seam_latency`` probe and the
@@ -113,9 +123,14 @@ def pick_overlap(backend: str | None = None) -> bool:
     return (backend or jax.default_backend()) == "tpu"
 
 
-def pick_schedule(backend: str | None = None) -> str:
-    """Three-way schedule auto-selection for the sharded scan runner.
+def pick_schedule(n_stripes: int, backend: str | None = None) -> str:
+    """Schedule auto-selection for the sharded scan runner on
+    ``n_stripes`` stripes.
 
+    * ``"single"``   — one stripe: no seam, so no exchange.  The whole
+      stripe is one window whose zero extension at both edges is the
+      reference's boundary condition; its output needs no boundary
+      windows and no stitch.
     * ``"pipeline"`` — double-buffered halo exchange ACROSS scan blocks:
       block b+1's packed ppermute is issued before block b's interior
       compute and seam stitch, so the exchange hides behind a whole
@@ -127,18 +142,22 @@ def pick_schedule(backend: str | None = None) -> str:
       critical path; least redundant compute, the right choice where
       collectives are synchronous anyway (CPU hosts).
 
-    All three produce BIT-IDENTICAL results on the XLA path — the
+    The three split schedules apply where a seam exists (two stripes
+    or more); named explicitly they run on one stripe too, against
+    zero halos.  All produce BIT-IDENTICAL results on the XLA path — the
     invariance tests pin it — so this is purely a performance choice;
     ``measure_seam_latency`` (fwi/calibrate.py) audits it."""
+    if n_stripes == 1:
+        return "single"
     return "pipeline" if (backend or jax.default_backend()) == "tpu" \
         else "fused"
 
 
-def _as_schedule(overlap) -> str:
+def _as_schedule(overlap, n_stripes: int) -> str:
     """Normalize the legacy bool knob: True -> "overlap", False ->
-    "fused"; strings pass through; None -> ``pick_schedule()``."""
+    "fused"; strings pass through; None -> ``pick_schedule``."""
     if overlap is None:
-        return pick_schedule()
+        return pick_schedule(n_stripes)
     if isinstance(overlap, str):
         if overlap not in ("fused", "overlap", "pipeline"):
             raise ValueError(f"unknown halo schedule: {overlap!r}")
@@ -305,8 +324,13 @@ def _sharded_block_parts(cfg: FWIConfig, mesh: Mesh, k: int,
     grid of ``geom`` (``stripe_geometry``) — callers jit at
     their own boundary (wrapping the body in its own jit inside a
     lax.scan defeats XLA's loop fusion; see solver.py).  ``sms`` is a
-    dict: ``{"block"}`` for the "fused"/"overlap" schedules,
+    dict: ``{"block"}`` for the "single"/"fused"/"overlap" schedules,
     ``{"prologue", "pipeline"}`` for "pipeline".
+
+    schedule="single" (one stripe only) is the interior window over the
+    whole stripe and nothing else: no exchange, no boundary windows, no
+    stitch; the model fields are built at the stripe's own width, so
+    the kernel reads them whole.
 
     schedule="overlap" realizes the within-block comm/compute-overlap
     schedule (DESIGN.md §13): packed halo ppermute issued first; the
@@ -325,10 +349,10 @@ def _sharded_block_parts(cfg: FWIConfig, mesh: Mesh, k: int,
     edges BEFORE block b's interior compute and seam stitch, and the
     interior fusion plus stitch fly under it.
 
-    On the XLA path the overlap and pipeline schedules are pinned
-    bitwise-identical to the reference (the pipeline computes the same
-    per-block graph as overlap — only the exchange's position in the
-    schedule moves); the single-window schedule computes the identical
+    On the XLA path the single, overlap and pipeline schedules are
+    pinned bitwise-identical to the reference (the pipeline computes the
+    same per-block graph as overlap — only the exchange's position in
+    the schedule moves); the fused schedule computes the identical
     op sequence but its different fusion shapes may flush denormal
     wavefront tails differently — equal up to sub-normal (< 1.2e-38)
     noise.
@@ -342,8 +366,10 @@ def _sharded_block_parts(cfg: FWIConfig, mesh: Mesh, k: int,
     sponge = sponge_taper(cfg)
     # zero model fields in the padding hold the padded cells at 0
     edge = ((0, geom.rows - cfg.nz), (0, geom.cols - cfg.nx))
-    v2e_all = _overlapped_field(np.pad(np.asarray(v2dt2), edge), n, pad)
-    spe_all = _overlapped_field(np.pad(np.asarray(sponge), edge), n, pad)
+    # the single window has no neighbour to overlap
+    fpad = 0 if schedule == "single" else pad
+    v2e_all = _overlapped_field(np.pad(np.asarray(v2dt2), edge), n, fpad)
+    spe_all = _overlapped_field(np.pad(np.asarray(sponge), edge), n, fpad)
     wavelet = ricker(cfg)
     pos = cfg.shot_positions()
     src_z = jnp.asarray(pos[:, 0])
@@ -470,6 +496,14 @@ def _sharded_block_parts(cfg: FWIConfig, mesh: Mesh, k: int,
         )
         return stitch(pb, pi), stitch(ppb, ppi), stitch(trb, tri)
 
+    def local_single_block(p, p_prev, v2e, spe, t0):
+        # one stripe: both window edges are physical, so the window's
+        # zero extension is the reference's and all nxl columns are
+        # valid after k steps
+        with jax.named_scope("fwi.interior"):
+            return window(p, p_prev, v2e[0], spe[0], 0, 0, make_srcv(t0),
+                          interior_tiling)
+
     def local_prologue(p, p_prev):
         # eager packed exchange priming the pipeline's halo carry for
         # block 0 — the only on-critical-path exchange of the whole scan
@@ -522,7 +556,8 @@ def _sharded_block_parts(cfg: FWIConfig, mesh: Mesh, k: int,
         )
     else:
         sms["block"] = jax.shard_map(
-            local_block, mesh=mesh,
+            local_single_block if schedule == "single" else local_block,
+            mesh=mesh,
             in_specs=(field, field, parts, parts, P()),
             out_specs=(field, field, field), check_vma=False,
         )
@@ -543,6 +578,7 @@ def _sharded_block_parts(cfg: FWIConfig, mesh: Mesh, k: int,
         return jax.tree.map(one, state_fields)
 
     place.geometry = geom
+    place.schedule = schedule
     return sms, v2e_all, spe_all, place, geom
 
 
@@ -560,7 +596,8 @@ def make_sharded_multistep(cfg: FWIConfig, mesh: Mesh, *, k: int = 1,
     sharded on x over "stripe", on the padded grid ``place`` makes of
     logical fields (``crop`` takes them back).  ``overlap`` takes the
     legacy bool (True="overlap", False="fused") or a schedule name; ``None``
-    auto-selects per backend (``pick_schedule``).  The cross-block
+    auto-selects from the stripe count and the backend (``pick_schedule``:
+    "single" on one stripe).  The cross-block
     "pipeline" schedule needs a scan to carry halos through, so the
     single-block API maps it to its within-block form, "overlap".
 
@@ -568,7 +605,7 @@ def make_sharded_multistep(cfg: FWIConfig, mesh: Mesh, *, k: int = 1,
     (``effective_block``); callers advancing t0 must use the EFFECTIVE
     block size, exposed as ``block_step.k``.
     """
-    schedule = _as_schedule(overlap)
+    schedule = _as_schedule(overlap, mesh.shape["stripe"])
     if schedule == "pipeline":
         schedule = "overlap"
     sms, v2e_all, spe_all, place, geom = _sharded_block_parts(
@@ -618,16 +655,18 @@ def make_sharded_scan_runner(cfg: FWIConfig, mesh: Mesh, *, k: int = 4,
     dispatch (a lax.scan over k-step fused blocks, one packed halo
     exchange per block).  ``overlap`` takes the legacy bool or a
     schedule name ("fused"/"overlap"/"pipeline"); ``None`` auto-selects
-    per backend (``pick_schedule`` — "pipeline" where collectives are
-    async).  Under "pipeline" the halos ride in the scan CARRY: a
-    prologue exchange primes block 0, each block issues block b+1's
-    ppermute before its own interior compute and stitch, and the last
-    block's exchange is discarded (one wasted epilogue message —
-    the price of keeping every other exchange a full block ahead).
+    (``pick_schedule`` — "single" on one stripe, with no exchange at
+    all; on more, "pipeline" where collectives are async).  The resolved
+    name is ``place.schedule``.  Under "pipeline" the halos ride in the
+    scan CARRY: a prologue exchange primes block 0, each block issues
+    block b+1's ppermute before its own interior compute and stitch,
+    and the last block's exchange is discarded (one wasted epilogue
+    message — the price of keeping every other exchange a full block
+    ahead).
     Returns (p, p_prev, traces (S, blocks·k, NX)): the fields on the
     padded grid ``place`` makes of logical ones (``stripe_geometry``;
     ``crop`` takes them back), the traces on the logical one."""
-    schedule = _as_schedule(overlap)
+    schedule = _as_schedule(overlap, mesh.shape["stripe"])
     sms, v2e_all, spe_all, place, geom = _sharded_block_parts(
         cfg, mesh, k, use_pallas, bz, schedule
     )
@@ -703,7 +742,12 @@ def halo_exchange_plan(cfg: FWIConfig, n_stripes: int, k: int = 1) -> dict:
     ppermute latency into the effective (un-hidden) seam residue.
     ``redundant_frac`` is the extra trapezoid compute the boundary
     windows pay (4·k·HALO of 2·k·HALO patched columns) relative to the
-    stripe width."""
+    stripe width.
+
+    It describes the seams of ``n_stripes`` ≥ 2 stripes.  One stripe has
+    none: its default "single" schedule sends no message and runs no
+    boundary window, whatever the plan's figures for ``n_stripes`` = 1
+    say."""
     k = effective_block(cfg, n_stripes, k)
     pad = k * HALO
     nxl = -(-cfg.nx // n_stripes)

@@ -127,10 +127,12 @@ class FWISession(Session):
                 cfg, self.mesh, k=exchange_interval, use_pallas=use_pallas,
                 bz=bz,
             )
-            # the runner's grid and its interior kernel's tiling
+            # the runner's grid, its interior kernel's tiling and the
+            # halo schedule it resolved for this stripe count
             g = place.geometry
             remesh.attrs.update(rows=g.rows, lanes=g.lanes, stream=g.stream,
-                                shot_tile=g.shot_tile, bz=g.bz, win=g.win)
+                                shot_tile=g.shot_tile, bz=g.bz, win=g.win,
+                                schedule=place.schedule)
         # timesteps per measured dispatch (multiple of the exchange
         # interval so every block is fully temporally blocked)
         self.block = max(scan_block // self.k, 1) * self.k

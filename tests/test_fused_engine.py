@@ -25,6 +25,7 @@ import numpy as np
 import pytest
 
 from repro.fwi.domain import (
+    crop,
     effective_block,
     halo_bytes_per_step,
     halo_exchange_plan,
@@ -196,12 +197,14 @@ def test_temporal_block_multi_stripe_subprocess():
 
 def test_ppermute_count_drops_k_fold():
     """k=4 temporal blocking must emit the SAME 2 collective-permutes
-    per block as k=1 — i.e. 4× fewer per timestep."""
+    per block as k=1 — i.e. 4× fewer per timestep.  One stripe's default
+    exchanges nothing, so the exchanging schedule is named."""
     mesh = stripe_mesh(1)
     s = ShotState.init(CFG)
     counts = {}
     for k in (1, 4):
-        blk, place = make_sharded_multistep(CFG, mesh, k=k)
+        blk, place = make_sharded_multistep(CFG, mesh, k=k,
+                                            overlap="overlap")
         p, pp = place((s.p, s.p_prev))
         txt = jax.jit(blk).lower(p, pp, 0).as_text()
         counts[k] = txt.count("collective_permute") \
@@ -484,6 +487,113 @@ def test_sharded_pallas_schedules_on_one_stripe(schedule):
                                atol=1e-5 * scale)
     np.testing.assert_allclose(np.asarray(tr), np.asarray(ref_tr),
                                atol=1e-5 * scale)
+
+
+# --------------------------------------------- one stripe, one window
+
+#: 61 × 203 streams under this VMEM budget, as 13601 × 2801 does under
+#: the real one: shot tile 1, 8-row strips, padded to 64 × 256
+SMALL_VMEM_BUDGET = 300_000
+RAGGED = FWIConfig(nz=61, nx=203, timesteps=32, n_shots=2, sponge_width=4)
+
+
+def _run_one_stripe(cfg, steps, **kw):
+    """The one-stripe scan runner's carry (padded), logical fields and
+    traces after ``steps`` steps from rest."""
+    run, place, k = make_sharded_scan_runner(cfg, stripe_mesh(1), k=4, **kw)
+    s = ShotState.init(cfg)
+    p, pp, tr = run(*place((s.p, s.p_prev)), 0, steps // k)
+    return place, (p, pp), (crop(cfg, p), crop(cfg, pp), tr)
+
+
+@pytest.mark.parametrize("cfg", [CFG, RAGGED], ids=["aligned", "ragged"])
+def test_one_stripe_default_is_the_single_window_bitwise(monkeypatch, cfg):
+    """One stripe's default schedule is the interior window alone, and
+    on the XLA path it equals the reference and the exchanging
+    "pipeline" schedule bit for bit: fields, padding and traces."""
+    import repro.kernels.stencil.kernel as kernel
+
+    ref, ref_tr = run_forward(cfg, steps=cfg.timesteps)
+    monkeypatch.setattr(kernel, "DEFAULT_VMEM_BUDGET", SMALL_VMEM_BUDGET)
+    place, carry, got = _run_one_stripe(cfg, cfg.timesteps)
+    pipe_place, pipe_carry, pipe = _run_one_stripe(
+        cfg, cfg.timesteps, overlap="pipeline")
+    assert (place.schedule, pipe_place.schedule) == ("single", "pipeline")
+    assert place.geometry.padded(cfg) == (cfg is RAGGED)
+    for a, b in zip(carry, pipe_carry):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for a, b, r in zip(got, pipe, (ref.p, ref.p_prev, ref_tr)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(r))
+        np.testing.assert_array_equal(np.asarray(b), np.asarray(r))
+
+
+def test_one_stripe_default_pallas_matches_reference(monkeypatch):
+    """The single window on the Pallas (interpret) path, streamed over a
+    padded grid at shot tiles smaller than the batch, matches the
+    reference to the Pallas 1e-5."""
+    import repro.kernels.stencil.kernel as kernel
+
+    ref, ref_tr = run_forward(RAGGED, steps=16, use_pallas=False)
+    monkeypatch.setattr(kernel, "DEFAULT_VMEM_BUDGET", SMALL_VMEM_BUDGET)
+    place, _, (p, pp, tr) = _run_one_stripe(RAGGED, 16, use_pallas=True)
+    g = place.geometry
+    assert place.schedule == "single"
+    assert g.stream and g.shot_tile < RAGGED.n_shots
+    scale = float(np.max(np.abs(np.asarray(ref.p))))
+    for a, r in ((p, ref.p), (pp, ref.p_prev), (tr, ref_tr)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r),
+                                   atol=1e-5 * scale)
+
+
+_SEAM_PHASES = ("collective-permute", "/fwi.exchange/", "/fwi.boundary/",
+                "/fwi.stitch/")
+
+_TWO_STRIPE_PHASES = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+import sys
+sys.path.insert(0, sys.argv[1])
+import jax, jax.numpy as jnp
+from repro.fwi.domain import (
+    make_sharded_scan_runner, pick_schedule, stripe_mesh)
+from repro.fwi.solver import FWIConfig
+
+cfg = FWIConfig(nz=32, nx=64, timesteps=32, n_shots=2, sponge_width=4)
+# the TPU's default for two stripes, and this host's own
+for schedule in (pick_schedule(2, "tpu"), None):
+    run, place, k = make_sharded_scan_runner(
+        cfg, stripe_mesh(2), k=4, use_pallas=False, overlap=schedule)
+    z = jnp.zeros((cfg.n_shots, cfg.nz, cfg.nx), jnp.float32)
+    hlo = run.lower(*place((z, z)), 0, blocks=2).compile().as_text()
+    for phase in sys.argv[2:] + ["/fwi.interior/"]:
+        print(place.schedule, phase, phase in hlo)
+"""
+
+
+def test_single_window_drops_the_seam_phases_only_on_one_stripe():
+    """The compiled one-stripe default holds the interior and none of
+    the exchange, boundary windows or stitch; two stripes keep them all
+    under the TPU's default, and this host's default still exchanges."""
+    run, place, k = make_sharded_scan_runner(CFG, stripe_mesh(1), k=4)
+    s = ShotState.init(CFG)
+    hlo = run.lower(*place((s.p, s.p_prev)), 0, blocks=2).compile().as_text()
+    assert "/fwi.interior/" in hlo
+    for phase in _SEAM_PHASES + ("collective_permute",):
+        assert phase not in hlo, phase
+
+    env = dict(os.environ)
+    env.pop("XLA_FLAGS", None)
+    src = str(Path(__file__).resolve().parents[1] / "src")
+    out = subprocess.run(
+        [sys.executable, "-c", _TWO_STRIPE_PHASES, src, *_SEAM_PHASES],
+        capture_output=True, text=True, env=env, timeout=600,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    lines = out.stdout.split("\n")
+    for phase in _SEAM_PHASES + ("/fwi.interior/",):
+        assert f"pipeline {phase} True" in lines, out.stdout
+    for phase in ("collective-permute", "/fwi.exchange/"):
+        assert f"fused {phase} True" in lines, out.stdout
 
 
 def test_driver_checkpoint_carries_block_progress():

@@ -266,10 +266,12 @@ def test_fwi_session_spans_its_mesh_placement_blocks_and_checkpoint():
         "fwi.remesh", "fwi.place", "fwi.dispatch", "fwi.wait",
         "fwi.fetch", "fwi.fetch", "fwi.checkpoint"]
     remesh, place, dispatch, wait, f_p, f_pp, ckpt = got
-    # an aligned grid on the XLA path: no padding, one unstripped block
+    # an aligned grid on the XLA path: no padding, one unstripped block,
+    # one stripe's single window
     assert remesh.attrs == {
         "session": s.session, "stripes": 1, "rows": 32, "lanes": 64,
-        "stream": False, "shot_tile": 2, "bz": None, "win": None}
+        "stream": False, "shot_tile": 2, "bz": None, "win": None,
+        "schedule": "single"}
     assert place.attrs == {
         "session": s.session, "bytes": s.p.nbytes + s.p_prev.nbytes,
         "padded_bytes": s.p.nbytes + s.p_prev.nbytes,
@@ -305,7 +307,8 @@ def test_fwi_session_spans_its_padded_geometry(monkeypatch):
     remesh, place = _of(s, t)
     assert remesh.attrs == {
         "session": s.session, "stripes": 1, "rows": 64, "lanes": 256,
-        "stream": True, "shot_tile": 1, "bz": 8, "win": 24}
+        "stream": True, "shot_tile": 1, "bz": 8, "win": 24,
+        "schedule": "single"}
     assert place.attrs["bytes"] == 2 * 2 * 61 * 203 * 4
     assert place.attrs["padded_bytes"] == 2 * 2 * 64 * 256 * 4
     assert s.p.shape == (2, 61, 203)
@@ -323,6 +326,51 @@ def test_fwi_session_restored_in_a_transition_spans_its_placement():
     assert (remesh.name, place.name) == ("fwi.remesh", "fwi.place")
     assert remesh.parent == place.parent == transition.id
     assert place.attrs["bytes"] == snap["p"].nbytes + snap["p_prev"].nbytes
+
+
+_TWO_STRIPE_SESSION = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+import sys
+sys.path.insert(0, sys.argv[1])
+import time
+import numpy as np
+from repro.core import PodSpec, Resources, spans
+from repro.fwi.domain import pick_schedule
+from repro.fwi.driver import FWISession, TimeModel
+from repro.fwi.solver import FWIConfig
+
+t = time.perf_counter()
+cfg = FWIConfig(nz=32, nx=64, timesteps=32, n_shots=2, sponge_width=4)
+s = FWISession(
+    cfg, Resources(pods=[PodSpec(chips=2, name="cluster")], shares=[1.0]),
+    0, None, time_model=TimeModel(jitter=0.0),
+    rng=np.random.default_rng(0), n_stripes=2, exchange_interval=4)
+remesh, = [x for x in spans.recorded(t, float("inf"))
+           if x.name == "fwi.remesh"]
+print("stripes", remesh.attrs["stripes"])
+print("schedule", remesh.attrs["schedule"], pick_schedule(2))
+"""
+
+
+def test_fwi_remesh_names_the_schedule_it_resolved():
+    """One stripe's session resolves the single window; a two-stripe
+    session resolves the backend's exchanging schedule, and its
+    ``fwi.remesh`` span says so."""
+    t = time.perf_counter()
+    _fwi_session()
+    remesh = [x for x in since(t, "fwi.") if x.name == "fwi.remesh"][-1]
+    assert remesh.attrs["schedule"] == "single"
+
+    env = dict(os.environ)
+    env.pop("XLA_FLAGS", None)
+    out = subprocess.run([sys.executable, "-c", _TWO_STRIPE_SESSION, SRC],
+                         capture_output=True, text=True, env=env,
+                         timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    lines = out.stdout.split("\n")
+    assert "stripes 2" in lines, out.stdout
+    assert "schedule fused fused" in lines, out.stdout
 
 
 # -------------------------------------------------- scopes in the HLO
@@ -345,6 +393,7 @@ for schedule in ("overlap", "pipeline"):
     z = jnp.zeros((cfg.n_shots, cfg.nz, cfg.nx), jnp.float32)
     p, pp = place((z, z))
     hlo = run.lower(p, pp, 0, blocks=2).compile().as_text()
+    print(schedule, "resolved", place.schedule)
     for scope in ("fwi.exchange", "fwi.boundary", "fwi.interior",
                   "fwi.stitch", "fwi.traces"):
         print(schedule, scope, ('/' + scope + '/') in hlo)
@@ -360,6 +409,7 @@ def test_sharded_runner_phases_carry_named_scopes_in_the_hlo():
     assert out.returncode == 0, out.stderr[-3000:]
     lines = out.stdout.split("\n")
     for schedule in ("overlap", "pipeline"):
+        assert f"{schedule} resolved {schedule}" in lines, out.stdout
         for scope in ("fwi.exchange", "fwi.boundary", "fwi.interior",
                       "fwi.stitch", "fwi.traces"):
             assert f"{schedule} {scope} True" in lines, out.stdout

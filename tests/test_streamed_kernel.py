@@ -266,14 +266,17 @@ def test_pipeline_schedule_invariance_subprocess():
 def test_pick_schedule_and_normalization():
     from repro.fwi.domain import _as_schedule, pick_schedule
 
-    assert pick_schedule("tpu") == "pipeline"
-    assert pick_schedule("cpu") == "fused"
-    assert _as_schedule(None) == pick_schedule()
-    assert _as_schedule(True) == "overlap"     # legacy bool knob
-    assert _as_schedule(False) == "fused"
-    assert _as_schedule("pipeline") == "pipeline"
-    with pytest.raises(ValueError):
-        _as_schedule("bogus")
+    assert pick_schedule(4, "tpu") == "pipeline"
+    assert pick_schedule(4, "cpu") == "fused"
+    # one stripe has no seam: the single window on any backend
+    assert pick_schedule(1, "tpu") == pick_schedule(1, "cpu") == "single"
+    for n in (1, 2):
+        assert _as_schedule(None, n) == pick_schedule(n)
+        assert _as_schedule(True, n) == "overlap"     # legacy bool knob
+        assert _as_schedule(False, n) == "fused"
+        assert _as_schedule("pipeline", n) == "pipeline"
+        with pytest.raises(ValueError):
+            _as_schedule("bogus", n)
 
 
 # ------------------------------------------------- seam provenance
