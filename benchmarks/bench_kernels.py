@@ -11,8 +11,8 @@ kernels' *structure* (a tiling bug usually shows up as a blow-up), NOT
 TPU projections — those come from the roofline analysis.  On a TPU
 backend the same rows time the compiled kernels and are directly
 comparable.  Each row also emits the kernel's arithmetic-intensity
-estimate (flops/byte) used to pick block shapes; the stencil section
-additionally reports the ``autotune_bz`` winner for the paper grid."""
+estimate (flops/byte) used to pick block shapes.  The stencil kernels
+are measured on the chip by ``bench/`` (DESIGN.md §15)."""
 from __future__ import annotations
 
 import time
@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from repro.kernels.flash_attention.ops import attention
 from repro.kernels.rmsnorm.ops import rmsnorm_residual
 from repro.kernels.ssd.ops import ssd_chunk
-from repro.kernels.stencil.ops import autotune_bz, wave_step
 
 
 def _time(fn, *args, n=3, **kw):
@@ -38,19 +37,6 @@ def _time(fn, *args, n=3, **kw):
 
 def run() -> list[str]:
     rows = []
-    # stencil: 512x512 strip-tiled
-    nz = nx = 512
-    p = jnp.ones((nz, nx), jnp.float32)
-    v = jnp.full((nz, nx), 0.1, jnp.float32)
-    us_ref = _time(wave_step, p, p, v, v, use_pallas=False)
-    us_pal = _time(wave_step, p, p, v, v, use_pallas=True, bz=128)
-    flops = nz * nx * 16
-    bytes_ = nz * nx * 4 * 6
-    rows += [
-        f"kernels.stencil_ref_512,{us_ref:.0f},{flops / bytes_:.2f}",
-        f"kernels.stencil_pallas_512,{us_pal:.0f},{flops / bytes_:.2f}",
-        f"kernels.stencil_autotune_bz_512,0,{autotune_bz(nz, nx)}",
-    ]
     # flash attention 1x4x512x64
     q = jnp.ones((1, 4, 512, 64), jnp.float32)
     k = jnp.ones((1, 2, 512, 64), jnp.float32)

@@ -8,8 +8,6 @@
   bench_envs            — Tables 1-2 (platform + workload configuration)
   bench_kernels         — Pallas kernel µbenches (interpret mode)
   bench_roofline        — EXPERIMENTS §Roofline from dry-run artifacts
-  bench_fused_scan      — overlap-and-fuse engine vs PR 1 scan vs seed
-                          loop; HBM launch-boundary proxy
   bench_fleet_scenarios — autoscaler policy suite × fleet scenarios
                           (hit-rate / cloud cost / useful-work frac)
   bench_faults          — fault-storm robustness grid: hardened vs
@@ -27,9 +25,8 @@
 Usage:
   python benchmarks/run.py [--only a,b,...] [--json PATH] [--big]
 
-``--big`` adds the production-scale ``fused_scan_big`` tier (4096²,
-8192×2048 streamed-vs-resident + sharded-schedule rows); it is off by
-default because it takes minutes on CPU.
+``--big`` adds the thousand-job ``fleet_tournament_big`` tier; it is
+off by default because it takes minutes on CPU.
 
 ``--json`` additionally writes machine-readable results: one record per
 row with the name/us_per_call/derived fields parsed apart, plus the
@@ -88,19 +85,12 @@ from benchmarks import (  # noqa: E402
     bench_faults,
     bench_fleet_scenarios,
     bench_fleet_tournament,
-    bench_fused_scan,
     bench_gamma_fit,
     bench_kernels,
     bench_overheads,
     bench_real_elastic,
     bench_roofline,
 )
-
-class _BigFusedScan:
-    """`--big` tier shim: bench module whose run() is run_big()."""
-
-    run = staticmethod(bench_fused_scan.run_big)
-
 
 class _BigFleetTournament:
     """`--big` tier shim: thousand-job tournament (run_big())."""
@@ -119,7 +109,6 @@ BENCHES = [
     ("real_elastic", bench_real_elastic),
     ("overheads", bench_overheads),
     ("kernels", bench_kernels),
-    ("fused_scan", bench_fused_scan),
     ("roofline", bench_roofline),
 ]
 
@@ -146,13 +135,11 @@ def main(argv: list[str] | None = None) -> None:
     ap.add_argument("--json", default="", metavar="PATH",
                     help="write machine-readable results to PATH")
     ap.add_argument("--big", action="store_true",
-                    help="include the production-scale fused_scan_big "
+                    help="include the thousand-job fleet_tournament_big "
                          "tier (minutes on CPU)")
     args = ap.parse_args(argv)
     only = {s for s in args.only.split(",") if s}
     benches = list(BENCHES)
-    if args.big or "fused_scan_big" in only:
-        benches.append(("fused_scan_big", _BigFusedScan))
     if args.big or "fleet_tournament_big" in only:
         benches.append(("fleet_tournament_big", _BigFleetTournament))
     unknown = only - {name for name, _ in benches}

@@ -58,13 +58,9 @@ BUDGET_SAMPLES = (1024 * 1024, 16 * 1024 * 1024)
 
 #: wrapper function -> (formula name, sample -> formula kwargs)
 WRAPPER_FORMULAS = {
-    "wave_block_pallas": ("resident_vmem_bytes", lambda e: {
-        "nz": e["nz"], "nx": e["nx"], "k": e["k"], "bz": e["bz"], "s": 1}),
     "wave_block_shots_pallas": ("resident_vmem_bytes", lambda e: {
         "nz": e["nz"], "nx": e["nx"], "k": e["k"], "bz": e["bz"],
         "s": e["ns"]}),
-    "wave_block_stream_pallas": ("stream_vmem_bytes", lambda e: {
-        "nz": e["nz"], "nx": e["nx"], "bz": e["bz"], "k": e["k"], "s": 1}),
     "wave_block_shots_stream_pallas": ("stream_vmem_bytes", lambda e: {
         "nz": e["nz"], "nx": e["nx"], "bz": e["bz"], "k": e["k"],
         "s": e["ns"]}),

@@ -37,7 +37,7 @@ def measure_gamma_sweep(
     compute-dominated steps)."""
     import jax
 
-    from repro.fwi.solver import ShotState, make_scan_runner
+    from repro.fwi.solver import ShotState, make_block_runner
 
     times = []
     for nx in widths:
@@ -46,7 +46,7 @@ def measure_gamma_sweep(
             timesteps=steps, n_shots=base.n_shots,
             sponge_width=base.sponge_width,
         )
-        runner = make_scan_runner(cfg)
+        runner = make_block_runner(cfg, collect_traces=False)
         st = ShotState.init(cfg)
         jax.block_until_ready(runner(st.p, st.p_prev, 0, steps))  # compile
         best = float("inf")

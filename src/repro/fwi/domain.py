@@ -1,4 +1,4 @@
-"""Striped domain decomposition + overlapped, temporally-blocked halo
+"""Striped domain decomposition + pipelined, temporally-blocked halo
 exchange (paper Fig. 2, communication-avoiding + communication-hiding).
 
 The x-axis (width) is cut into contiguous column stripes, one per device
@@ -26,37 +26,23 @@ its edges every inner step, and on one stripe both are physical edges,
 where zero IS the reference's value: the window's whole output is the
 answer.
 
-Where seams exist, communication HIDING comes in three schedules
-(DESIGN.md §13, §15):
+Where seams exist (two stripes or more), communication HIDING is the
+``"pipeline"`` schedule (DESIGN.md §13, §15): the INTERIOR of the
+stripe — every column ≥ k·HALO from a seam, which by construction never
+reads the halo within one k-step block — is one fused ``wave_block``;
+two narrow (3·k·HALO-column) BOUNDARY windows consume the received
+halos and are stitched in.  The exchange runs ACROSS scan blocks: the
+received halos ride in the scan carry, each block computes its boundary
+windows first from the CARRIED halos, issues the NEXT block's exchange
+from their fresh edge columns, then computes interior + stitch — so a
+whole block of compute covers each exchange (one eager prologue
+exchange; one wasted epilogue exchange).
 
-* ``"overlap"`` — within one block: the packed exchange is issued
-  FIRST; the INTERIOR of the stripe — every column ≥ k·HALO from a
-  seam, which by construction never reads the halo within one k-step
-  block — is computed as one fused ``wave_block`` while the ppermute
-  is in flight; two narrow (3·k·HALO-column) BOUNDARY windows that do
-  consume the received halos are computed after and stitched in.
-  Per-block cost drops from ``compute + seam`` to
-  ``max(interior, seam) + boundary``.
-* ``"pipeline"`` — ACROSS scan blocks: the received halos ride in the
-  scan carry, each block computes its boundary windows first from the
-  CARRIED halos, issues the NEXT block's exchange from their fresh
-  edge columns, then computes interior + stitch — so a whole block of
-  compute covers each exchange instead of only the interior window
-  (one eager prologue exchange; one wasted epilogue exchange).  The
-  per-block op graph is the overlap schedule's, reordered: pinned
-  BITWISE equal.
-* ``"fused"`` — comm-avoiding single window, exchange on the critical
-  path, least redundant compute (2·k·HALO columns vs 6·k·HALO for the
-  split schedules).
-
-The splits only pay where collectives are async, so ``pick_schedule``
-auto-selects from the stripe count and the backend (one stripe:
-"single"; more on TPU: "pipeline"; on synchronous hosts: "fused");
-``pick_overlap`` is the legacy boolean view.
-``halo_exchange_plan`` exports the seam-traffic AND overlap
-bookkeeping (``overlap_fraction``) that ``OverheadModel
-.with_overlapped_seam``, the ``measure_seam_latency`` probe and the
-overhead benches consume.
+``pick_schedule`` chooses from the stripe count alone: "single" on one
+stripe, "pipeline" on more, on every backend.  ``halo_exchange_plan``
+exports the seam-traffic AND overlap bookkeeping (``overlap_fraction``)
+that ``OverheadModel.with_overlapped_seam``, the
+``measure_seam_latency`` probe and the overhead benches consume.
 
 Physical domain edges need no special-casing: every window is
 zero-extended in x, which at a physical edge IS the reference's
@@ -108,61 +94,24 @@ def stripe_mesh(n_devices: int | None = None) -> Mesh:
     return make_mesh((n,), ("stripe",), devices=devs[:n])
 
 
-def pick_overlap(backend: str | None = None) -> bool:
-    """Schedule selection for the sharded block body (DESIGN.md §13).
-
-    The interior/boundary overlap split only pays where collectives are
-    ASYNC (TPU: collective-permute-start/done hide behind the interior
-    fusion); on hosts whose ppermute is synchronous the split is pure
-    overhead — 6·k·HALO redundant columns instead of 2·k·HALO — so the
-    comm-avoiding single-window schedule wins.  Same auto-selection
-    spirit as the kernel's ``default_interpret``/``pick_bz``.
-
-    Kept as the boolean (PR 3) view of the choice; the full three-way
-    schedule selection lives in ``pick_schedule`` (DESIGN.md §15)."""
-    return (backend or jax.default_backend()) == "tpu"
-
-
-def pick_schedule(n_stripes: int, backend: str | None = None) -> str:
-    """Schedule auto-selection for the sharded scan runner on
-    ``n_stripes`` stripes.
+def pick_schedule(n_stripes: int) -> str:
+    """The halo schedule of the sharded scan runner on ``n_stripes``
+    stripes.
 
     * ``"single"``   — one stripe: no seam, so no exchange.  The whole
       stripe is one window whose zero extension at both edges is the
       reference's boundary condition; its output needs no boundary
       windows and no stitch.
-    * ``"pipeline"`` — double-buffered halo exchange ACROSS scan blocks:
-      block b+1's packed ppermute is issued before block b's interior
-      compute and seam stitch, so the exchange hides behind a whole
-      block of work instead of only the same block's interior window.
-      Needs async collectives — selected on TPU.
-    * ``"overlap"``  — PR 3's within-block split (exchange first,
-      interior while it flies, boundary windows after).
-    * ``"fused"``    — comm-avoiding single window, exchange on the
-      critical path; least redundant compute, the right choice where
-      collectives are synchronous anyway (CPU hosts).
+    * ``"pipeline"`` — two stripes or more: double-buffered halo
+      exchange ACROSS scan blocks: block b+1's packed ppermute is
+      issued before block b's interior compute and seam stitch, so the
+      exchange hides behind a whole block of work.
 
-    The three split schedules apply where a seam exists (two stripes
-    or more); named explicitly they run on one stripe too, against
-    zero halos.  All produce BIT-IDENTICAL results on the XLA path — the
-    invariance tests pin it — so this is purely a performance choice;
-    ``measure_seam_latency`` (fwi/calibrate.py) audits it."""
-    if n_stripes == 1:
-        return "single"
-    return "pipeline" if (backend or jax.default_backend()) == "tpu" \
-        else "fused"
-
-
-def _as_schedule(overlap, n_stripes: int) -> str:
-    """Normalize the legacy bool knob: True -> "overlap", False ->
-    "fused"; strings pass through; None -> ``pick_schedule``."""
-    if overlap is None:
-        return pick_schedule(n_stripes)
-    if isinstance(overlap, str):
-        if overlap not in ("fused", "overlap", "pipeline"):
-            raise ValueError(f"unknown halo schedule: {overlap!r}")
-        return overlap
-    return "overlap" if overlap else "fused"
+    Both are BIT-IDENTICAL to the reference on the XLA path — the tests
+    pin it — so the choice is only where the seams are;
+    ``measure_seam_latency`` (fwi/calibrate.py) audits what the
+    pipeline hides."""
+    return "single" if n_stripes == 1 else "pipeline"
 
 
 def _exchange_halo(edges_r: jnp.ndarray, edges_l: jnp.ndarray,
@@ -317,47 +266,32 @@ def crop(cfg: FWIConfig, x):
 
 @functools.lru_cache(maxsize=32)
 def _sharded_block_parts(cfg: FWIConfig, mesh: Mesh, k: int,
-                         use_pallas: bool, bz: int | None = None,
-                         schedule: str = "overlap"):
+                         use_pallas: bool, bz: int | None = None):
     """(sms, v2e_all, spe_all, place, geom): the UNJITTED shard_map'd
     k-step fused block bodies plus their closure fields, on the padded
     grid of ``geom`` (``stripe_geometry``) — callers jit at
     their own boundary (wrapping the body in its own jit inside a
     lax.scan defeats XLA's loop fusion; see solver.py).  ``sms`` is a
-    dict: ``{"block"}`` for the "single"/"fused"/"overlap" schedules,
-    ``{"prologue", "pipeline"}`` for "pipeline".
+    dict: ``{"block"}`` for the "single" schedule, ``{"prologue",
+    "pipeline"}`` for "pipeline" (``pick_schedule``).
 
-    schedule="single" (one stripe only) is the interior window over the
-    whole stripe and nothing else: no exchange, no boundary windows, no
-    stitch; the model fields are built at the stripe's own width, so
-    the kernel reads them whole.
+    "single" (one stripe) is the interior window over the whole stripe
+    and nothing else: no exchange, no boundary windows, no stitch; the
+    model fields are built at the stripe's own width, so the kernel
+    reads them whole.
 
-    schedule="overlap" realizes the within-block comm/compute-overlap
-    schedule (DESIGN.md §13): packed halo ppermute issued first; the
-    stripe INTERIOR advanced k fused steps (independent of the
-    exchange, overlappable with it); the two 3·k·HALO boundary windows
-    — batched into ONE ``wave_block`` call — consume the received halos
-    and patch the k·HALO seam-adjacent column strips.
-    schedule="fused" is the comm-AVOIDING schedule only: one fused
-    window over the whole extended stripe, exchange on the critical
-    path (less redundant compute — 2·k·HALO vs 6·k·HALO extra columns —
-    for hosts whose collectives are synchronous anyway).
-    schedule="pipeline" double-buffers the exchange ACROSS scan blocks
-    (DESIGN.md §15): the halos arrive in the scan CARRY, the boundary
-    windows run first (their valid columns are the stripe's fresh
-    edges), block b+1's packed ppermute is issued from those fresh
-    edges BEFORE block b's interior compute and seam stitch, and the
-    interior fusion plus stitch fly under it.
+    "pipeline" double-buffers the exchange ACROSS scan blocks
+    (DESIGN.md §15): the halos arrive in the scan CARRY, the two
+    3·k·HALO boundary windows — batched into ONE ``wave_block`` call —
+    run first (their valid columns are the stripe's fresh edges), block
+    b+1's packed ppermute is issued from those fresh edges BEFORE block
+    b's interior compute and seam stitch, and the interior fusion plus
+    stitch fly under it.
 
-    On the XLA path the single, overlap and pipeline schedules are
-    pinned bitwise-identical to the reference (the pipeline computes the
-    same per-block graph as overlap — only the exchange's position in
-    the schedule moves); the fused schedule computes the identical
-    op sequence but its different fusion shapes may flush denormal
-    wavefront tails differently — equal up to sub-normal (< 1.2e-38)
-    noise.
+    On the XLA path both are pinned bitwise-identical to the reference.
     """
     n = mesh.shape["stripe"]
+    schedule = pick_schedule(n)
     geom = stripe_geometry(cfg, n, k, use_pallas, bz)
     nxl, k = geom.lanes, geom.k
     pad = k * HALO
@@ -408,7 +342,7 @@ def _sharded_block_parts(cfg: FWIConfig, mesh: Mesh, k: int,
         # inject into EVERY window covering their column, so redundant
         # zones track true neighbor physics; each window's valid region
         # is stitched disjointly below.  The whole shot batch advances
-        # in ONE shot-batched wave_block (3-D dispatch, DESIGN.md §17)
+        # in ONE shot-batched wave_block (DESIGN.md §17)
         # with per-shot (S, k) amplitudes masked by window coverage —
         # bitwise-equal to the old vmap-of-per-shot form on the XLA
         # path (wave_block_shots_ref's pinned contract).
@@ -463,38 +397,6 @@ def _sharded_block_parts(cfg: FWIConfig, mesh: Mesh, k: int,
             [bnd[0][tuple(sl)], mid[tuple(mi)], bnd[1][tuple(sl)]],
             axis=axis,
         )
-
-    def local_block(p, p_prev, v2e, spe, t0):
-        # p (S, NZ, NXl) local stripe; v2e/spe (1, NZ, NXl + 2·pad)
-        v2e, spe = v2e[0], spe[0]
-        x0 = jax.lax.axis_index("stripe") * nxl   # global x of column 0
-        srcv = make_srcv(t0)
-
-        # 1) packed halo exchange, issued FIRST
-        lh_p, rh_p, lh_pp, rh_pp = exchange_edges(
-            p[..., -pad:], p[..., :pad],
-            p_prev[..., -pad:], p_prev[..., :pad],
-        )
-
-        if schedule == "fused":
-            # comm-avoiding only: ONE window over the extended stripe
-            # [-pad, nxl+pad); its zero-extension creep exactly eats
-            # the halos, leaving [0, nxl) valid after k steps
-            pe, ppe, tre = window(
-                jnp.concatenate([lh_p, p, rh_p], axis=-1),
-                jnp.concatenate([lh_pp, p_prev, rh_pp], axis=-1),
-                v2e, spe, -pad, x0, srcv,
-            )
-            sl = (Ellipsis, slice(pad, pad + nxl))
-            return pe[sl], ppe[sl], tre[sl]
-
-        # 2) INTERIOR (no halo dependency) while the exchange flies;
-        # 3) boundary windows consume the received halos; 4) stitch
-        pi, ppi, tri = interior(p, p_prev, v2e, spe, x0, srcv)
-        pb, ppb, trb = boundary(
-            p, p_prev, lh_p, rh_p, lh_pp, rh_pp, v2e, spe, x0, srcv
-        )
-        return stitch(pb, pi), stitch(ppb, ppi), stitch(trb, tri)
 
     def local_single_block(p, p_prev, v2e, spe, t0):
         # one stripe: both window edges are physical, so the window's
@@ -556,8 +458,7 @@ def _sharded_block_parts(cfg: FWIConfig, mesh: Mesh, k: int,
         )
     else:
         sms["block"] = jax.shard_map(
-            local_single_block if schedule == "single" else local_block,
-            mesh=mesh,
+            local_single_block, mesh=mesh,
             in_specs=(field, field, parts, parts, P()),
             out_specs=(field, field, field), check_vma=False,
         )
@@ -584,92 +485,30 @@ def _sharded_block_parts(cfg: FWIConfig, mesh: Mesh, k: int,
 
 @resolves_use_pallas
 @functools.lru_cache(maxsize=32)
-def make_sharded_multistep(cfg: FWIConfig, mesh: Mesh, *, k: int = 1,
-                           use_pallas: bool | None = None,
-                           bz: int | None = None,
-                           overlap: bool | str | None = None):
-    """Temporally-blocked, comm/compute-overlapped sharded propagator.
-
-    Returns (block_step, place): ``block_step(p, p_prev, t0)`` advances
-    ALL k timesteps with a single packed halo exchange and returns
-    (p, p_prev, traces) with traces (S, k, NX).  Fields are (S, NZ, NX)
-    sharded on x over "stripe", on the padded grid ``place`` makes of
-    logical fields (``crop`` takes them back).  ``overlap`` takes the
-    legacy bool (True="overlap", False="fused") or a schedule name; ``None``
-    auto-selects from the stripe count and the backend (``pick_schedule``:
-    "single" on one stripe).  The cross-block
-    "pipeline" schedule needs a scan to carry halos through, so the
-    single-block API maps it to its within-block form, "overlap".
-
-    The requested k may be clamped so the overlap fits in one stripe
-    (``effective_block``); callers advancing t0 must use the EFFECTIVE
-    block size, exposed as ``block_step.k``.
-    """
-    schedule = _as_schedule(overlap, mesh.shape["stripe"])
-    if schedule == "pipeline":
-        schedule = "overlap"
-    sms, v2e_all, spe_all, place, geom = _sharded_block_parts(
-        cfg, mesh, k, use_pallas, bz, schedule
-    )
-    sm, k = sms["block"], geom.k
-
-    @jax.jit
-    def jit_block(p, p_prev, t0):
-        pn, pd, tr = sm(p, p_prev, v2e_all, spe_all, t0)
-        return pn, pd, _crop_traces(cfg, tr)
-
-    def block_step(p, p_prev, t0):
-        return jit_block(p, p_prev, t0)
-
-    block_step.k = k
-    return block_step, place
-
-
-@resolves_use_pallas
-@functools.lru_cache(maxsize=32)
-def make_sharded_step(cfg: FWIConfig, mesh: Mesh, *,
-                      use_pallas: bool | None = None):
-    """Single-timestep sharded propagator (k=1 temporal block) — the
-    seed-compatible interface: step(p, p_prev, t) -> (p, p_prev, trace)
-    with trace (S, NX)."""
-    block_step, place = make_sharded_multistep(
-        cfg, mesh, k=1, use_pallas=use_pallas
-    )
-
-    @jax.jit
-    def step(p, p_prev, t):
-        pn, pp, tr = block_step(p, p_prev, t)
-        return pn, pp, tr[:, 0]
-
-    return step, place
-
-
-@resolves_use_pallas
-@functools.lru_cache(maxsize=32)
 def make_sharded_scan_runner(cfg: FWIConfig, mesh: Mesh, *, k: int = 4,
                              use_pallas: bool | None = None,
-                             bz: int | None = None,
-                             overlap: bool | str | None = None):
-    """Scan-fused, overlapped, temporally-blocked runner:
+                             bz: int | None = None):
+    """Scan-fused, temporally-blocked runner:
     run(p, p_prev, t0, blocks) advances blocks·k timesteps in ONE
-    dispatch (a lax.scan over k-step fused blocks, one packed halo
-    exchange per block).  ``overlap`` takes the legacy bool or a
-    schedule name ("fused"/"overlap"/"pipeline"); ``None`` auto-selects
-    (``pick_schedule`` — "single" on one stripe, with no exchange at
-    all; on more, "pipeline" where collectives are async).  The resolved
-    name is ``place.schedule``.  Under "pipeline" the halos ride in the
-    scan CARRY: a prologue exchange primes block 0, each block issues
-    block b+1's ppermute before its own interior compute and stitch,
-    and the last block's exchange is discarded (one wasted epilogue
-    message — the price of keeping every other exchange a full block
-    ahead).
-    Returns (p, p_prev, traces (S, blocks·k, NX)): the fields on the
-    padded grid ``place`` makes of logical ones (``stripe_geometry``;
-    ``crop`` takes them back), the traces on the logical one."""
-    schedule = _as_schedule(overlap, mesh.shape["stripe"])
+    dispatch (a lax.scan over k-step fused blocks).  The halo schedule
+    follows the stripe count (``pick_schedule``): "single" on one
+    stripe, with no exchange at all; "pipeline" on more, one packed
+    halo exchange per block.  The resolved name is ``place.schedule``.
+    Under "pipeline" the halos ride in the scan CARRY: a prologue
+    exchange primes block 0, each block issues block b+1's ppermute
+    before its own interior compute and stitch, and the last block's
+    exchange is discarded (one wasted epilogue message — the price of
+    keeping every other exchange a full block ahead).
+    The requested k may be clamped so the boundary windows fit in one
+    stripe (``effective_block``); the EFFECTIVE k is returned.
+    Returns (run, place, k); run returns (p, p_prev, traces (S,
+    blocks·k, NX)): the fields on the padded grid ``place`` makes of
+    logical ones (``stripe_geometry``; ``crop`` takes them back), the
+    traces on the logical one."""
     sms, v2e_all, spe_all, place, geom = _sharded_block_parts(
-        cfg, mesh, k, use_pallas, bz, schedule
+        cfg, mesh, k, use_pallas, bz
     )
+    schedule = place.schedule
     k = geom.k
 
     if schedule == "pipeline":
@@ -744,10 +583,10 @@ def halo_exchange_plan(cfg: FWIConfig, n_stripes: int, k: int = 1) -> dict:
     windows pay (4·k·HALO of 2·k·HALO patched columns) relative to the
     stripe width.
 
-    It describes the seams of ``n_stripes`` ≥ 2 stripes.  One stripe has
-    none: its default "single" schedule sends no message and runs no
-    boundary window, whatever the plan's figures for ``n_stripes`` = 1
-    say."""
+    It describes the "pipeline" schedule's seams on ``n_stripes`` ≥ 2
+    stripes.  One stripe has none: its "single" schedule sends no
+    message and runs no boundary window, whatever the plan's figures
+    for ``n_stripes`` = 1 say."""
     k = effective_block(cfg, n_stripes, k)
     pad = k * HALO
     nxl = -(-cfg.nx // n_stripes)

@@ -43,17 +43,11 @@ from repro.core.orchestrator import Resources, Session, elastic_chips
 from repro.core.spans import span
 from repro.fwi.domain import (
     crop,
-    effective_block,
     make_sharded_scan_runner,
     stripe_mesh,
 )
 from repro.fwi.solver import FWIConfig, ShotState
-from repro.kernels.stencil.ops import (
-    autotune_bz_k,
-    pick_bz_block,
-    pick_k,
-    resolve_use_pallas,
-)
+from repro.kernels.stencil.ops import pick_k, resolve_use_pallas
 
 
 @dataclasses.dataclass
@@ -96,7 +90,6 @@ class FWISession(Session):
         exchange_interval: int | None = 4,
         scan_block: int = 8,
         use_pallas: bool | None = None,
-        autotune: bool = False,
     ):
         self.cfg = cfg
         self.res = res
@@ -107,25 +100,10 @@ class FWISession(Session):
         with span("fwi.remesh", session=self.session, stripes=n) as remesh:
             self.mesh = stripe_mesh(n)
             use_pallas = resolve_use_pallas(use_pallas)
-            bz = None
-            if autotune and use_pallas:
-                # joint (strip height, block length) tuned at the
-                # PER-STRIPE width the engine actually runs (not the
-                # global NX); memoized per (shape, backend) so a RESHARD
-                # rebuild does not re-time.  If the stripe clamp shrinks
-                # the tuned k, re-derive bz for the clamped k instead of
-                # keeping the strip that won jointly with the larger one.
-                bz, exchange_interval = autotune_bz_k(cfg.nz,
-                                                      -(-cfg.nx // n))
-                keff = effective_block(cfg, n, exchange_interval)
-                if keff != exchange_interval:
-                    exchange_interval = keff
-                    bz = pick_bz_block(cfg.nz, keff)
-            elif exchange_interval is None:
+            if exchange_interval is None:
                 exchange_interval = pick_k(cfg.nz)
             self.runner, place, self.k = make_sharded_scan_runner(
                 cfg, self.mesh, k=exchange_interval, use_pallas=use_pallas,
-                bz=bz,
             )
             # the runner's grid, its interior kernel's tiling and the
             # halo schedule it resolved for this stripe count
@@ -353,8 +331,7 @@ def fwi_session_factory(cfg: FWIConfig, time_model: TimeModel,
                         *, seed: int = 0, stripes_for=None,
                         exchange_interval: int | None = 4,
                         scan_block: int = 8,
-                        use_pallas: bool | None = None,
-                        autotune: bool = False):
+                        use_pallas: bool | None = None):
     rng = np.random.default_rng(seed)
 
     def factory(res: Resources, start_step: int, restored) -> FWISession:
@@ -363,7 +340,7 @@ def fwi_session_factory(cfg: FWIConfig, time_model: TimeModel,
             cfg, res, start_step, restored,
             time_model=time_model, rng=rng, n_stripes=n,
             exchange_interval=exchange_interval, scan_block=scan_block,
-            use_pallas=use_pallas, autotune=autotune,
+            use_pallas=use_pallas,
         )
 
     return factory

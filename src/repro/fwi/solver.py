@@ -6,32 +6,25 @@ receiver traces sampled at the surface.  Multiple shots are independent
 (task-parallel) over the same velocity model (data-parallel) — exactly
 the structure the paper exploits to split work between environments.
 
-Propagation engine layout (the overlap-and-fuse hot loop):
+Propagation engine layout:
 
-* ``make_step_fn``       — one jitted timestep (kept for interactive /
-                           single-step use and as the equivalence oracle).
-* ``make_scan_runner``   — the PR 1 engine: jit-once ``lax.scan`` over
-                           timesteps with the UNJITTED step body inlined
-                           (a nested jit inside a scan body defeats
-                           XLA's loop fusion and costs ~3× on CPU),
-                           receiver traces collected as scan outputs,
-                           and the body unrolled (default 8×).  Kept as
-                           the bench baseline and equivalence oracle.
-* ``make_block_runner``  — the fused engine: ``lax.scan`` over k-step
-                           fused blocks (``kernels.stencil.ops
-                           .wave_block``), each block one fused region —
-                           source injection, sponge damping and receiver
-                           capture in the step epilogue, the damped
-                           previous field folded into the next leapfrog
-                           expression instead of materialized per step,
-                           and (XLA path) the field held padded across
-                           inner steps.  Bit-identical to the scan
-                           runner; this is what ``run_forward`` uses
-                           (DESIGN.md §13).
+* ``make_block_runner``  — ``lax.scan`` over k-step fused blocks
+                           (``kernels.stencil.ops.wave_block``), each
+                           block one fused region — source injection,
+                           sponge damping and receiver capture in the
+                           step epilogue, the damped previous field
+                           folded into the next leapfrog expression
+                           instead of materialized per step, and (XLA
+                           path) the field held padded across inner
+                           steps.  Bit-identical to k sequential
+                           reference steps; this is what
+                           ``run_forward`` uses (DESIGN.md §13).
+* ``make_shot_parallel_runner`` — the same body with the shot axis
+                           sharded over devices.
 * model-building (``velocity_model``/``sponge_taper``/``ricker``) and
   all runner factories are memoized on the (frozen, hashable)
   ``FWIConfig`` plus their full engine knobs — ``make_block_runner``
-  keys on ``(cfg, k, bz, use_pallas, collect_traces)`` so autotuned
+  keys on ``(cfg, k, bz, use_pallas, collect_traces, ...)`` so
   variants never collide in the cache, and a RESHARD-triggered session
   rebuild re-uses the cached arrays and compiled runners instead of
   recomputing and re-tracing.
@@ -49,7 +42,6 @@ from repro.kernels.stencil.ops import (
     pick_k,
     resolves_use_pallas,
     wave_block,
-    wave_step,
 )
 from repro.launch.mesh import make_mesh
 
@@ -132,75 +124,6 @@ class ShotState:
         )
 
 
-@functools.lru_cache(maxsize=32)
-def _raw_step_fn(cfg: FWIConfig, use_pallas: bool):
-    """Unjitted step(p, p_prev, t) -> (p_next, p_damped, trace) advancing
-    all shots one timestep — inlined into the scan body by the runner."""
-    v = velocity_model(cfg)
-    v2dt2 = (v * cfg.dt / cfg.dx) ** 2
-    sponge = sponge_taper(cfg)
-    wavelet = ricker(cfg)
-    pos = cfg.shot_positions()
-    src_z = jnp.asarray(pos[:, 0])
-    src_x = jnp.asarray(pos[:, 1])
-
-    def one_shot(p, p_prev, t, zi, xi):
-        p_next, p_damped = wave_step(
-            p, p_prev, v2dt2, sponge, use_pallas=use_pallas
-        )
-        src = wavelet[jnp.clip(t, 0, cfg.timesteps - 1)] * (cfg.dt ** 2)
-        p_next = p_next.at[zi, xi].add(src)
-        return p_next, p_damped
-
-    def step(p, p_prev, t):
-        p_next, p_damped = jax.vmap(
-            one_shot, in_axes=(0, 0, None, 0, 0)
-        )(p, p_prev, t, src_z, src_x)
-        trace = p_next[:, cfg.receiver_depth, :]     # (S, NX) receivers
-        return p_next, p_damped, trace
-
-    return step
-
-
-@functools.lru_cache(maxsize=32)
-def make_step_fn(cfg: FWIConfig, *, use_pallas: bool = False):
-    """Returns jitted step(state_fields, t) advancing one timestep."""
-    return jax.jit(_raw_step_fn(cfg, use_pallas))
-
-
-@functools.lru_cache(maxsize=32)
-def make_scan_runner(cfg: FWIConfig, *, use_pallas: bool = False,
-                     collect_traces: bool = False, unroll: int = 8):
-    """jit-once multi-step propagator (lax.scan over timesteps).
-
-    run(p, p_prev, t0, steps) -> (p, p_prev)                 [default]
-                             -> (p, p_prev, traces (S,T,NX)) [collect]
-
-    ``t0`` is traced, ``steps`` static — restarting at a different
-    offset does not retrace.  The factory is memoized, so RESHARD /
-    restart paths reuse the compiled runner.
-    """
-    step = _raw_step_fn(cfg, use_pallas)
-
-    @functools.partial(jax.jit, static_argnames=("steps",))
-    def run(p, p_prev, t0, steps: int):
-        def body(carry, i):
-            p, pp = carry
-            pn, pd, tr = step(p, pp, t0 + i)
-            return (pn, pd), (tr if collect_traces else None)
-
-        (p, pp), traces = jax.lax.scan(
-            body, (p, p_prev), jnp.arange(steps),
-            unroll=min(unroll, max(steps, 1)),
-        )
-        if collect_traces:
-            # scan stacks on axis 0 (time); traces as (S, T, NX)
-            return p, pp, jnp.swapaxes(traces, 0, 1)
-        return p, pp
-
-    return run
-
-
 def _block_scan_body(cfg: FWIConfig, k: int, use_pallas: bool,
                      bz: int | None, collect_traces: bool,
                      stream: bool | None = None,
@@ -213,7 +136,7 @@ def _block_scan_body(cfg: FWIConfig, k: int, use_pallas: bool,
     shot-sharded caller can pass its local shard's sources.
 
     The whole shot batch advances through ONE shot-batched
-    ``wave_block`` call per block (the 3-D dispatch, DESIGN.md §17) —
+    ``wave_block`` call per block (DESIGN.md §17) —
     not a ``vmap`` over per-shot kernels — so the shared model fields
     are read once per strip for all local shots and the batch costs one
     kernel launch per block.  Bit-identical to the old vmapped body on
@@ -281,14 +204,13 @@ def make_block_runner(cfg: FWIConfig, *, k: int | None = None,
     run(p, p_prev, t0, steps) -> (p, p_prev, traces (S, steps, NX))
 
     ``t0`` is traced, ``steps`` static; a non-multiple-of-k step count
-    runs a tail block of the remainder length.  Bit-identical to
-    ``make_scan_runner`` on the XLA path (the block body is a pure
-    re-scheduling of the same ops — and the auto-selected STREAMED
-    tiling for production grids keeps that contract via
-    ``wave_block_strips_ref``, see DESIGN.md §15).  Memoized on the
-    FULL knob set (cfg, k, bz, use_pallas, collect_traces, stream,
-    vmem_budget, shot_tile) so autotuned variants don't collide in the
-    cache."""
+    runs a tail block of the remainder length.  Bit-identical to k
+    sequential ``wave_step_ref`` steps on the XLA path (the block body
+    is a pure re-scheduling of the same ops — and the auto-selected
+    STREAMED tiling for production grids keeps that contract via
+    ``wave_block_shots_strips_ref``, see DESIGN.md §15).  Memoized on
+    the FULL knob set (cfg, k, bz, use_pallas, collect_traces, stream,
+    vmem_budget, shot_tile) so variants don't collide in the cache."""
     if k is None:
         k = pick_k(cfg.nz)
     pos = cfg.shot_positions()

@@ -1,49 +1,44 @@
-"""Pallas TPU kernel: fused 4th-order wave-equation timestep.
+"""Pallas TPU kernels: k fused 4th-order wave-equation timesteps for a
+batch of shots.
 
 TPU adaptation of the paper's (CPU/MPI, Eigen-based) FWI hot loop —
-re-blocked for the TPU memory hierarchy instead of ported:
+re-blocked for the TPU memory hierarchy instead of ported.  One kernel
+family, two designs, both shot-batched — (S, NZ, NX) wavefields over
+shared (NZ, NX) model fields; one shot is the batch S=1:
 
-* Row-strip tiling: each grid step owns a (BZ, NX) strip resident in
-  VMEM.  The pressure field is passed ONCE with a whole-array BlockSpec
-  whose index map is constant — the pipeline fetches it a single time
-  and every grid step slices its strip plus the ±HALO neighbor rows out
-  of the resident copy.  (The seed version passed `p` through THREE
-  aliased BlockSpecs — center/up/down neighbor views — which costs 3×
-  the HBM reads of the field per step; for a memory-bound stencil that
-  was most of the budget.)  x-halo needs no exchange because strips span
-  the full width, matching the paper's striped second-level partitioning
-  that minimizes communication.
-* One fused pass: Laplacian + leapfrog update + sponge damping for BOTH
-  outputs (p_next, p_damped) — the fields are read once from HBM per
-  step, which is the whole battle for a memory-bound stencil.
-* f32 compute; (8,128)-aligned strips (BZ multiple of 8, NX multiple of
-  128) keep loads/stores VPU-lane aligned.
-* `interpret` auto-selects from the backend: compiled on TPU, interpret
-  mode elsewhere (the kernel body runs with real Pallas semantics on
-  CPU, validating the BlockSpec/halo logic).  `autotune_bz` sweeps strip
-  heights and memoizes the fastest — the block-shape knob the ROADMAP's
-  "fast as the hardware allows" goal turns.
+* ``wave_block_shots_pallas`` — RESIDENT: each grid step owns an
+  (S, bz, NX) strip and computes on its (S, bz + 2·k·HALO, NX) haloed
+  window, sliced out of whole-array VMEM copies the pipeline fetches
+  once (constant index maps).  Strips span the full width, so x needs
+  no halo — the paper's striped partitioning.  The whole field sits in
+  VMEM, which caps the design at ~1k²-class grids.
+* ``wave_block_shots_stream_pallas`` — STREAMED (DESIGN.md §15): the
+  fields stay in HBM and each grid step double-buffer-DMAs its haloed
+  window while the previous strip computes, walking the shot tiles in
+  its own grid — ``stream_vmem_bytes`` is O(tile·bz·NX), independent
+  of NZ and of S.
 
-Physical-boundary strips (first/last) zero their out-of-domain halo
-rows, reproducing ref.py's zero-halo convention exactly.
+Both run one trapezoid body (``_trapezoid_k_steps_shots``): per inner
+step Laplacian + leapfrog + sponge damping, source injection and
+receiver capture fused in the epilogue, so k steps cost one launch and
+one wavefield HBM round trip (DESIGN.md §13).  Window edges zero-extend
+every step: at a physical edge that is ref.py's zero-halo convention,
+at an interior one the wrong values stay outside the owned strip.  The
+model-field windows are read once per strip for every shot of the tile
+(DESIGN.md §17).  f32 compute; (8,128)-aligned strips keep loads and
+stores lane aligned (``_check_compiled_geometry``).
 
-Capacity: the constant-map whole-array spec keeps the full field in
-VMEM (NZ·NX·4 B — 1.4 MB for the paper's 600² grid, comfortably under
-the ~16 MB/core budget), which hard-caps the resident design at
-~1k²-class grids.  Production surveys (≥ 4096² — DESIGN.md §15) run the
-STREAMED kernel instead: ``wave_block_stream_pallas`` holds only a
-double-buffered pair of (bz + 2·k·HALO, NX) haloed windows in VMEM and
-DMAs strip i+1 in from HBM while strip i computes its k-step trapezoid
-— ``stream_vmem_bytes`` is O(bz·NX), independent of NZ, so the grid
-height is unbounded by VMEM.  ``pick_bz_stream`` sizes the strip under
-an explicit budget and ``should_stream`` auto-selects the design per
-(shape, budget); the XLA-path mirror of the same tiling is
-``ref.py::wave_block_strips_ref`` (bit-exactness oracle).
+``interpret`` auto-selects from the backend: compiled on TPU, interpret
+mode elsewhere (the kernel body runs with real Pallas semantics on CPU).
+``should_stream`` picks the design per (shape, budget); ``pick_bz_block``
+/ ``pick_bz_stream``, ``pick_k`` and ``pick_shot_tile`` size the tiling
+in closed form from the VMEM budget.  The XLA-path mirrors of the same
+tilings are ``ref.py::wave_block_shots_ref`` and
+``wave_block_shots_strips_ref`` (bit-exactness oracles).
 """
 from __future__ import annotations
 
 import functools
-import time
 import warnings
 
 import jax
@@ -101,112 +96,22 @@ def _warn_whole_strip(nz: int, cap: int, who: str) -> int:
         f"falling back to a SINGLE whole-height strip ({nz} rows "
         f"VMEM-resident). Fine for small grids; for tall grids pad nz "
         f"to a composite height or use the streamed kernel "
-        f"(wave_block_stream_pallas), which refuses this fallback.",
+        f"(wave_block_shots_stream_pallas), which refuses this fallback.",
         StripFallbackWarning,
         stacklevel=3,
     )
     return nz
 
 
-def pick_bz(nz: int, cap: int = 128) -> int:
-    """Largest divisor of nz ≤ cap, preferring (8,128)-aligned strips.
-
-    Never returns a strip shorter than HALO — the kernel's clamped
-    neighbor-row slices assume bz ≥ HALO, so a 1-row strip (e.g. prime
-    nz > cap) would silently corrupt the stencil; such grids fall back
-    to a single whole-height strip (with a ``StripFallbackWarning`` when
-    that strip is taller than the cap — the whole field goes resident)."""
-    aligned = [b for b in range(8, cap + 1, 8) if nz % b == 0]
-    if aligned:
-        return max(aligned)
-    ok = [b for b in range(HALO, cap + 1) if nz % b == 0]
-    if ok:
-        return max(ok)
-    return _warn_whole_strip(nz, cap, "pick_bz") if nz > cap else nz
-
-
 def _shift_x(a, d: int, nx: int):
-    """x-shift with zero boundary fill (shared by all stencil kernels).
-
-    Operates on the LAST axis so the same helper serves the (win, NX)
-    single-shot windows and the (S, win, NX) shot-batched ones."""
+    """x-shift with zero boundary fill along the LAST axis (the (S, win,
+    NX) windows of both kernels)."""
     ax = a.ndim - 1
     rolled = jnp.roll(a, d, axis=ax)
     idx = jax.lax.broadcasted_iota(jnp.int32, a.shape, ax)
     if d > 0:
         return jnp.where(idx >= d, rolled, 0.0)
     return jnp.where(idx < nx + d, rolled, 0.0)
-
-
-def _wave_kernel(
-    p_ref, p_prev_ref, v2dt2_ref, sponge_ref, p_next_ref, p_damped_ref,
-    *, bz: int,
-):
-    i = pl.program_id(0)
-    n = pl.num_programs(0)
-    nz = p_ref.shape[0]
-    nx = p_ref.shape[1]
-    row0 = i * bz
-
-    # one resident copy of p serves center AND both halo views
-    center = p_ref[pl.ds(pl.multiple_of(row0, bz), bz), :]
-    up = p_ref[pl.ds(jnp.maximum(row0 - HALO, 0), HALO), :]
-    dn = p_ref[pl.ds(jnp.minimum(row0 + bz, nz - HALO), HALO), :]
-    zero_h = jnp.zeros((HALO, nx), center.dtype)
-    up = jnp.where(i == 0, zero_h, up)                 # physical boundary
-    dn = jnp.where(i == n - 1, zero_h, dn)
-
-    ext = jnp.concatenate([up, center, dn], axis=0)    # (bz+4, nx)
-
-    # z-direction stencil from the extended strip
-    lap = 2.0 * C0 * center
-    lap += C1 * (ext[HALO - 1: HALO - 1 + bz, :]
-                 + ext[HALO + 1: HALO + 1 + bz, :])
-    lap += C2 * (ext[HALO - 2: HALO - 2 + bz, :]
-                 + ext[HALO + 2: HALO + 2 + bz, :])
-
-    # x-direction stencil with zero boundary fill (full width in-strip)
-    lap += C1 * (_shift_x(center, 1, nx) + _shift_x(center, -1, nx))
-    lap += C2 * (_shift_x(center, 2, nx) + _shift_x(center, -2, nx))
-
-    sponge = sponge_ref[...]
-    p_next = (2.0 * center - p_prev_ref[...] + v2dt2_ref[...] * lap) * sponge
-    p_next_ref[...] = p_next
-    p_damped_ref[...] = center * sponge
-
-
-@functools.partial(jax.jit, static_argnames=("bz", "interpret"))
-def wave_step_pallas(
-    p: jax.Array,          # (NZ, NX) f32
-    p_prev: jax.Array,
-    v2dt2: jax.Array,
-    sponge: jax.Array,
-    *,
-    bz: int | None = None,
-    interpret: bool | None = None,
-):
-    nz, nx = p.shape
-    if bz is None:
-        bz = pick_bz(nz)
-    if interpret is None:
-        interpret = default_interpret()
-    assert nz % bz == 0, (nz, bz)
-    assert bz >= HALO, (bz, HALO)   # clamped halo slices need bz >= HALO
-    grid = (nz // bz,)
-    whole = pl.BlockSpec((nz, nx), lambda i: (0, 0))   # fetched once
-    strip = pl.BlockSpec((bz, nx), lambda i: (i, 0))
-    out_shape = [
-        jax.ShapeDtypeStruct((nz, nx), p.dtype),
-        jax.ShapeDtypeStruct((nz, nx), p.dtype),
-    ]
-    return pl.pallas_call(
-        functools.partial(_wave_kernel, bz=bz),
-        grid=grid,
-        in_specs=[whole, strip, strip, strip],
-        out_specs=[strip, strip],
-        out_shape=out_shape,
-        interpret=interpret,
-    )(p, p_prev, v2dt2, sponge)
 
 
 def pick_bz_block(nz: int, k: int, cap: int = 128) -> int:
@@ -245,8 +150,7 @@ def resident_vmem_bytes(nz: int, nx: int, k: int = 1,
     fields fetched once, the pipeline's double-buffered output strips
     (per shot) and the trace block, every row lane-padded (``_lanes``).
     ``s`` is the shot-batch size — the model-field term is charged ONCE
-    regardless of ``s`` (DESIGN.md §17); ``s=1`` reduces to the classic
-    single-shot accounting."""
+    regardless of ``s`` (DESIGN.md §17)."""
     bz = min(bz if bz is not None else 128, nz)
     nx = _lanes(nx)
     return 4 * ((2 * s + 2) * nz * nx + 2 * 2 * s * bz * nx + s * k * nx)
@@ -317,296 +221,6 @@ def pick_k(nz: int, cap: int = 8) -> int:
     while k > 1 and pick_bz_block(nz, k) == nz and nz > 2 * k * HALO:
         k //= 2
     return max(k, 1)
-
-
-def _trapezoid_k_steps(
-    cur, prevd, vw, sw, srcv_ref, srcp_ref, tr_ref,
-    *, start, row0, win: int, nx: int, bz: int, k: int, rrow: int,
-):
-    """k fused leapfrog steps on one (win, NX) haloed window.
-
-    The shared trapezoid body of BOTH block kernels (resident and
-    streamed): per inner step, zero-extend in z, 4th-order Laplacian
-    (z-rings from the extension, x-rings via ``_shift_x``), leapfrog +
-    sponge, iota-masked source injection, and receiver-row capture into
-    ``tr_ref`` for the program owning the receiver strip.  Returns the
-    updated (cur, prevd) window pair."""
-    zi = srcp_ref[0, 0]
-    xi = srcp_ref[0, 1]
-    iz = jax.lax.broadcasted_iota(jnp.int32, (win, nx), 0)
-    ix = jax.lax.broadcasted_iota(jnp.int32, (win, nx), 1)
-    zero_h = jnp.zeros((HALO, nx), cur.dtype)
-    own_receiver = (rrow >= row0) & (rrow < row0 + bz)
-
-    for j in range(k):
-        ext = jnp.concatenate([zero_h, cur, zero_h], axis=0)
-        lap = 2.0 * C0 * cur
-        lap += C1 * (ext[HALO - 1: HALO - 1 + win, :]
-                     + ext[HALO + 1: HALO + 1 + win, :])
-        lap += C2 * (ext[HALO - 2: HALO - 2 + win, :]
-                     + ext[HALO + 2: HALO + 2 + win, :])
-        lap += C1 * (_shift_x(cur, 1, nx) + _shift_x(cur, -1, nx))
-        lap += C2 * (_shift_x(cur, 2, nx) + _shift_x(cur, -2, nx))
-        pn = (2.0 * cur - prevd + vw * lap) * sw
-        # epilogue: source injection + receiver-row capture, fused
-        pn = pn + jnp.where(
-            (iz == zi - start) & (ix == xi), srcv_ref[0, j], 0.0
-        )
-
-        @pl.when(own_receiver)
-        def _capture(pn=pn, j=j):
-            tr_ref[j, :] = jax.lax.dynamic_slice_in_dim(
-                pn, rrow - start, 1, axis=0
-            )[0, :]
-
-        prevd = cur * sw
-        cur = pn
-    return cur, prevd
-
-
-def _wave_block_kernel(
-    p_ref, pp_ref, v2dt2_ref, sponge_ref, srcv_ref, srcp_ref,
-    p_out_ref, pp_out_ref, tr_ref,
-    *, bz: int, win: int, k: int, rrow: int,
-):
-    """k fused timesteps on one z-strip (ghost-zone temporal blocking).
-
-    Each program owns a (bz, NX) strip but computes on a (win, NX)
-    window, ``win = bz + 2·k·HALO`` clamped to NZ, sliced out of the
-    single VMEM-resident copy of each field.  Every inner step
-    zero-extends the window in z: at a physical domain edge that IS the
-    boundary condition; at an interior window edge it seeds a wrong
-    value whose influence creeps inward HALO rows per step — after k
-    steps exactly the owned strip is clean (the window start is clamped
-    so the strip sits ≥ k·HALO rows from any interior window edge).
-    Source injection, sponge damping and the receiver-row capture run in
-    the step epilogue, so k launches and 2k wavefield HBM round-trips
-    collapse into one pallas_call (DESIGN.md §13)."""
-    i = pl.program_id(0)
-    nz = p_ref.shape[0]
-    nx = p_ref.shape[1]
-    row0 = i * bz
-    start = jnp.clip(row0 - k * HALO, 0, nz - win)
-    off = row0 - start          # strip offset inside the window
-
-    cur = p_ref[pl.ds(start, win), :]
-    prevd = pp_ref[pl.ds(start, win), :]      # already sponge-damped
-    vw = v2dt2_ref[pl.ds(start, win), :]
-    sw = sponge_ref[pl.ds(start, win), :]
-    cur, prevd = _trapezoid_k_steps(
-        cur, prevd, vw, sw, srcv_ref, srcp_ref, tr_ref,
-        start=start, row0=row0, win=win, nx=nx, bz=bz, k=k, rrow=rrow,
-    )
-
-    p_out_ref[...] = jax.lax.dynamic_slice_in_dim(cur, off, bz, axis=0)
-    pp_out_ref[...] = jax.lax.dynamic_slice_in_dim(prevd, off, bz, axis=0)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("bz", "receiver_row", "interpret")
-)
-def wave_block_pallas(
-    p: jax.Array,          # (NZ, NX) f32
-    p_prev: jax.Array,     # (NZ, NX), already sponge-damped
-    v2dt2: jax.Array,
-    sponge: jax.Array,
-    src_vals: jax.Array,   # (k,) source amplitude per inner step
-    src_z,                 # scalar int source row
-    src_x,                 # scalar int source column
-    *,
-    receiver_row: int = 0,
-    bz: int | None = None,
-    interpret: bool | None = None,
-):
-    """k fused timesteps in ONE pallas_call (k = src_vals.shape[0]).
-
-    Returns (p_k, p_prev_damped_k, traces (k, NX)).  Matches
-    ``wave_block_ref`` to stencil-reorder tolerance (the z/x accumulation
-    order differs from the reference — documented `allclose`, not
-    bitwise; the pure-XLA block path carries the bitwise contract)."""
-    nz, nx = p.shape
-    k = int(src_vals.shape[0])
-    if bz is None:
-        bz = pick_bz_block(nz, k)
-    if interpret is None:
-        interpret = default_interpret()
-    win = min(bz + 2 * k * HALO, nz)
-    assert nz % bz == 0, (nz, bz)
-    # reject oversized explicit strips: a bz < nz whose trapezoid spills
-    # past the field would make every program recompute the WHOLE field
-    # (grid-fold redundant work); only the single whole-height strip may
-    # clamp the window
-    assert bz == nz or bz + 2 * k * HALO <= nz, (nz, bz, k)
-    grid = (nz // bz,)
-    whole = pl.BlockSpec((nz, nx), lambda i: (0, 0))   # fetched once
-    strip = pl.BlockSpec((bz, nx), lambda i: (i, 0))
-    srcv = src_vals.reshape(1, k).astype(p.dtype)
-    srcp = jnp.stack(
-        [jnp.asarray(src_z, jnp.int32), jnp.asarray(src_x, jnp.int32)]
-    ).reshape(1, 2)
-    out_shape = [
-        jax.ShapeDtypeStruct((nz, nx), p.dtype),
-        jax.ShapeDtypeStruct((nz, nx), p.dtype),
-        jax.ShapeDtypeStruct((k, nx), p.dtype),
-    ]
-    return pl.pallas_call(
-        functools.partial(
-            _wave_block_kernel, bz=bz, win=win, k=k,
-            rrow=int(receiver_row),
-        ),
-        grid=grid,
-        in_specs=[whole, whole, whole, whole,
-                  pl.BlockSpec((1, k), lambda i: (0, 0)),
-                  pl.BlockSpec((1, 2), lambda i: (0, 0))],
-        out_specs=[strip, strip, pl.BlockSpec((k, nx), lambda i: (0, 0))],
-        out_shape=out_shape,
-        interpret=interpret,
-    )(p, p_prev, v2dt2, sponge, srcv, srcp)
-
-
-def _wave_block_stream_kernel(
-    p_hbm, pp_hbm, v_hbm, s_hbm, srcv_ref, srcp_ref,
-    p_out_ref, pp_out_ref, tr_ref, win_buf, sems,
-    *, bz: int, win: int, k: int, rrow: int,
-):
-    """STREAMED k-step trapezoid: manual double-buffered window DMA.
-
-    The four fields stay in HBM (``memory_space=ANY``); each grid step
-    owns a (bz, NX) strip and computes on a (win, NX) haloed window that
-    it DMAs into one of two VMEM slots.  Grid step i starts the fetch of
-    strip i+1's window into the OTHER slot before waiting on its own, so
-    the next window flies over this strip's k-step compute — the manual
-    analogue of the pipelined-BlockSpec prefetch the resident kernel
-    gets for free, without requiring the whole field to fit in VMEM
-    (DESIGN.md §15).  Trapezoid math is ``_trapezoid_k_steps``, shared
-    with the resident kernel."""
-    i = pl.program_id(0)
-    n = pl.num_programs(0)
-    nz = p_hbm.shape[0]
-    nx = p_hbm.shape[1]
-    fields = (p_hbm, pp_hbm, v_hbm, s_hbm)
-
-    def win_start(strip):
-        return jnp.clip(strip * bz - k * HALO, 0, nz - win)
-
-    def dma(slot, strip):
-        start = win_start(strip)
-        return [
-            pltpu.make_async_copy(
-                f.at[pl.ds(start, win), :],
-                win_buf.at[slot, fi],
-                sems.at[slot, fi],
-            )
-            for fi, f in enumerate(fields)
-        ]
-
-    @pl.when(i == 0)                 # warm-up: fetch our own window
-    def _warmup():
-        for c in dma(0, 0):
-            c.start()
-
-    @pl.when(i + 1 < n)              # prefetch next strip's window
-    def _prefetch():
-        for c in dma((i + 1) % 2, i + 1):
-            c.start()
-
-    slot = i % 2
-    for c in dma(slot, i):           # wait for our window to land
-        c.wait()
-
-    row0 = i * bz
-    start = win_start(i)
-    off = row0 - start               # strip offset inside the window
-    cur, prevd = _trapezoid_k_steps(
-        win_buf[slot, 0], win_buf[slot, 1],
-        win_buf[slot, 2], win_buf[slot, 3],
-        srcv_ref, srcp_ref, tr_ref,
-        start=start, row0=row0, win=win, nx=nx, bz=bz, k=k, rrow=rrow,
-    )
-    p_out_ref[...] = jax.lax.dynamic_slice_in_dim(cur, off, bz, axis=0)
-    pp_out_ref[...] = jax.lax.dynamic_slice_in_dim(prevd, off, bz, axis=0)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("receiver_row", "bz", "interpret", "vmem_budget"),
-)
-def wave_block_stream_pallas(
-    p: jax.Array,          # (NZ, NX) f32
-    p_prev: jax.Array,     # (NZ, NX), already sponge-damped
-    v2dt2: jax.Array,
-    sponge: jax.Array,
-    src_vals: jax.Array,   # (k,) source amplitude per inner step
-    src_z,                 # scalar int source row
-    src_x,                 # scalar int source column
-    *,
-    receiver_row: int = 0,
-    bz: int | None = None,
-    interpret: bool | None = None,
-    vmem_budget: int | None = None,
-):
-    """k fused timesteps, STREAMED: VMEM holds two haloed windows, not
-    the field (k = src_vals.shape[0]).
-
-    The production-scale form of ``wave_block_pallas``: fields live in
-    HBM and each grid step double-buffer-DMAs its (bz + 2·k·HALO, NX)
-    window while the previous strip computes, so capacity is O(bz·NX)
-    — a 4096² grid (256 MB resident) streams in ~8 MB of VMEM.  Strip
-    height defaults to ``pick_bz_stream`` (raises rather than fall back
-    to a whole-height resident strip).  Returns
-    (p_k, p_prev_damped_k, traces (k, NX)); same accuracy contract as
-    the resident Pallas kernel (allclose vs ``wave_block_ref``; the
-    bitwise strip-tiled oracle is ``ref.wave_block_strips_ref``)."""
-    nz, nx = p.shape
-    k = int(src_vals.shape[0])
-    if interpret is None:
-        interpret = default_interpret()
-    if bz is None:
-        bz = pick_bz_stream(nz, nx, k, vmem_budget=vmem_budget)
-    budget = vmem_budget if vmem_budget is not None else DEFAULT_VMEM_BUDGET
-    win = bz + 2 * k * HALO
-    assert nz % bz == 0, (nz, bz)
-    assert win <= nz, (nz, bz, k)    # no whole-height fallback, ever
-    assert stream_vmem_bytes(nz, nx, bz, k) <= budget, (nz, nx, bz, k)
-    grid = (nz // bz,)
-    hbm = pl.BlockSpec(memory_space=pl.ANY)
-    strip = pl.BlockSpec((bz, nx), lambda i: (i, 0))
-    srcv = src_vals.reshape(1, k).astype(p.dtype)
-    srcp = jnp.stack(
-        [jnp.asarray(src_z, jnp.int32), jnp.asarray(src_x, jnp.int32)]
-    ).reshape(1, 2)
-    out_shape = [
-        jax.ShapeDtypeStruct((nz, nx), p.dtype),
-        jax.ShapeDtypeStruct((nz, nx), p.dtype),
-        jax.ShapeDtypeStruct((k, nx), p.dtype),
-    ]
-    kwargs = {}
-    if not interpret:
-        # enforce the budget at compile time on real TPUs (twice over:
-        # the trapezoid's temporaries come on top of the buffers the
-        # budget counts); interpret mode has no VMEM, the assert above
-        # carries the contract
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            vmem_limit_bytes=2 * budget
-        )
-    return pl.pallas_call(
-        functools.partial(
-            _wave_block_stream_kernel, bz=bz, win=win, k=k,
-            rrow=int(receiver_row),
-        ),
-        grid=grid,
-        in_specs=[hbm, hbm, hbm, hbm,
-                  pl.BlockSpec((1, k), lambda i: (0, 0)),
-                  pl.BlockSpec((1, 2), lambda i: (0, 0))],
-        out_specs=[strip, strip, pl.BlockSpec((k, nx), lambda i: (0, 0))],
-        out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((2, 4, win, nx), p.dtype),
-            pltpu.SemaphoreType.DMA((2, 4)),
-        ],
-        interpret=interpret,
-        **kwargs,
-    )(p, p_prev, v2dt2, sponge, srcv, srcp)
 
 
 def _strip_starts(nz: int, bz: int, win: int, k: int) -> list[int]:
@@ -783,16 +397,18 @@ def wave_block_shots_pallas(
     bz: int | None = None,
     interpret: bool | None = None,
 ):
-    """Shot-batched ``wave_block_pallas``: k fused timesteps for ALL S
-    shots in ONE pallas_call.
+    """k fused timesteps for ALL S shots in ONE pallas_call, the fields
+    VMEM-resident (k = src_vals.shape[-1]).
 
     One grid pass covers the whole batch — the model fields are fetched
     once (not once per shot) and every strip's trapezoid is computed for
     all shots together, so kernel launches and model-field HBM traffic
-    are amortized S-fold vs ``vmap``-of-``wave_block_pallas``
-    (DESIGN.md §17).  Returns (p_k (S, NZ, NX), p_prev_damped_k,
-    traces (S, k, NX)); the S=1 batch is bitwise-equal to the 2-D
-    kernel (pinned by tests)."""
+    are amortized S-fold over one kernel per shot (DESIGN.md §17).
+    Strip height defaults to ``pick_bz_block``.  Returns
+    (p_k (S, NZ, NX), p_prev_damped_k, traces (S, k, NX)); matches
+    ``wave_block_shots_ref`` to stencil-reorder tolerance (the z/x
+    accumulation order differs — documented ``allclose``, not bitwise;
+    the XLA path carries the bitwise contract)."""
     ns, nz, nx = p.shape
     k = int(src_vals.shape[-1])
     if bz is None:
@@ -932,7 +548,7 @@ def wave_block_shots_stream_pallas(
     vmem_budget: int | None = None,
     shot_tile: int | None = None,
 ):
-    """Shot-batched ``wave_block_stream_pallas``: VMEM holds two
+    """k fused timesteps for ALL S shots, STREAMED: VMEM holds two
     (tile, win, NX) wavefield window slots plus ONE shared (win, NX)
     model-field slot pair — capacity O(tile·bz·NX), independent of NZ
     and of S.
@@ -942,9 +558,10 @@ def wave_block_shots_stream_pallas(
     its own grid, so the whole batch goes in and comes out whole, with
     no slice or join of the wavefields around the call.  Strip height
     defaults to ``pick_bz_stream(..., s=tile)`` (raises rather than
-    fall back to a whole-height resident strip — same no-fallback
-    contract as the single-shot streamed kernel).  Returns
-    (p_k, p_prev_damped_k, traces (S, k, NX))."""
+    fall back to a whole-height resident strip).  Returns
+    (p_k, p_prev_damped_k, traces (S, k, NX)), bitwise equal to the
+    resident kernel at the same strip geometry: the DMA pipeline is
+    pure data movement around the one trapezoid body."""
     ns, nz, nx = p.shape
     k = int(src_vals.shape[-1])
     ts = ns if shot_tile is None else int(shot_tile)
@@ -1037,252 +654,3 @@ def pick_shot_tile(n_shots: int, nz: int, nx: int, k: int, *,
     ok = [t for t, b in strips.items() if b is not None]
     aligned = [t for t in ok if strips[t] % 8 == 0 or strips[t] == nz]
     return max(aligned or ok, default=1)
-
-
-def _tune_backend(backend: str | None) -> str:
-    return backend if backend is not None else jax.default_backend()
-
-
-@functools.lru_cache(maxsize=None)
-def _autotune_bz_cached(
-    nz: int, nx: int, candidates: tuple[int, ...], repeats: int,
-    backend: str,
-) -> int:
-    cands = [b for b in candidates if nz % b == 0]
-    if not cands:
-        return pick_bz(nz)
-    key = jax.random.key(0)
-    p = jax.random.normal(key, (nz, nx), jnp.float32)
-    args = (p, p, jnp.full((nz, nx), 0.1, jnp.float32),
-            jnp.ones((nz, nx), jnp.float32))
-    best_bz, best_t = cands[0], float("inf")
-    for b in cands:
-        out = wave_step_pallas(*args, bz=b)       # compile
-        jax.block_until_ready(out)
-        t0 = time.perf_counter()
-        for _ in range(repeats):
-            out = wave_step_pallas(*args, bz=b)
-        jax.block_until_ready(out)
-        dt = (time.perf_counter() - t0) / repeats
-        if dt < best_t:
-            best_bz, best_t = b, dt
-    return best_bz
-
-
-def autotune_bz(
-    nz: int, nx: int, candidates: tuple[int, ...] = (8, 16, 32, 64, 128),
-    repeats: int = 3, backend: str | None = None,
-) -> int:
-    """Sweep strip heights on this backend, return the fastest.
-
-    Wall-clock autotune over the real kernel (interpret mode off-TPU, so
-    absolute numbers are NOT TPU projections — but the relative ranking
-    tracks the tiling trade-off).  Memoized per (shape, candidates,
-    backend): an FWISession rebuilt after RESHARD re-reads the cached
-    choice instead of re-timing."""
-    return _autotune_bz_cached(
-        nz, nx, tuple(candidates), repeats, _tune_backend(backend)
-    )
-
-
-@functools.lru_cache(maxsize=None)
-def _autotune_bz_k_cached(
-    nz: int, nx: int, bz_candidates: tuple[int, ...],
-    k_candidates: tuple[int, ...], repeats: int, backend: str,
-) -> tuple[int, int]:
-    key = jax.random.key(0)
-    p = jax.random.normal(key, (nz, nx), jnp.float32)
-    v = jnp.full((nz, nx), 0.1, jnp.float32)
-    s = jnp.ones((nz, nx), jnp.float32)
-    best, best_t = (pick_bz_block(nz, pick_k(nz)), pick_k(nz)), float("inf")
-    for k in k_candidates:
-        srcv = jnp.zeros((k,), jnp.float32)
-        bzs = [b for b in bz_candidates
-               if nz % b == 0 and (b + 2 * k * HALO <= nz or b == nz)]
-        if not bzs:
-            bzs = [pick_bz_block(nz, k)]
-        for b in bzs:
-            out = wave_block_pallas(p, p, v, s, srcv, 0, 0, bz=b)
-            jax.block_until_ready(out)
-            t0 = time.perf_counter()
-            for _ in range(repeats):
-                out = wave_block_pallas(p, p, v, s, srcv, 0, 0, bz=b)
-            jax.block_until_ready(out)
-            dt = (time.perf_counter() - t0) / (repeats * k)   # per step
-            if dt < best_t:
-                best, best_t = (b, k), dt
-    return best
-
-
-@functools.lru_cache(maxsize=None)
-def _autotune_stream_cached(
-    nz: int, nx: int, bz_candidates: tuple[int, ...],
-    k_candidates: tuple[int, ...], repeats: int, backend: str,
-    budget: int,
-) -> tuple[int, int]:
-    key = jax.random.key(0)
-    p = jax.random.normal(key, (nz, nx), jnp.float32)
-    v = jnp.full((nz, nx), 0.1, jnp.float32)
-    s = jnp.ones((nz, nx), jnp.float32)
-    best, best_t = None, float("inf")
-    for k in k_candidates:
-        srcv = jnp.zeros((k,), jnp.float32)
-        bzs = [b for b in bz_candidates
-               if nz % b == 0 and b + 2 * k * HALO <= nz
-               and stream_vmem_bytes(nz, nx, b, k) <= budget]
-        if not bzs:
-            try:
-                bzs = [pick_bz_stream(nz, nx, k, vmem_budget=budget)]
-            except ValueError:
-                continue                      # no streamable strip at this k
-        for b in bzs:
-            out = wave_block_stream_pallas(
-                p, p, v, s, srcv, 0, 0, bz=b, vmem_budget=budget
-            )
-            jax.block_until_ready(out)
-            t0 = time.perf_counter()
-            for _ in range(repeats):
-                out = wave_block_stream_pallas(
-                    p, p, v, s, srcv, 0, 0, bz=b, vmem_budget=budget
-                )
-            jax.block_until_ready(out)
-            dt = (time.perf_counter() - t0) / (repeats * k)   # per step
-            if dt < best_t:
-                best, best_t = (b, k), dt
-    if best is None:
-        raise ValueError(
-            f"no (bz, k) candidate streams nz={nz}, nx={nx} under "
-            f"vmem_budget={budget}"
-        )
-    return best
-
-
-@functools.lru_cache(maxsize=None)
-def _autotune_shots_cached(
-    ns: int, nz: int, nx: int, bz_candidates: tuple[int, ...],
-    k_candidates: tuple[int, ...], tile_candidates: tuple[int, ...],
-    repeats: int, backend: str, stream: bool, budget: int,
-) -> tuple[int, int, int]:
-    key = jax.random.key(0)
-    p = jax.random.normal(key, (ns, nz, nx), jnp.float32)
-    v = jnp.full((nz, nx), 0.1, jnp.float32)
-    s = jnp.ones((nz, nx), jnp.float32)
-    sz = jnp.full((ns,), nz // 2, jnp.int32)
-    sx = jnp.arange(ns, dtype=jnp.int32) % nx
-    best, best_t = None, float("inf")
-    for k in k_candidates:
-        srcv = jnp.zeros((k,), jnp.float32)
-        for t in tile_candidates:
-            if not 1 <= t <= ns:
-                continue
-            if stream:
-                bzs = [b for b in bz_candidates
-                       if nz % b == 0 and b + 2 * k * HALO <= nz
-                       and stream_vmem_bytes(nz, nx, b, k, s=t) <= budget]
-                if not bzs:
-                    try:
-                        bzs = [pick_bz_stream(nz, nx, k,
-                                              vmem_budget=budget, s=t)]
-                    except ValueError:
-                        continue          # no streamable strip at (k, t)
-            else:
-                bzs = [b for b in bz_candidates
-                       if nz % b == 0
-                       and (b + 2 * k * HALO <= nz or b == nz)
-                       and resident_vmem_bytes(nz, nx, k, bz=b,
-                                               s=t) <= budget]
-                if not bzs:
-                    continue              # tile blows the resident budget
-
-            def run(b, t=t, srcv=srcv):
-                if stream and ns % t == 0:    # tiles walked in the grid
-                    return wave_block_shots_stream_pallas(
-                        p, p, v, s, srcv, sz, sx, bz=b,
-                        vmem_budget=budget, shot_tile=t,
-                    )
-                outs = []
-                for lo in range(0, ns, t):
-                    hi = min(lo + t, ns)
-                    if stream:
-                        outs.append(wave_block_shots_stream_pallas(
-                            p[lo:hi], p[lo:hi], v, s, srcv,
-                            sz[lo:hi], sx[lo:hi], bz=b,
-                            vmem_budget=budget,
-                        ))
-                    else:
-                        outs.append(wave_block_shots_pallas(
-                            p[lo:hi], p[lo:hi], v, s, srcv,
-                            sz[lo:hi], sx[lo:hi], bz=b,
-                        ))
-                return outs
-
-            for b in bzs:
-                jax.block_until_ready(run(b))          # compile
-                t0 = time.perf_counter()
-                for _ in range(repeats):
-                    out = run(b)
-                jax.block_until_ready(out)
-                # amortized per step per shot
-                dt = (time.perf_counter() - t0) / (repeats * k * ns)
-                if dt < best_t:
-                    best, best_t = (b, k, t), dt
-    if best is None:
-        raise ValueError(
-            f"no (bz, k, shot_tile) candidate fits ns={ns}, nz={nz}, "
-            f"nx={nx} under vmem_budget={budget} (stream={stream})"
-        )
-    return best
-
-
-def autotune_bz_k(
-    nz: int, nx: int,
-    bz_candidates: tuple[int, ...] = (8, 16, 24, 32, 40, 64, 120, 128),
-    k_candidates: tuple[int, ...] = (1, 2, 4, 8),
-    repeats: int = 3, backend: str | None = None,
-    *, stream: bool | None = None, vmem_budget: int | None = None,
-    n_shots: int | None = None,
-    shot_tile_candidates: tuple[int, ...] | None = None,
-):
-    """Jointly tune (strip height, fused-block length) for ``wave_block``.
-
-    Amortized per-STEP wall clock decides, so longer blocks only win
-    when the extra trapezoid compute pays for the saved round trips.
-    Memoized per (shape, candidates, backend) in-process — repeated
-    ``FWISession`` rebuilds after a RESHARD reuse the cached pair
-    instead of re-timing (DESIGN.md §13).
-
-    ``stream`` switches the search to the STREAMED kernel's (strip,
-    depth) space, where candidates must also fit ``vmem_budget``
-    (``stream_vmem_bytes``); ``stream=None`` auto-selects via
-    ``should_stream`` — grids whose resident design would blow the
-    budget tune the streamed kernel (DESIGN.md §15).
-
-    ``n_shots`` extends the search to the SHOT-BATCHED engine's
-    ``(bz, k, shot_tile)`` space (DESIGN.md §17): candidates sweep the
-    tile sizes in ``shot_tile_candidates`` (default: the divisors of
-    ``n_shots``), each sized against the s-aware VMEM accounting, and
-    the return value becomes a 3-tuple.  Without ``n_shots`` the
-    classic 2-tuple ``(bz, k)`` is returned, so existing callers are
-    unchanged."""
-    budget = vmem_budget if vmem_budget is not None else DEFAULT_VMEM_BUDGET
-    if stream is None:
-        stream = should_stream(nz, nx, vmem_budget=budget)
-    if n_shots is not None:
-        if shot_tile_candidates is None:
-            shot_tile_candidates = tuple(
-                t for t in range(1, n_shots + 1) if n_shots % t == 0
-            )
-        return _autotune_shots_cached(
-            n_shots, nz, nx, tuple(bz_candidates), tuple(k_candidates),
-            tuple(shot_tile_candidates), repeats, _tune_backend(backend),
-            bool(stream), budget,
-        )
-    if stream:
-        return _autotune_stream_cached(
-            nz, nx, tuple(bz_candidates), tuple(k_candidates), repeats,
-            _tune_backend(backend), budget,
-        )
-    return _autotune_bz_k_cached(
-        nz, nx, tuple(bz_candidates), tuple(k_candidates), repeats,
-        _tune_backend(backend),
-    )

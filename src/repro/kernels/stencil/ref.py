@@ -19,8 +19,8 @@ Two Laplacian formulations live here:
   production form: XLA fuses the slice-adds into a single pass, so the
   only extra materialization is the padded copy.
 * ``laplacian_roll`` — the original roll-then-mask form (8 rolls + 8
-  masked sets per step, each a full-array copy on CPU), kept as the
-  benchmark baseline and as an independent oracle.
+  masked sets per step, each a full-array copy on CPU), kept as an
+  independent oracle.
 
 Both accumulate terms in the SAME order (center, then the d=1 ring
 z/x, then the d=2 ring), so they are bit-identical in f32 — the fused
@@ -132,7 +132,7 @@ def wave_block_ref(
 ):
     """k fused timesteps with in-block source injection + receiver rows.
 
-    The pure-XLA mirror of the Pallas ``wave_block`` kernel (k is static,
+    The single-shot pure-XLA block (k is static,
     read off ``src_vals.shape``).  Two fusions vs the step-at-a-time
     form (DESIGN.md §13):
 
@@ -188,7 +188,7 @@ def wave_block_shots_ref(
     source injection scatters to ``(shot, z_s, x_s)`` — one element per
     batch row, so the adds are order-independent and bitwise equal to
     the per-shot ``at[z, x].add``.  This is the dispatch target
-    ``ops.wave_block`` uses for 3-D inputs on the XLA path, keeping the
+    ``ops.wave_block`` uses on the XLA path, keeping the
     engine's bitwise contract intact while the model fields are shared
     (DESIGN.md §17).  Returns (p_k, p_prev_damped_k, traces (S, k, NX)).
     """

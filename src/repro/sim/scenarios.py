@@ -124,8 +124,8 @@ def overheads_from_probe(
 #: MEASURED shot-batch scaling probe for the batched stencil engine
 #: (DESIGN.md §17) — a committed snapshot of the streamed shot-batched
 #: kernel's per-timestep wall clock vs batch size S (600×600, k=8,
-#: bz=120, Pallas interpret on CPU, best-of-4; re-run
-#: ``benchmarks/bench_fused_scan.py --shot-batch`` to refresh).
+#: bz=120, Pallas interpret on CPU, best-of-4: a host number, which a
+#: fit from chip runs is to replace, ROADMAP.md).
 #: ``t_step_vmapped_s4`` is the PRE-batching engine (one kernel per
 #: shot) at the full batch — the 1.47× the batched engine banks shows
 #: up BETWEEN the engines, while within the batched engine the CPU

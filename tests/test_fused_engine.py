@@ -2,13 +2,13 @@
 
 Equivalence ladder for the fused engine:
   pad-slice laplacian  == roll laplacian          (bitwise)
-  scan-runner          == per-step jitted loop    (bitwise, incl. traces)
+  block runner         == per-step reference loop (bitwise, incl. traces)
   wave_block (XLA)     == k sequential ref steps  (BITWISE — the fused
                           block is a pure re-scheduling of the same ops)
   wave_block (Pallas)  == same, to documented allclose tolerance (the
                           kernel's z/x stencil accumulation order
                           differs from the reference)
-  overlapped sharded k-step block == reference    (bitwise on the XLA
+  sharded k-step scan runner == reference         (bitwise on the XLA
                           path, incl. across REAL stripe seams)
 plus the communication claims: ppermute count per timestep drops k×,
 the halo-plan bookkeeping (incl. overlap fields) matches the lowered
@@ -29,19 +29,20 @@ from repro.fwi.domain import (
     effective_block,
     halo_bytes_per_step,
     halo_exchange_plan,
-    make_sharded_multistep,
     make_sharded_scan_runner,
     stripe_mesh,
 )
 from repro.fwi.solver import (
     FWIConfig,
     ShotState,
-    make_scan_runner,
-    make_step_fn,
+    make_block_runner,
+    ricker,
     run_forward,
+    sponge_taper,
     velocity_model,
 )
-from repro.kernels.stencil.ref import laplacian, laplacian_roll
+from repro.kernels.stencil.ops import wave_block
+from repro.kernels.stencil.ref import laplacian, laplacian_roll, wave_step_ref
 
 CFG = FWIConfig(nz=64, nx=128, timesteps=48, n_shots=2, sponge_width=8)
 
@@ -56,17 +57,36 @@ def test_laplacian_pad_equals_roll_bitwise():
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_scan_runner_traces_equal_per_step_loop():
-    """The fused scan must reproduce the per-step dispatch loop exactly,
-    receiver traces included."""
-    step = make_step_fn(CFG)
-    st = ShotState.init(CFG)
+def _per_step_loop(cfg, steps):
+    """The engine's oracle: one jitted ``wave_step_ref`` timestep at a
+    time for the whole batch, each shot's source injected after it and
+    the receiver row read out.  Returns (p, p_prev, traces (S, T, NX))."""
+    v2dt2 = (velocity_model(cfg) * cfg.dt / cfg.dx) ** 2
+    sponge = sponge_taper(cfg)
+    wavelet = ricker(cfg)
+    pos = cfg.shot_positions()
+    shots = np.arange(cfg.n_shots)
+
+    @jax.jit
+    def step(p, pp, t):
+        pn, pd = wave_step_ref(p, pp, v2dt2, sponge)
+        src = wavelet[jnp.clip(t, 0, cfg.timesteps - 1)] * (cfg.dt ** 2)
+        pn = pn.at[shots, pos[:, 0], pos[:, 1]].add(src)
+        return pn, pd, pn[:, cfg.receiver_depth, :]
+
+    st = ShotState.init(cfg)
     p, pp = st.p, st.p_prev
     traces = []
-    for t in range(CFG.timesteps):
+    for t in range(steps):
         p, pp, tr = step(p, pp, t)
         traces.append(tr)
-    loop_tr = jnp.stack(traces, axis=1)
+    return p, pp, jnp.stack(traces, axis=1)
+
+
+def test_scan_runner_traces_equal_per_step_loop():
+    """The fused block runner must reproduce the per-step reference loop
+    exactly, receiver traces included."""
+    p, _, loop_tr = _per_step_loop(CFG, CFG.timesteps)
     st_scan, scan_tr = run_forward(CFG)
     np.testing.assert_array_equal(np.asarray(st_scan.p), np.asarray(p))
     np.testing.assert_array_equal(np.asarray(scan_tr), np.asarray(loop_tr))
@@ -74,8 +94,9 @@ def test_scan_runner_traces_equal_per_step_loop():
 
 def test_scan_runner_restart_offset_no_retrace():
     """t0 is traced: restarting mid-run reuses the compiled runner and
-    matches the straight-through run bit-for-bit."""
-    run = make_scan_runner(CFG, collect_traces=True)
+    matches the straight-through run and the per-step reference loop
+    bit-for-bit."""
+    run = make_block_runner(CFG, collect_traces=True)
     st = ShotState.init(CFG)
     p_a, pp_a, tr_a = run(st.p, st.p_prev, 0, 48)
     p_b, pp_b, tr1 = run(st.p, st.p_prev, 0, 24)
@@ -84,12 +105,22 @@ def test_scan_runner_restart_offset_no_retrace():
     np.testing.assert_array_equal(
         np.asarray(tr_a), np.asarray(jnp.concatenate([tr1, tr2], axis=1))
     )
+    p_l, pp_l, tr_l = _per_step_loop(CFG, 48)
+    for a, b in ((p_a, p_l), (pp_a, pp_l), (tr_a, tr_l)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_model_building_memoized():
     assert velocity_model(CFG) is velocity_model(CFG)
-    assert make_scan_runner(CFG) is make_scan_runner(CFG)
-    assert make_step_fn(CFG) is make_step_fn(CFG)
+    run = make_block_runner(CFG, collect_traces=False)
+    assert make_block_runner(CFG, collect_traces=False) is run
+    assert make_block_runner(CFG) is make_block_runner(CFG)
+    # the memoized runner is the engine the per-step loop pins
+    st = ShotState.init(CFG)
+    p, pp = run(st.p, st.p_prev, 0, 8)
+    p_l, pp_l, _ = _per_step_loop(CFG, 8)
+    np.testing.assert_array_equal(np.asarray(p), np.asarray(p_l))
+    np.testing.assert_array_equal(np.asarray(pp), np.asarray(pp_l))
 
 
 # ------------------------------------------------- temporal blocking layer
@@ -101,12 +132,13 @@ def test_temporal_block_equals_sequential_ref(k):
     steps, to well under the 1e-4 acceptance tolerance."""
     ref, ref_tr = run_forward(CFG, steps=CFG.timesteps)
     mesh = stripe_mesh(1)
-    blk, place = make_sharded_multistep(CFG, mesh, k=k)
+    run, place, keff = make_sharded_scan_runner(CFG, mesh, k=k)
+    assert keff == k
     s = ShotState.init(CFG)
     p, pp = place((s.p, s.p_prev))
     trs = []
-    for b in range(CFG.timesteps // k):
-        p, pp, tr = blk(p, pp, b * k)
+    for b in range(CFG.timesteps // k):      # one block per dispatch
+        p, pp, tr = run(p, pp, b * k, 1)
         trs.append(tr)
     tr = jnp.concatenate(trs, axis=1)
     np.testing.assert_allclose(np.asarray(p), np.asarray(ref.p), atol=1e-6)
@@ -132,37 +164,25 @@ import sys
 sys.path.insert(0, sys.argv[1])
 import jax, jax.numpy as jnp, numpy as np
 from repro.fwi.solver import FWIConfig, ShotState, run_forward
-from repro.fwi.domain import stripe_mesh, make_sharded_multistep
+from repro.fwi.domain import stripe_mesh, make_sharded_scan_runner
 
 cfg = FWIConfig(nz=64, nx=128, timesteps=40, n_shots=2, sponge_width=8)
 ref, ref_tr = run_forward(cfg, steps=40)
-for overlap in (True, False):
-    for k in (2, 4, 8):
-        for n in (2, 4):
-            mesh = stripe_mesh(n)
-            blk, place = make_sharded_multistep(
-                cfg, mesh, k=k, overlap=overlap
-            )
-            s = ShotState.init(cfg)
-            p, pp = place((s.p, s.p_prev))
-            trs = []
-            for b in range(40 // blk.k):
-                p, pp, tr = blk(p, pp, b * blk.k)
-                trs.append(tr)
-            tr = jnp.concatenate(trs, axis=1)
-            if overlap:
-                # the overlapped XLA block path is pinned BITWISE equal
-                # to the seed reference, seams included
-                assert np.array_equal(np.asarray(p), np.asarray(ref.p)), (k, n)
-                assert np.array_equal(np.asarray(tr), np.asarray(ref_tr)), (k, n)
-            else:
-                # the single-window schedule computes the identical op
-                # sequence but its different fusion shapes may flush
-                # denormal wavefront tails differently — equal up to
-                # sub-normal noise (< FLT_MIN = 1.2e-38)
-                perr = np.max(np.abs(np.asarray(p) - np.asarray(ref.p)))
-                terr = np.max(np.abs(np.asarray(tr) - np.asarray(ref_tr)))
-                assert perr < 1.2e-38 and terr < 1.2e-38, (k, n, perr, terr)
+for k in (2, 4, 8):
+    for n in (2, 4):
+        run, place, keff = make_sharded_scan_runner(cfg, stripe_mesh(n), k=k)
+        assert place.schedule == "pipeline", place.schedule
+        s = ShotState.init(cfg)
+        p, pp = place((s.p, s.p_prev))
+        trs = []
+        for b in range(40 // keff):          # one block per dispatch
+            p, pp, tr = run(p, pp, b * keff, 1)
+            trs.append(tr)
+        tr = jnp.concatenate(trs, axis=1)
+        # the pipelined XLA block path is pinned BITWISE equal to the
+        # seed reference, seams included
+        assert np.array_equal(np.asarray(p), np.asarray(ref.p)), (k, n)
+        assert np.array_equal(np.asarray(tr), np.asarray(ref_tr)), (k, n)
 
 # shot-parallel fused runner: zero-communication first-level split;
 # contract is f32-ULP allclose (per-device batch changes XLA's
@@ -195,22 +215,43 @@ def test_temporal_block_multi_stripe_subprocess():
     assert "BLOCKED_MULTI_STRIPE_OK" in out.stdout
 
 
+_PPERMUTES = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+import sys
+sys.path.insert(0, sys.argv[1])
+import jax.numpy as jnp
+from repro.fwi.domain import make_sharded_scan_runner, stripe_mesh
+from repro.fwi.solver import FWIConfig
+
+cfg = FWIConfig(nz=64, nx=128, timesteps=48, n_shots=2, sponge_width=8)
+z = jnp.zeros((cfg.n_shots, cfg.nz, cfg.nx), jnp.float32)
+for k in (1, 4):
+    run, place, keff = make_sharded_scan_runner(cfg, stripe_mesh(2), k=k)
+    for blocks in (1, 3):
+        txt = run.lower(*place((z, z)), 0, blocks=blocks).as_text()
+        n = txt.count("collective_permute") + txt.count("collective-permute")
+        print("k", keff, "blocks", blocks, "ppermutes", n)
+"""
+
+
 def test_ppermute_count_drops_k_fold():
     """k=4 temporal blocking must emit the SAME 2 collective-permutes
-    per block as k=1 — i.e. 4× fewer per timestep.  One stripe's default
-    exchanges nothing, so the exchanging schedule is named."""
-    mesh = stripe_mesh(1)
-    s = ShotState.init(CFG)
-    counts = {}
-    for k in (1, 4):
-        blk, place = make_sharded_multistep(CFG, mesh, k=k,
-                                            overlap="overlap")
-        p, pp = place((s.p, s.p_prev))
-        txt = jax.jit(blk).lower(p, pp, 0).as_text()
-        counts[k] = txt.count("collective_permute") \
-            + txt.count("collective-permute")
-    assert counts[1] == 2, counts
-    assert counts[4] == 2, counts          # per-timestep: 2 vs 0.5 = 4x
+    per block as k=1 — i.e. 4× fewer per timestep.  The two-stripe
+    pipeline's program holds its prologue's 2 and the scan body's 2,
+    whatever the number of blocks (one stripe exchanges nothing)."""
+    src = str(Path(__file__).resolve().parents[1] / "src")
+    env = dict(os.environ)
+    env.pop("XLA_FLAGS", None)
+    out = subprocess.run(
+        [sys.executable, "-c", _PPERMUTES, src],
+        capture_output=True, text=True, env=env, timeout=600,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    lines = out.stdout.split("\n")
+    for k in (1, 4):                       # per-timestep: 2 vs 0.5 = 4x
+        for blocks in (1, 3):
+            assert f"k {k} blocks {blocks} ppermutes 4" in lines, out.stdout
 
 
 def test_halo_exchange_plan_bookkeeping():
@@ -226,8 +267,8 @@ def test_halo_exchange_plan_bookkeeping():
     # k clamps so the overlap fits in a stripe, and the clamped value is
     # exposed on the block step so callers advance t0 correctly
     assert effective_block(CFG, CFG.nx // 2, 64) == 1
-    blk, _ = make_sharded_multistep(CFG, stripe_mesh(1), k=4)
-    assert blk.k == 4
+    _, _, k = make_sharded_scan_runner(CFG, stripe_mesh(1), k=4)
+    assert k == 4
 
 
 def test_effective_block_keeps_overlap_inside_stripe():
@@ -299,6 +340,14 @@ def _sequential_ref(p, pp, v, s, srcv, zi, xi, rrow):
     return p, pp, jnp.stack(traces)
 
 
+def _one_shot_block(p, pp, v, s, srcv, zi, xi, **kw):
+    """``wave_block`` on one shot: the (NZ, NX) fields as the batch S=1,
+    its outputs back as (NZ, NX) fields and (k, NX) traces."""
+    out = wave_block(p[None], pp[None], v, s, srcv, jnp.array([zi]),
+                     jnp.array([xi]), **kw)
+    return tuple(o[0] for o in out)
+
+
 def _block_fields(nz, nx, seed=0):
     ks = jax.random.split(jax.random.key(seed), 4)
     p = jax.random.normal(ks[0], (nz, nx), jnp.float32)
@@ -313,14 +362,12 @@ def test_wave_block_xla_bitwise_vs_sequential_ref(k):
     """The pure-XLA fused block is a re-scheduling of the identical ops:
     BITWISE equal to k sequential seed-form steps (random fields put
     energy at every physical domain edge)."""
-    from repro.kernels.stencil.ops import wave_block
-
     nz, nx = 64, 96
     p, pp, v, s = _block_fields(nz, nx, seed=k)
     srcv = jnp.linspace(0.5, 1.0, k)
     zi, xi = nz // 3, nx // 2
     a = _sequential_ref(p, pp, v, s, srcv, zi, xi, 2)
-    b = wave_block(p, pp, v, s, srcv, zi, xi, receiver_row=2)
+    b = _one_shot_block(p, pp, v, s, srcv, zi, xi, receiver_row=2)
     for x, y in zip(a, b):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
@@ -332,15 +379,13 @@ def test_wave_block_pallas_matches_ref(k, bz):
     (bz, k).  Contract: allclose at 5e-5 (NOT bitwise — the kernel
     accumulates the z then x stencil rings, the reference interleaves
     them per ring; each inner step compounds ~1e-6)."""
-    from repro.kernels.stencil.ops import wave_block
-
     nz, nx = 64, 96
     p, pp, v, s = _block_fields(nz, nx, seed=10 + k)
     srcv = jnp.linspace(0.5, 1.0, k)
     zi, xi = 1, nx - 2            # source ON the corner boundary region
     a = _sequential_ref(p, pp, v, s, srcv, zi, xi, 2)
-    b = wave_block(p, pp, v, s, srcv, zi, xi, receiver_row=2,
-                   use_pallas=True, bz=bz)
+    b = _one_shot_block(p, pp, v, s, srcv, zi, xi, receiver_row=2,
+                        use_pallas=True, bz=bz)
     for x, y in zip(a, b):
         np.testing.assert_allclose(np.asarray(x), np.asarray(y),
                                    atol=5e-5)
@@ -350,73 +395,48 @@ def test_wave_block_single_strip_fallback():
     """Grids too short for any multi-strip trapezoid (prime nz) run as
     one whole-height strip and still match."""
     from repro.kernels.stencil.kernel import pick_bz_block
-    from repro.kernels.stencil.ops import wave_block
 
     nz, nx = 37, 64
     assert pick_bz_block(nz, 8) == nz
     p, pp, v, s = _block_fields(nz, nx, seed=3)
     srcv = jnp.ones((8,)) * 0.5
     a = _sequential_ref(p, pp, v, s, srcv, 5, 6, 1)
-    b = wave_block(p, pp, v, s, srcv, 5, 6, receiver_row=1,
-                   use_pallas=True)
+    b = _one_shot_block(p, pp, v, s, srcv, 5, 6, receiver_row=1,
+                        use_pallas=True)
     for x, y in zip(a, b):
         np.testing.assert_allclose(np.asarray(x), np.asarray(y),
                                    atol=5e-5)
 
 
 def test_block_runner_factories_key_on_full_knobs():
-    """Memoized factories must key on (k, bz, use_pallas) so autotuned
-    variants don't collide in the cache — and still hit for equal args."""
-    from repro.fwi.solver import make_block_runner
-
+    """Memoized factories must key on (k, bz, use_pallas) so variants
+    don't collide in the cache — and still hit for equal args."""
     a = make_block_runner(CFG, k=2)
     assert make_block_runner(CFG, k=2) is a
     assert make_block_runner(CFG, k=4) is not a
     assert make_block_runner(CFG, k=2, bz=8) is not a
     assert make_block_runner(CFG, k=2, use_pallas=True) is not a
-    m1, _ = make_sharded_multistep(CFG, stripe_mesh(1), k=2)
-    m2, _ = make_sharded_multistep(CFG, stripe_mesh(1), k=2, bz=8)
-    m3, _ = make_sharded_multistep(CFG, stripe_mesh(1), k=2)
+    m1, _, _ = make_sharded_scan_runner(CFG, stripe_mesh(1), k=2)
+    m2, _, _ = make_sharded_scan_runner(CFG, stripe_mesh(1), k=2, bz=8)
+    m3, _, _ = make_sharded_scan_runner(CFG, stripe_mesh(1), k=2)
     assert m1 is m3 and m1 is not m2
-
-
-def test_autotune_bz_k_memoized_per_shape_and_backend():
-    """The joint (bz, k) autotune must be measured once per (shape,
-    backend) — RESHARD-triggered session rebuilds hit the cache."""
-    from repro.kernels.stencil.kernel import (
-        _autotune_bz_k_cached, autotune_bz_k,
-    )
-
-    nz, nx = 32, 64
-    before = _autotune_bz_k_cached.cache_info()
-    r1 = autotune_bz_k(nz, nx, bz_candidates=(8, 16),
-                       k_candidates=(1, 2), repeats=1)
-    mid = _autotune_bz_k_cached.cache_info()
-    r2 = autotune_bz_k(nz, nx, bz_candidates=(8, 16),
-                       k_candidates=(1, 2), repeats=1)
-    after = _autotune_bz_k_cached.cache_info()
-    assert r1 == r2
-    assert mid.misses == before.misses + 1
-    assert after.hits == mid.hits + 1 and after.misses == mid.misses
-    bz, k = r1
-    assert nz % bz == 0 and k in (1, 2)
 
 
 def test_entry_boundary_bytes_drops_k_fold():
     """The launch-boundary HBM proxy: a k-step fused block moves the
     wavefields across the jit boundary once per k steps."""
-    from repro.kernels.stencil.ops import wave_block, wave_step
     from repro.launch.hlo_cost import entry_boundary_bytes
 
     nz, nx, k = 64, 96, 4
     p, pp, v, s = _block_fields(nz, nx)
-    f_step = jax.jit(
-        lambda a, b, vv, ss: wave_step(a, b, vv, ss)
-    ).lower(p, pp, v, s).compile()
-    srcv = jnp.zeros((k,))
-    f_blk = jax.jit(
-        lambda a, b, vv, ss, sv: wave_block(a, b, vv, ss, sv, 3, 4)
-    ).lower(p, pp, v, s, srcv).compile()
+    p, pp = p[None], pp[None]
+
+    def block(steps):
+        return jax.jit(
+            lambda a, b, vv, ss, sv: wave_block(a, b, vv, ss, sv, 3, 4)
+        ).lower(p, pp, v, s, jnp.zeros((steps,))).compile()
+
+    f_step, f_blk = block(1), block(k)
     shape = (nz, nx)
     sb = entry_boundary_bytes(f_step.as_text(), shape)
     bb = entry_boundary_bytes(f_blk.as_text(), shape)
@@ -432,18 +452,17 @@ def test_entry_boundary_bytes_drops_k_fold():
 
 @pytest.mark.parametrize("bz", [8, 16, 32, 64, None])
 def test_pallas_bz_sweep_matches_ref(bz):
-    """Single-input BlockSpec kernel vs ref across strip heights,
-    including the auto-picked one (bz=None)."""
-    from repro.kernels.stencil.ops import wave_step
-
+    """The resident kernel, one shot and one step, vs the reference step
+    across strip heights, including the auto-picked one (bz=None)."""
     nz, nx = 64, 256
     ks = jax.random.split(jax.random.key(7), 4)
     p = jax.random.normal(ks[0], (nz, nx), jnp.float32)
     pp = jax.random.normal(ks[1], (nz, nx), jnp.float32)
     v = jax.random.uniform(ks[2], (nz, nx), jnp.float32, 0.05, 0.2)
     sponge = jnp.clip(jax.random.uniform(ks[3], (nz, nx)), 0.9, 1.0)
-    a1, a2 = wave_step(p, pp, v, sponge)
-    b1, b2 = wave_step(p, pp, v, sponge, use_pallas=True, bz=bz)
+    a1, a2 = wave_step_ref(p, pp, v, sponge)
+    b1, b2, _ = _one_shot_block(p, pp, v, sponge, jnp.zeros((1,)), 0, 0,
+                                use_pallas=True, bz=bz, stream=False)
     np.testing.assert_allclose(a1, b1, atol=3e-6)
     np.testing.assert_allclose(a2, b2, atol=3e-6)
 
@@ -453,14 +472,14 @@ def test_sharded_pallas_path_equals_reference():
     runs inside the shard_map region and matches the reference."""
     cfg = FWIConfig(nz=32, nx=64, timesteps=8, n_shots=1, sponge_width=4)
     ref, ref_tr = run_forward(cfg, steps=8)
-    blk, place = make_sharded_multistep(
+    run, place, k = make_sharded_scan_runner(
         cfg, stripe_mesh(1), k=4, use_pallas=True
     )
     s = ShotState.init(cfg)
     p, pp = place((s.p, s.p_prev))
     trs = []
     for b in range(2):
-        p, pp, tr = blk(p, pp, b * 4)
+        p, pp, tr = run(p, pp, b * k, 1)
         trs.append(tr)
     tr = jnp.concatenate(trs, axis=1)
     np.testing.assert_allclose(np.asarray(p), np.asarray(ref.p), atol=1e-5)
@@ -468,18 +487,17 @@ def test_sharded_pallas_path_equals_reference():
                                atol=1e-5)
 
 
-@pytest.mark.parametrize("schedule", ["fused", "overlap", "pipeline"])
-def test_sharded_pallas_schedules_on_one_stripe(schedule):
-    """Every halo schedule runs the shot-batched kernels on one stripe —
-    "pipeline" (the TPU default) with its vmapped boundary windows and
-    self-exchange — and matches the reference to the Pallas 1e-5."""
-    from repro.fwi.domain import make_sharded_scan_runner
-
+@pytest.mark.parametrize("k", [1, 2, 4])
+def test_sharded_pallas_schedules_on_one_stripe(k):
+    """The one-stripe schedule runs the shot-batched kernel at every
+    block length — the whole stripe as one window — and matches the
+    reference to the Pallas 1e-5."""
     cfg = FWIConfig(nz=64, nx=256, timesteps=16, n_shots=3, sponge_width=8)
     ref, ref_tr = run_forward(cfg, steps=16, use_pallas=False)
-    run, place, k = make_sharded_scan_runner(
-        cfg, stripe_mesh(1), k=4, use_pallas=True, overlap=schedule
+    run, place, keff = make_sharded_scan_runner(
+        cfg, stripe_mesh(1), k=k, use_pallas=True
     )
+    assert (place.schedule, keff) == ("single", k)
     s = ShotState.init(cfg)
     p, _, tr = run(*place((s.p, s.p_prev)), 0, 16 // k)
     scale = float(np.max(np.abs(np.asarray(ref.p))))
@@ -508,23 +526,21 @@ def _run_one_stripe(cfg, steps, **kw):
 
 @pytest.mark.parametrize("cfg", [CFG, RAGGED], ids=["aligned", "ragged"])
 def test_one_stripe_default_is_the_single_window_bitwise(monkeypatch, cfg):
-    """One stripe's default schedule is the interior window alone, and
-    on the XLA path it equals the reference and the exchanging
-    "pipeline" schedule bit for bit: fields, padding and traces."""
+    """One stripe's schedule is the interior window alone, and on the
+    XLA path it equals the reference bit for bit: fields and traces,
+    with the padding of a ragged grid still exactly zero."""
     import repro.kernels.stencil.kernel as kernel
 
     ref, ref_tr = run_forward(cfg, steps=cfg.timesteps)
     monkeypatch.setattr(kernel, "DEFAULT_VMEM_BUDGET", SMALL_VMEM_BUDGET)
     place, carry, got = _run_one_stripe(cfg, cfg.timesteps)
-    pipe_place, pipe_carry, pipe = _run_one_stripe(
-        cfg, cfg.timesteps, overlap="pipeline")
-    assert (place.schedule, pipe_place.schedule) == ("single", "pipeline")
+    assert place.schedule == "single"
     assert place.geometry.padded(cfg) == (cfg is RAGGED)
-    for a, b in zip(carry, pipe_carry):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    for a, b, r in zip(got, pipe, (ref.p, ref.p_prev, ref_tr)):
+    for a in carry:
+        a = np.asarray(a)
+        assert not a[..., cfg.nz:, :].any() and not a[..., cfg.nx:].any()
+    for a, r in zip(got, (ref.p, ref.p_prev, ref_tr)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(r))
-        np.testing.assert_array_equal(np.asarray(b), np.asarray(r))
 
 
 def test_one_stripe_default_pallas_matches_reference(monkeypatch):
@@ -559,21 +575,20 @@ from repro.fwi.domain import (
 from repro.fwi.solver import FWIConfig
 
 cfg = FWIConfig(nz=32, nx=64, timesteps=32, n_shots=2, sponge_width=4)
-# the TPU's default for two stripes, and this host's own
-for schedule in (pick_schedule(2, "tpu"), None):
-    run, place, k = make_sharded_scan_runner(
-        cfg, stripe_mesh(2), k=4, use_pallas=False, overlap=schedule)
-    z = jnp.zeros((cfg.n_shots, cfg.nz, cfg.nx), jnp.float32)
-    hlo = run.lower(*place((z, z)), 0, blocks=2).compile().as_text()
-    for phase in sys.argv[2:] + ["/fwi.interior/"]:
-        print(place.schedule, phase, phase in hlo)
+run, place, k = make_sharded_scan_runner(
+    cfg, stripe_mesh(2), k=4, use_pallas=False)
+z = jnp.zeros((cfg.n_shots, cfg.nz, cfg.nx), jnp.float32)
+hlo = run.lower(*place((z, z)), 0, blocks=2).compile().as_text()
+print("pick", pick_schedule(2))
+for phase in sys.argv[2:] + ["/fwi.interior/"]:
+    print(place.schedule, phase, phase in hlo)
 """
 
 
 def test_single_window_drops_the_seam_phases_only_on_one_stripe():
-    """The compiled one-stripe default holds the interior and none of
+    """The compiled one-stripe program holds the interior and none of
     the exchange, boundary windows or stitch; two stripes keep them all
-    under the TPU's default, and this host's default still exchanges."""
+    under "pipeline", on this host as on the TPU."""
     run, place, k = make_sharded_scan_runner(CFG, stripe_mesh(1), k=4)
     s = ShotState.init(CFG)
     hlo = run.lower(*place((s.p, s.p_prev)), 0, blocks=2).compile().as_text()
@@ -590,10 +605,9 @@ def test_single_window_drops_the_seam_phases_only_on_one_stripe():
     )
     assert out.returncode == 0, out.stderr[-2000:]
     lines = out.stdout.split("\n")
+    assert "pick pipeline" in lines, out.stdout
     for phase in _SEAM_PHASES + ("/fwi.interior/",):
         assert f"pipeline {phase} True" in lines, out.stdout
-    for phase in ("collective-permute", "/fwi.exchange/"):
-        assert f"fused {phase} True" in lines, out.stdout
 
 
 def test_driver_checkpoint_carries_block_progress():
@@ -620,31 +634,35 @@ def test_driver_checkpoint_carries_block_progress():
 
 
 def test_interpret_auto_selects_off_tpu():
-    from repro.kernels.stencil.kernel import HALO, default_interpret, pick_bz
+    from repro.kernels.stencil.kernel import (
+        HALO, StripFallbackWarning, default_interpret, pick_bz_block,
+    )
 
     if jax.default_backend() != "tpu":
         assert default_interpret() is True
-    assert 600 % pick_bz(600) == 0 and pick_bz(600) % 8 == 0
-    assert pick_bz(64) == 64
+    assert 600 % pick_bz_block(600, 1) == 0
+    assert pick_bz_block(600, 1) % 8 == 0
+    assert pick_bz_block(64, 1) == 32
     # strips shorter than the halo would silently mis-clamp the
     # neighbor-row slices: prime heights fall back to one whole strip
-    assert pick_bz(251) == 251
-    assert pick_bz(127) >= HALO
+    with pytest.warns(StripFallbackWarning):
+        assert pick_bz_block(251, 1) == 251
+    assert pick_bz_block(127, 1) >= HALO
 
 
 def test_step_and_block_share_interpret_default():
-    """wave_step and wave_block must agree on backend detection through
-    the ONE shared helper — a drifted copy would silently run one
-    kernel compiled and the other interpreted."""
+    """The resident and streamed kernels must agree on backend detection
+    through the ONE shared helper — a drifted copy would silently run
+    one kernel compiled and the other interpreted."""
     import inspect
 
     from repro.kernels.stencil import kernel, ops
 
     assert ops.default_interpret is kernel.default_interpret
-    src_step = inspect.getsource(kernel.wave_step_pallas)
-    src_blk = inspect.getsource(kernel.wave_block_pallas)
-    assert "default_interpret()" in src_step
-    assert "default_interpret()" in src_blk
+    src_res = inspect.getsource(kernel.wave_block_shots_pallas)
+    src_stm = inspect.getsource(kernel.wave_block_shots_stream_pallas)
+    assert "default_interpret()" in src_res
+    assert "default_interpret()" in src_stm
 
 
 def test_pick_bz_block_and_pick_k():
@@ -665,15 +683,14 @@ def test_pick_bz_block_and_pick_k():
 def test_pallas_prime_height_auto_bz():
     """nz with no divisor in [HALO, cap] (prime 251) must still match
     the reference through the auto-picked single-strip path."""
-    from repro.kernels.stencil.ops import wave_step
-
     nz, nx = 251, 128
     ks = jax.random.split(jax.random.key(11), 4)
     p = jax.random.normal(ks[0], (nz, nx), jnp.float32)
     pp = jax.random.normal(ks[1], (nz, nx), jnp.float32)
     v = jax.random.uniform(ks[2], (nz, nx), jnp.float32, 0.05, 0.2)
     sponge = jnp.clip(jax.random.uniform(ks[3], (nz, nx)), 0.9, 1.0)
-    a1, a2 = wave_step(p, pp, v, sponge)
-    b1, b2 = wave_step(p, pp, v, sponge, use_pallas=True)
+    a1, a2 = wave_step_ref(p, pp, v, sponge)
+    b1, b2, _ = _one_shot_block(p, pp, v, sponge, jnp.zeros((1,)), 0, 0,
+                                use_pallas=True)
     np.testing.assert_allclose(a1, b1, atol=3e-6)
     np.testing.assert_allclose(a2, b2, atol=3e-6)

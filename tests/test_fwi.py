@@ -10,11 +10,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.fwi.domain import halo_bytes_per_step, make_sharded_step, stripe_mesh
+from repro.fwi.domain import (
+    halo_bytes_per_step,
+    make_sharded_scan_runner,
+    stripe_mesh,
+)
 from repro.fwi.solver import (
     FWIConfig,
     ShotState,
-    make_step_fn,
     run_forward,
     sponge_taper,
     velocity_model,
@@ -58,11 +61,11 @@ def test_cfl_stability():
 def test_sharded_single_stripe_equals_reference():
     st_ref, _ = run_forward(CFG, steps=40)
     mesh = stripe_mesh(1)
-    step, place = make_sharded_step(CFG, mesh)
+    run, place, k = make_sharded_scan_runner(CFG, mesh, k=1)
     s = ShotState.init(CFG)
     p, pp = place((s.p, s.p_prev))
-    for t in range(40):
-        p, pp, _ = step(p, pp, t)
+    for t in range(40):                      # one step per dispatch
+        p, pp, _ = run(p, pp, t, 1)
     np.testing.assert_allclose(np.asarray(p), np.asarray(st_ref.p),
                                atol=1e-10)
 
@@ -101,16 +104,16 @@ import sys
 sys.path.insert(0, sys.argv[1])
 import jax, jax.numpy as jnp, numpy as np
 from repro.fwi.solver import FWIConfig, ShotState, run_forward
-from repro.fwi.domain import stripe_mesh, make_sharded_step
+from repro.fwi.domain import stripe_mesh, make_sharded_scan_runner
 
 cfg = FWIConfig(nz=64, nx=128, timesteps=40, n_shots=2, sponge_width=8)
 ref, _ = run_forward(cfg, steps=40)
 mesh = stripe_mesh(4)
-step, place = make_sharded_step(cfg, mesh)
+run, place, k = make_sharded_scan_runner(cfg, mesh, k=1)
 s = ShotState.init(cfg)
 p, pp = place((s.p, s.p_prev))
-for t in range(40):
-    p, pp, _ = step(p, pp, t)
+for t in range(40):                          # one step per dispatch
+    p, pp, _ = run(p, pp, t, 1)
 err = float(jnp.max(jnp.abs(np.asarray(p) - np.asarray(ref.p))))
 assert err < 1e-10, f"halo exchange mismatch: {err}"
 print("MULTI_STRIPE_OK", err)

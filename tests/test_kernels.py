@@ -7,22 +7,42 @@ import pytest
 from repro.kernels.flash_attention.ops import attention
 from repro.kernels.rmsnorm.ops import rmsnorm_residual
 from repro.kernels.ssd.ops import ssd_chunk
-from repro.kernels.stencil.ops import wave_step
+from repro.kernels.stencil.ops import wave_block
+from repro.kernels.stencil.ref import wave_step_ref
 
 # --------------------------------------------------------------- stencil
 
 
-@pytest.mark.parametrize("nz,nx,bz", [
-    (256, 256, 128), (128, 384, 32), (512, 128, 64), (64, 640, 8),
+def _one_step(p, pp, v, sponge, **kw):
+    """One step of one shot through ``wave_block`` (the batch S=1, k=1,
+    no source): its (p_next, p_damped)."""
+    z = jnp.zeros((1,), jnp.int32)
+    pn, pd, _ = wave_block(p[None], pp[None], v, sponge,
+                           jnp.zeros((1,)), z, z, **kw)
+    return pn[0], pd[0]
+
+
+_SHAPES = [(256, 256, 128), (128, 384, 32), (512, 128, 64), (64, 640, 8)]
+
+
+@pytest.mark.parametrize("nz,nx,bz,stream", [
+    pytest.param(*shape, False, id="-".join(map(str, shape)))
+    for shape in _SHAPES
+] + [
+    pytest.param(*shape, True, id="stream-" + "-".join(map(str, shape)))
+    for shape in _SHAPES
 ])
-def test_stencil_matches_ref(nz, nx, bz):
+def test_stencil_matches_ref(nz, nx, bz, stream):
+    """The resident and the streamed kernel, one shot and one step, vs
+    the reference step."""
     ks = jax.random.split(jax.random.key(nz + nx), 4)
     p = jax.random.normal(ks[0], (nz, nx), jnp.float32)
     pp = jax.random.normal(ks[1], (nz, nx), jnp.float32)
     v = jax.random.uniform(ks[2], (nz, nx), jnp.float32, 0.05, 0.2)
     sponge = jnp.clip(jax.random.uniform(ks[3], (nz, nx)), 0.9, 1.0)
-    a1, a2 = wave_step(p, pp, v, sponge)
-    b1, b2 = wave_step(p, pp, v, sponge, use_pallas=True, bz=bz)
+    a1, a2 = wave_step_ref(p, pp, v, sponge)
+    b1, b2 = _one_step(p, pp, v, sponge, use_pallas=True, bz=bz,
+                       stream=stream)
     np.testing.assert_allclose(a1, b1, atol=3e-6)
     np.testing.assert_allclose(a2, b2, atol=3e-6)
 
@@ -34,8 +54,8 @@ def test_stencil_boundary_rows_match_ref():
     pp = jnp.zeros_like(p)
     v = jnp.full_like(p, 0.1)
     sponge = jnp.ones_like(p)
-    a, _ = wave_step(p, pp, v, sponge)
-    b, _ = wave_step(p, pp, v, sponge, use_pallas=True, bz=8)
+    a, _ = wave_step_ref(p, pp, v, sponge)
+    b, _ = _one_step(p, pp, v, sponge, use_pallas=True, bz=8)
     np.testing.assert_allclose(a[:4], b[:4], atol=1e-6)
     np.testing.assert_allclose(a[-4:], b[-4:], atol=1e-6)
 

@@ -68,8 +68,8 @@ VMEM_RESIDENT = textwrap.dedent('''\
         o_ref[...] = p_ref[...]
 
 
-    def wave_block_pallas(p, pp, v, sp):
-        spec = pl.BlockSpec((nz, nx), lambda: (0, 0))
+    def wave_block_shots_pallas(p, pp, v, sp):
+        spec = pl.BlockSpec((ns, nz, nx), lambda: (0, 0, 0))
         return pl.pallas_call(
             _body,
             in_specs=[spec, spec, spec, spec],
@@ -98,13 +98,13 @@ VMEM_STREAM = textwrap.dedent('''\
         o_ref[...] = win[...]
 
 
-    def wave_block_stream_pallas(p, bz, vmem_budget):
+    def wave_block_shots_stream_pallas(p, bz, vmem_budget):
         any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
         return pl.pallas_call(
             _body,
             in_specs=[any_spec],
             out_specs=any_spec,
-            scratch_shapes=[pltpu.VMEM((5 * bz, nx), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((ns, 5 * bz, nx), jnp.float32)],
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=vmem_budget,
             ),
@@ -158,7 +158,7 @@ def test_vmem_streamed_wrapper_without_limit_is_flagged():
 
 
 def test_vmem_unmapped_kernel_with_scratch_is_flagged():
-    src = VMEM_RESIDENT.replace("wave_block_pallas", "fancy_new_kernel") \
+    src = VMEM_RESIDENT.replace("wave_block_shots_pallas", "fancy_new_kernel") \
         .replace("scratch_shapes=[]",
                  "scratch_shapes=[pltpu.VMEM((128, 128), jnp.float32)]")
     fs = _vmem(src)
@@ -167,7 +167,7 @@ def test_vmem_unmapped_kernel_with_scratch_is_flagged():
 
 
 def test_vmem_unmapped_kernel_without_scratch_is_clean():
-    src = VMEM_RESIDENT.replace("wave_block_pallas", "plain_kernel")
+    src = VMEM_RESIDENT.replace("wave_block_shots_pallas", "plain_kernel")
     assert _vmem(src) == []
 
 
@@ -572,8 +572,8 @@ def test_injected_vmem_formula_drift_is_caught():
     assert old in src
     fs = analyze_source(src.replace(old, "2 * 3 * s * bz * nx"),
                         VmemBudgetRule(), filename=KREL)
-    # all four formula-mapped wrappers drift from the edited formula
-    assert len(fs) == 4
+    # both formula-mapped wrappers drift from the edited formula
+    assert len(fs) == 2
     assert all("drift" in f.message for f in fs)
 
 
@@ -604,10 +604,10 @@ def test_injected_unpaired_dma_start_is_caught():
 def test_injected_dma_slot_mismatch_is_caught():
     src = KERNEL.read_text()
     old = "for c in dma((i + 1) % 2, i + 1):"
-    assert src.count(old) == 2        # both streamed kernels
+    assert src.count(old) == 1        # the one streamed kernel
     fs = analyze_source(src.replace(old, "for c in dma(i % 2, i + 1):"),
                         DmaPairingRule(), filename=KREL)
-    assert len(fs) == 2
+    assert len(fs) == 1
     assert all("slot mismatch" in f.message for f in fs)
 
 

@@ -1,5 +1,5 @@
 """Shot-batched stencil engine (DESIGN.md §17): parity, VMEM
-accounting, tiling, autotune, and the uneven shot split."""
+accounting, tiling, dispatch, and the uneven shot split."""
 import os
 import subprocess
 import sys
@@ -13,13 +13,11 @@ import pytest
 from repro.kernels.stencil.kernel import (
     DEFAULT_VMEM_BUDGET,
     HALO,
-    autotune_bz_k,
     pick_bz_stream,
     pick_shot_tile,
     resident_vmem_bytes,
     should_stream,
     stream_vmem_bytes,
-    wave_block_pallas,
     wave_block_shots_pallas,
     wave_block_shots_stream_pallas,
 )
@@ -169,17 +167,6 @@ def test_streamed_dispatch_neither_splits_nor_joins_the_batch():
     assert "stencil.tile_concat" in ragged
 
 
-def test_shots_s1_bitwise_vs_2d_kernel():
-    p, pp, v, sp, srcv, sz, sx = _case(1, 64, 128, 4)
-    batched = wave_block_shots_pallas(p, pp, v, sp, srcv, sz, sx,
-                                      receiver_row=7, bz=16)
-    single = wave_block_pallas(p[0], pp[0], v, sp, srcv, sz[0], sx[0],
-                               receiver_row=7, bz=16)
-    for a, b in zip(batched, single):
-        assert np.array_equal(np.asarray(a)[0] if a.ndim == b.ndim + 1
-                              else np.asarray(a), np.asarray(b))
-
-
 # ------------------------------------------- dispatch + unaligned tiles
 
 
@@ -193,6 +180,16 @@ def test_dispatch_xla_shot_tile_bitwise(tile):
                        shot_tile=tile)
     for a, b in zip(full, tiled):
         assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_wave_block_refuses_2d_fields(use_pallas):
+    """One shot is the batch S=1: a bare (NZ, NX) field is refused, not
+    run through a kernel of its own."""
+    p, pp, v, sp, srcv, sz, sx = _case(1, 48, 64, 2)
+    with pytest.raises(ValueError, match="S, NZ, NX"):
+        wave_block(p[0], pp[0], v, sp, srcv, sz[0], sx[0],
+                   use_pallas=use_pallas)
 
 
 @pytest.mark.parametrize("tile", [3, 4])
@@ -270,31 +267,6 @@ def test_pick_shot_tile():
                           vmem_budget=1024) == 1
     # only divisors are picked by default (no ragged tiles)
     assert 6 % pick_shot_tile(6, 600, 600, 8, bz=120) == 0
-
-
-def test_autotune_shots_returns_triple():
-    bz, k, tile = autotune_bz_k(
-        48, 64, bz_candidates=(8, 16), k_candidates=(2,), repeats=1,
-        backend="interpret", stream=False, n_shots=2,
-    )
-    assert (bz, k) in {(8, 2), (16, 2)}
-    assert tile in (1, 2) and 2 % tile == 0
-
-
-def test_autotune_streamed_shots_returns_triple():
-    bz, k, tile = autotune_bz_k(
-        64, 128, bz_candidates=(16,), k_candidates=(4,), repeats=1,
-        backend="interpret", stream=True, n_shots=2,
-    )
-    assert (bz, k) == (16, 4)
-    assert tile in (1, 2)
-
-
-def test_autotune_without_shots_still_pair():
-    out = autotune_bz_k(48, 64, bz_candidates=(8, 16),
-                        k_candidates=(2,), repeats=1,
-                        backend="interpret", stream=False)
-    assert len(out) == 2
 
 
 def test_shot_parallel_runner_single_device():

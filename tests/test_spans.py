@@ -355,8 +355,8 @@ print("schedule", remesh.attrs["schedule"], pick_schedule(2))
 
 def test_fwi_remesh_names_the_schedule_it_resolved():
     """One stripe's session resolves the single window; a two-stripe
-    session resolves the backend's exchanging schedule, and its
-    ``fwi.remesh`` span says so."""
+    session resolves the pipeline, and its ``fwi.remesh`` span says
+    so."""
     t = time.perf_counter()
     _fwi_session()
     remesh = [x for x in since(t, "fwi.") if x.name == "fwi.remesh"][-1]
@@ -370,7 +370,7 @@ def test_fwi_remesh_names_the_schedule_it_resolved():
     assert out.returncode == 0, out.stderr[-3000:]
     lines = out.stdout.split("\n")
     assert "stripes 2" in lines, out.stdout
-    assert "schedule fused fused" in lines, out.stdout
+    assert "schedule pipeline pipeline" in lines, out.stdout
 
 
 # -------------------------------------------------- scopes in the HLO
@@ -387,16 +387,15 @@ from repro.fwi.solver import FWIConfig
 
 cfg = FWIConfig(nz=32, nx=64, timesteps=32, n_shots=2, sponge_width=4)
 assert len(jax.devices()) == 2
-for schedule in ("overlap", "pipeline"):
-    run, place, k = make_sharded_scan_runner(
-        cfg, stripe_mesh(2), k=4, use_pallas=False, overlap=schedule)
-    z = jnp.zeros((cfg.n_shots, cfg.nz, cfg.nx), jnp.float32)
-    p, pp = place((z, z))
-    hlo = run.lower(p, pp, 0, blocks=2).compile().as_text()
-    print(schedule, "resolved", place.schedule)
-    for scope in ("fwi.exchange", "fwi.boundary", "fwi.interior",
-                  "fwi.stitch", "fwi.traces"):
-        print(schedule, scope, ('/' + scope + '/') in hlo)
+run, place, k = make_sharded_scan_runner(
+    cfg, stripe_mesh(2), k=4, use_pallas=False)
+z = jnp.zeros((cfg.n_shots, cfg.nz, cfg.nx), jnp.float32)
+p, pp = place((z, z))
+hlo = run.lower(p, pp, 0, blocks=2).compile().as_text()
+print("resolved", place.schedule)
+for scope in ("fwi.exchange", "fwi.boundary", "fwi.interior",
+              "fwi.stitch", "fwi.traces"):
+    print(scope, ('/' + scope + '/') in hlo)
 """
 
 
@@ -408,11 +407,10 @@ def test_sharded_runner_phases_carry_named_scopes_in_the_hlo():
                          timeout=600)
     assert out.returncode == 0, out.stderr[-3000:]
     lines = out.stdout.split("\n")
-    for schedule in ("overlap", "pipeline"):
-        assert f"{schedule} resolved {schedule}" in lines, out.stdout
-        for scope in ("fwi.exchange", "fwi.boundary", "fwi.interior",
-                      "fwi.stitch", "fwi.traces"):
-            assert f"{schedule} {scope} True" in lines, out.stdout
+    assert "resolved pipeline" in lines, out.stdout
+    for scope in ("fwi.exchange", "fwi.boundary", "fwi.interior",
+                  "fwi.stitch", "fwi.traces"):
+        assert f"{scope} True" in lines, out.stdout
 
 
 def test_shot_tiles_carry_named_scopes_in_the_hlo():
@@ -449,7 +447,7 @@ def test_padding_carries_named_scopes_only_on_a_ragged_grid(
     monkeypatch.setattr(kernel, "DEFAULT_VMEM_BUDGET", 300_000)
     cfg = FWIConfig(nz=nz, nx=nx, timesteps=34, n_shots=2, sponge_width=4)
     run, place, k = make_sharded_scan_runner(
-        cfg, stripe_mesh(1), k=4, use_pallas=False, overlap="overlap")
+        cfg, stripe_mesh(1), k=4, use_pallas=False)
     assert place.geometry.padded(cfg) == padded
 
     def program(p, pp):

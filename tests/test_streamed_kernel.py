@@ -7,8 +7,8 @@ Capacity ladder for production-scale grids:
   streamed Pallas       == wave_block_ref            (allclose ≤ 1e-5,
                            same contract as the resident Pallas kernel)
   streamed Pallas       == resident Pallas           (BITWISE — both run
-                           the one _trapezoid_k_steps body)
-  pipeline schedule     == overlap == fused          (BITWISE across
+                           the one _trapezoid_k_steps_shots body)
+  pipeline schedule     == reference                 (BITWISE across
                            REAL stripe seams, 2 and 4 stripes)
 
 plus the capacity bookkeeping (should_stream / stream_vmem_bytes /
@@ -31,14 +31,13 @@ from repro.kernels.stencil.kernel import (
     DEFAULT_VMEM_BUDGET,
     HALO,
     StripFallbackWarning,
-    pick_bz,
     pick_bz_block,
     pick_bz_stream,
     resident_vmem_bytes,
     should_stream,
     stream_vmem_bytes,
-    wave_block_pallas,
-    wave_block_stream_pallas,
+    wave_block_shots_pallas,
+    wave_block_shots_stream_pallas,
 )
 from repro.kernels.stencil.ops import wave_block
 from repro.kernels.stencil.ref import wave_block_ref, wave_block_strips_ref
@@ -79,29 +78,28 @@ def test_streamed_2048_grid_matches_ref(k):
     for a, b in zip(ref, strips):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
-    streamed = wave_block_stream_pallas(
-        p, pp, v, s, srcv, zi, xi, receiver_row=7, bz=bz,
-        vmem_budget=SMALL_BUDGET,
+    streamed = wave_block_shots_stream_pallas(
+        p[None], pp[None], v, s, srcv, jnp.array([zi]), jnp.array([xi]),
+        receiver_row=7, bz=bz, vmem_budget=SMALL_BUDGET,
     )
     for a, b in zip(ref, streamed):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b)[0],
                                    atol=1e-5)
 
 
 @pytest.mark.parametrize("k", [1, 4])
 def test_streamed_pallas_bitwise_vs_resident_pallas(k):
     """Streamed and resident Pallas kernels share one trapezoid body
-    (_trapezoid_k_steps): identical strip geometry must produce BITWISE
-    identical fields and traces — the DMA pipeline is pure data
-    movement."""
+    (_trapezoid_k_steps_shots): identical strip geometry must produce
+    BITWISE identical fields and traces for one shot — the DMA pipeline
+    is pure data movement."""
     nz, nx = 128, 160
     bz = 16
     p, pp, v, s = _fields(nz, nx, seed=20 + k)
     srcv = jnp.linspace(0.5, 1.0, k)
-    a = wave_block_pallas(p, pp, v, s, srcv, 40, 80, receiver_row=3,
-                          bz=bz)
-    b = wave_block_stream_pallas(p, pp, v, s, srcv, 40, 80,
-                                 receiver_row=3, bz=bz)
+    at = (p[None], pp[None], v, s, srcv, jnp.array([40]), jnp.array([80]))
+    a = wave_block_shots_pallas(*at, receiver_row=3, bz=bz)
+    b = wave_block_shots_stream_pallas(*at, receiver_row=3, bz=bz)
     for x, y in zip(a, b):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
@@ -136,26 +134,10 @@ def test_wave_block_auto_streams_over_budget():
     p, pp, v, s = _fields(nz, nx, seed=9)
     srcv = jnp.linspace(0.5, 1.0, k)
     ref = wave_block_ref(p, pp, v, s, srcv, 17, 400, receiver_row=1)
-    out = wave_block(p, pp, v, s, srcv, 17, 400, receiver_row=1,
-                     vmem_budget=budget)
+    out = wave_block(p[None], pp[None], v, s, srcv, jnp.array([17]),
+                     jnp.array([400]), receiver_row=1, vmem_budget=budget)
     for a, b in zip(ref, out):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-def test_autotune_dispatches_to_stream_space():
-    """autotune_bz_k(stream=True) searches the streamed (strip, depth)
-    space: the winner must satisfy the divisor + trapezoid + budget
-    constraints (and never the whole-height fallback)."""
-    from repro.kernels.stencil.kernel import autotune_bz_k
-
-    nz, nx = 128, 128
-    budget = 512 * 1024
-    bz, k = autotune_bz_k(nz, nx, bz_candidates=(8, 16, 32),
-                          k_candidates=(1, 2), repeats=1, stream=True,
-                          vmem_budget=budget)
-    assert nz % bz == 0 and k in (1, 2)
-    assert bz + 2 * k * HALO <= nz
-    assert stream_vmem_bytes(nz, nx, bz, k) <= budget
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b)[0])
 
 
 # ------------------------------------------------- capacity bookkeeping
@@ -193,7 +175,7 @@ def test_resident_pickers_warn_on_whole_height_fallback():
     """Tall grids with no usable strip divisor fall back to ONE
     whole-height resident strip — now loudly."""
     with pytest.warns(StripFallbackWarning):
-        assert pick_bz(251) == 251
+        assert pick_bz_block(251, 4) == 251
     with pytest.warns(StripFallbackWarning):
         assert pick_bz_block(1009, 4) == 1009
     # small / composite grids take the normal branch silently
@@ -201,9 +183,10 @@ def test_resident_pickers_warn_on_whole_height_fallback():
 
     with _w.catch_warnings():
         _w.simplefilter("error", StripFallbackWarning)
-        assert pick_bz(600) == 120
-        assert pick_bz(64) == 64
-        assert pick_bz(37) == 37               # short prime: under cap
+        assert pick_bz_block(600, 1) == 120
+        assert pick_bz_block(64, 1) == 32
+        assert pick_bz_block(16, 8) == 16      # too short for a trapezoid
+        assert pick_bz_block(37, 8) == 37      # short prime: under cap
         assert pick_bz_block(600, 4) == 120
 
 
@@ -222,36 +205,24 @@ from repro.fwi.domain import stripe_mesh, make_sharded_scan_runner
 cfg = FWIConfig(nz=64, nx=128, timesteps=40, n_shots=2, sponge_width=8)
 ref, ref_tr = run_forward(cfg, steps=40)
 for n in (2, 4):
-    outs = {}
-    for sched in ("fused", "overlap", "pipeline"):
-        run, place, keff = make_sharded_scan_runner(
-            cfg, stripe_mesh(n), k=4, overlap=sched
-        )
-        s = ShotState.init(cfg)
-        p, pp = place((s.p, s.p_prev))
-        p, pp, tr = run(p, pp, 0, 40 // keff)
-        outs[sched] = (np.asarray(p), np.asarray(pp), np.asarray(tr))
-    # the double-buffered pipeline must be BITWISE identical to the
-    # eager-exchange schedule (same per-block op graph, reordered);
-    # vs the comm-avoiding fused window the op sequence is identical
-    # but fusion shapes may flush denormal wavefront tails differently
-    # (same contract as test_fused_engine) — equal up to sub-normal
-    # noise (< FLT_MIN = 1.2e-38)
-    for a, b in zip(outs["pipeline"], outs["overlap"]):
-        assert np.array_equal(a, b), n
-    for a, b in zip(outs["pipeline"], outs["fused"]):
-        err = np.max(np.abs(a - b))
-        assert err < 1.2e-38, (n, err)
-    assert np.max(np.abs(outs["pipeline"][0] - np.asarray(ref.p))) < 1e-6, n
-    assert np.max(np.abs(outs["pipeline"][2] - np.asarray(ref_tr))) < 1e-6, n
+    run, place, keff = make_sharded_scan_runner(cfg, stripe_mesh(n), k=4)
+    assert place.schedule == "pipeline", place.schedule
+    s = ShotState.init(cfg)
+    p, pp = place((s.p, s.p_prev))
+    p, pp, tr = run(p, pp, 0, 40 // keff)
+    # the double-buffered pipeline, all blocks in one dispatch, is
+    # BITWISE identical to the reference: fields, damped previous
+    # field and traces
+    for a, b in ((p, ref.p), (pp, ref.p_prev), (tr, ref_tr)):
+        assert np.array_equal(np.asarray(a), np.asarray(b)), n
 print("PIPELINE_INVARIANCE_OK")
 """
 
 
 def test_pipeline_schedule_invariance_subprocess():
-    """Double-buffered halo pipeline vs eager exchange vs fused window
-    across 2- and 4-stripe REAL seams (4 host devices): bitwise
-    invariant, and allclose to the seed reference."""
+    """The double-buffered halo pipeline across 2- and 4-stripe REAL
+    seams (4 host devices), many blocks in one dispatch: bitwise equal
+    to the seed reference."""
     src = str(Path(__file__).resolve().parents[1] / "src")
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
@@ -264,19 +235,23 @@ def test_pipeline_schedule_invariance_subprocess():
 
 
 def test_pick_schedule_and_normalization():
-    from repro.fwi.domain import _as_schedule, pick_schedule
+    """The schedule follows the stripe count alone: one stripe has no
+    seam, so the single window; the choice takes no backend."""
+    import inspect
 
-    assert pick_schedule(4, "tpu") == "pipeline"
-    assert pick_schedule(4, "cpu") == "fused"
-    # one stripe has no seam: the single window on any backend
-    assert pick_schedule(1, "tpu") == pick_schedule(1, "cpu") == "single"
-    for n in (1, 2):
-        assert _as_schedule(None, n) == pick_schedule(n)
-        assert _as_schedule(True, n) == "overlap"     # legacy bool knob
-        assert _as_schedule(False, n) == "fused"
-        assert _as_schedule("pipeline", n) == "pipeline"
-        with pytest.raises(ValueError):
-            _as_schedule("bogus", n)
+    from repro.fwi.domain import pick_schedule
+
+    assert pick_schedule(1) == "single"
+    assert list(inspect.signature(pick_schedule).parameters) == ["n_stripes"]
+
+
+@pytest.mark.parametrize("n", [2, 4])
+def test_pick_schedule_pipeline_on_every_backend(n):
+    """Seams take the pipeline on this host too, the schedule the TPU
+    runs and the tests pin bitwise to the reference."""
+    from repro.fwi.domain import pick_schedule
+
+    assert pick_schedule(n) == "pipeline"
 
 
 # ------------------------------------------------- seam provenance
